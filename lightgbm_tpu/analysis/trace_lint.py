@@ -39,14 +39,8 @@ def _load_fixture(path: str) -> None:
 def run_trace(args) -> int:
     from ..utils.hermetic import force_cpu_backend
     force_cpu_backend(device_count=8)
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(os.getcwd(), ".jax_cache"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except (AttributeError, ValueError):
-        # older jax without the persistent-cache options: slower, not wrong
-        pass
+    from ..utils.cache import resolve_compile_cache
+    resolve_compile_cache()
 
     from . import contracts as reg
     from .contracts import entries  # noqa: F401  (registers T001-T010)

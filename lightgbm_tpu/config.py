@@ -398,15 +398,12 @@ class Config:
     # decision and the engine.train budget line; 0 = use the capacity the
     # backend reports (env LGBM_TPU_HBM_BUDGET overrides both)
     tpu_hbm_budget_bytes: int = 0
-    # histogram kernel: "auto" resolves to "mixed" (XLA streaming passes +
-    # pallas-512 compacted passes — the round-5 pass-level measured best,
-    # 18.0 vs 22.1 ms at 25% active) on a real TPU whose on-chip gate has
-    # validated this kernel shape class, and to "xla" everywhere else; see
-    # boosting/gbdt.py kernel-resolution block. "xla" one-hot matmul |
+    # histogram kernel: "auto" resolves to "xla" on every platform — the
+    # choice is a function of committed code and configuration only (see
+    # boosting/gbdt.py kernel-resolution block). "xla" one-hot matmul |
     # "pallas" fused VMEM-accumulator kernel (ops/pallas_histogram.py, the
     # OpenCL histogram256.cl analog) | "mixed" (pallas for compacted passes
-    # only). Explicit pallas/mixed on a never-gated shape class runs with a
-    # warning (exp/pallas_onchip_check.py records the trust markers)
+    # only); chip_smoke.py compiles and checks "mixed" on the chip every run
     tpu_hist_kernel: str = "auto"
     # per-phase wall-clock accumulators (reference TIMETAG) printed after
     # training; tpu_profile_dir wraps training in a jax.profiler trace

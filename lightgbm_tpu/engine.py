@@ -37,11 +37,10 @@ def train(params: Dict[str, Any], train_set: Dataset,
     starts fresh otherwise, so a preempted run restarts with the identical
     command. With ``checkpoint_dir`` + ``checkpoint_interval`` set, a
     snapshot is written every N iterations."""
-    # persistent XLA compile cache (utils/cache.py): honor the
-    # LGBM_TPU_COMPILE_CACHE_DIR knob on every training entry point so
-    # repeated runs (and bench subprocess phases) pay each step compile once
-    from .utils.cache import maybe_enable_compile_cache
-    maybe_enable_compile_cache()
+    # persistent XLA compile cache (utils/cache.py): repeated runs pay each
+    # step compile once
+    from .utils.cache import resolve_compile_cache
+    resolve_compile_cache()
 
     params = dict(params or {})
     # verbosity -> Log.set_level BEFORE construction so construction-time
@@ -430,7 +429,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
         # train-end snapshot dump (cost/memory reports included): the
         # explicit dump_snapshot path AND — whenever a telemetry dir is
         # configured — a snapshot_<pid>.json in that dir, unconditionally,
-        # so harvest windows capture it without code edits
+        # so a chip run's output directory captures it without code edits
         try:
             snap_paths = []
             if config.dump_snapshot:

@@ -158,7 +158,7 @@ def snapshot() -> Dict:
 
 def write_snapshot(path: str) -> str:
     """Write ``snapshot()`` to ``path`` as JSON (atomic) — the
-    ``--dump-snapshot`` / train-end artifact harvest windows collect."""
+    ``--dump-snapshot`` / train-end artifact a chip run brings back."""
     from .export import atomic_write_json
     return atomic_write_json(path, snapshot(), indent=1, sort_keys=True,
                              trailing_newline=True)

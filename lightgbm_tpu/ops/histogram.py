@@ -4,8 +4,9 @@ The TPU replacement for the reference's histogram kernels:
 - CPU scatter-add: Bin::ConstructHistogram (src/io/dense_bin.hpp:66-130)
 - OpenCL local-memory atomics (src/treelearner/ocl/histogram256.cl:95-125)
 
-TPUs have no fast scatter (measured ~400x slower than matmul formulation —
-exp/RESULTS.md), so the histogram is computed as a chunked one-hot matmul:
+TPUs have no fast scatter (an earlier on-chip session measured it ~400x
+slower than the matmul formulation), so the histogram is computed as a
+chunked one-hot matmul:
 
     hist[f, b, s*ch+j] = sum_r (X[r,f] == b) * rhs[r, s*ch+j]
 
@@ -72,14 +73,22 @@ def combine_channels(acc, hilo):
 
 
 def _split_hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    hi = x.astype(jnp.bfloat16)
-    lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return hi, lo
+    # reduce_precision, NOT astype(bf16).astype(f32): inside a jitted program
+    # the TPU compiler (xla_allow_excess_precision) elides the f32->bf16->f32
+    # round trip, which makes hi == x and lo == 0 — the sums then carry bf16
+    # precision only. reduce_precision is never elided; its result is
+    # exactly bf16-representable, so the cast below is exact. The CPU
+    # backend keeps the round trip, so only a chip run shows the difference
+    # (chip_smoke.py's Pallas leg pins both kernels against f64 sums).
+    x = x.astype(jnp.float32)
+    hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
 
 
 # ---- packed-row form for the compacted gather -------------------------------
-# A random row access to HBM costs ~25-55 ns regardless of width (measured,
-# exp/chain_profile.py), so the compacted pass gathers ONE packed array
+# A random row access to HBM costs about the same regardless of width (an
+# earlier on-chip session measured ~25-55 ns), so the compacted pass gathers
+# ONE packed array
 # holding everything it needs per row instead of four separate gathers of
 # X/grad/hess/included. The packed dtype is uint8, NOT int32: TPU tiling
 # pads the minor dimension to 128 lanes, so ANY [N, small] i32 array
@@ -223,7 +232,7 @@ def table_lookup(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """table[idx] for a SMALL table ([T<=1024, C]) as a one-hot f32 matmul.
 
     XLA's TPU gather prices a per-row dynamic lookup at the random-access
-    tax (~15-25 ms for 2M rows — measured, exp/chain_profile.py) even when
+    tax (an earlier on-chip session measured ~15-25 ms for 2M rows) even when
     the table is tiny; the one-hot [N, T] x [T, C] contraction is ~0.1 ms
     on the MXU. Exact for values with |v| < 2^24 (f32 integer range) —
     callers keep table entries inside that. Returns table.dtype.

@@ -396,10 +396,7 @@ class ChunkFeeder:
         t0 = obs.clock()
         with obs.span("ingest_stall", chunk=i):
             arr = self._put(i)
-            try:
-                arr.block_until_ready()
-            except AttributeError:
-                pass
+            arr.block_until_ready()
         dt = obs.clock() - t0
         self.stall_seconds += dt
         obs.get_registry().histogram("ingest.stall_seconds").observe(dt)
@@ -441,9 +438,9 @@ def device_ingest(raw: np.ndarray, mappers: Sequence[BinMapper],
     ``[n_rows_padded, num_cols]`` device array (or the packed byte layout
     under ``code_mode``) bit-identical to host binning + ``np.pad`` +
     ``device_put``, and ``report`` carries the throughput/overlap numbers
-    (``bench.py --ingest``, ``--smoke``'s ingest leg). The caller owns any
-    further resharding (boosting/gbdt.py ``device_put``s onto the mesh
-    row sharding — a device-to-device move)."""
+    (``bench.py --ingest``, ``--smoke``'s ingest leg). The caller owns the
+    mesh layout: boosting/gbdt.py calls this once per device with that
+    device's row block and assembles the sharded array from the pieces."""
     import jax.numpy as jnp
     from .. import observability as obs
 
@@ -470,10 +467,7 @@ def device_ingest(raw: np.ndarray, mappers: Sequence[BinMapper],
         codes = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
         if codes.shape[0] != n_rows_padded:
             codes = codes[:n_rows_padded]
-        try:
-            codes.block_until_ready()
-        except AttributeError:
-            pass
+        codes.block_until_ready()
     seconds = obs.clock() - t0
     obs.inc("ingest.rows", int(n_rows))
     obs.inc("ingest.chunks", int(n_chunks))
@@ -490,3 +484,26 @@ def device_ingest(raw: np.ndarray, mappers: Sequence[BinMapper],
               "%d stalls, %.1f MB H2D)", n_rows, n_chunks, R, seconds,
               rep["stalls"], rep["bytes_h2d"] / (1 << 20))
     return codes, rep
+
+
+def merge_ingest_reports(reports: Sequence[Dict]) -> Dict:
+    """One report for a (row-sharded) ingest: per-device ``device_ingest``
+    reports run back to back, so counts and seconds add; ``compiles`` is
+    the worst single ingestor (each device owns one executable that all of
+    its chunks must share)."""
+    out = dict(reports[0])
+    for key in ("n_chunks", "stalls", "prefetch_hits", "bytes_h2d", "rows",
+                "rows_padded"):
+        out[key] = sum(r[key] for r in reports)
+    seconds = sum(r["seconds"] for r in reports)
+    stall = sum(r["stall_seconds"] for r in reports)
+    compiles = [r["compiles"] for r in reports]
+    out.update({
+        "devices": len(reports),
+        "seconds": round(seconds, 6),
+        "stall_seconds": round(stall, 6),
+        "rows_per_s": (out["rows"] / seconds) if seconds > 0 else None,
+        "stall_fraction": (stall / seconds) if seconds > 0 else 0.0,
+        "compiles": None if None in compiles else max(compiles),
+    })
+    return out

@@ -38,14 +38,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-# Importing pallas' TPU backend registers MLIR lowerings for platform "tpu",
-# which jax rejects when only the CPU plugin is present (the interpret-mode
-# test bed). Registering the identity alias first makes "tpu" a known
-# platform without initializing any backend.
-from jax._src import xla_bridge as _xb
-if not _xb.is_known_platform("tpu"):
-    _xb._platform_aliases["tpu"] = "tpu"
-
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -95,9 +87,8 @@ def _hist_kernel(n_active_ref,        # SMEM scalar prefetch: [1] i32
         # one-hot element. The earlier f-blocked form first materialized
         # [R, fb*B] i32 via jnp.repeat and compared against iota%B, i.e.
         # 3-4 VPU passes over the same elements; the one-hot build is the
-        # measured VPU bottleneck of this kernel (exp/RESULTS.md round-3
-        # cost model), so the extra passes were the pass-level gap vs the
-        # MXU floor. Per-feature [R, B] contractions keep the MXU busy at
+        # VPU-bound part of this kernel, so the extra passes were the
+        # pass-level gap vs the MXU floor. Per-feature [R, B] contractions keep the MXU busy at
         # B >= 128 (2 lane tiles at B=256).
         iota_b = jax.lax.broadcasted_iota(
             jnp.int32, (chunk_rows, num_bins), 1)
@@ -107,7 +98,7 @@ def _hist_kernel(n_active_ref,        # SMEM scalar prefetch: [1] i32
             else:
                 # little-endian byte pair, two contiguous 1-column slices
                 # (a stride-2 lane slice is lowered as a gather Mosaic
-                # fails to shape-check — round-5 on-chip gate log)
+                # fails to shape-check)
                 xs = (x_ref[:, 2 * f:2 * f + 1].astype(jnp.int32)
                       | (x_ref[:, 2 * f + 1:2 * f + 2].astype(jnp.int32)
                          << 8))                               # [R, 1]

@@ -303,10 +303,9 @@ class ShardPrefetcher:
             arr = self._put(i)
             # block on THIS transfer only (compute stays queued): the wait
             # is the measurable cost the double buffer exists to hide
-            try:
-                arr.block_until_ready()
-            except AttributeError:
-                pass
+            # (jax's own helper: an injected put_fn may hand back host arrays)
+            import jax
+            jax.block_until_ready(arr)
         dt = obs.clock() - t0
         self.stall_seconds += dt
         obs.get_registry().histogram("stream.stall_seconds").observe(dt)

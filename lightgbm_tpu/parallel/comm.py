@@ -52,30 +52,6 @@ from ..ops.split_finder import (PerFeatureBest, SplitCandidates,
                                 unpack_bundled_hist)
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, across jax versions.
-
-    The kwarg that disables the check was renamed check_rep -> check_vma,
-    and the function itself moved from jax.experimental.shard_map to jax
-    top-level — on different releases, in different combinations (0.5-0.6
-    export jax.shard_map that still takes check_rep). Feature-detect the
-    kwarg on whichever function exists instead of keying off the module."""
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "check_vma" in params:
-        kwargs["check_vma"] = False
-    elif "check_rep" in params:
-        kwargs["check_rep"] = False
-    return sm(fn, **kwargs)
-
-
 class BlockMeta(NamedTuple):
     """Per-feature metadata of the feature block this device scans.
 
@@ -834,8 +810,8 @@ class ParallelContext:
         rows2d = P(self.ROW_AXIS, None) if self.strategy in ("data", "voting") else P()
         in_specs = (rows2d, rows, rows, rows, P(), P(), P(), P(), P())
         out_specs = (P(), rows)       # (TreeArrays..., leaf_id)
-        return _shard_map(grow_fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+        return jax.shard_map(grow_fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
 
 def parse_machine_list(config) -> list:
@@ -1056,60 +1032,11 @@ def host_allgather(obj, tag: str, timeout_ms: int = 600_000, *,
         return out
 
 
-class _SafeKVClient:
-    """Bytes-safe facade over jax's DistributedRuntimeClient KV surface.
-
-    The ``*_bytes`` getters on the bundled jaxlib CPU wheels segfault when
-    fetching a key written by ANOTHER process (the py::bytes return path;
-    reproduced with a bare two-process ``jax.distributed`` cluster on
-    jaxlib 0.4.36 — the string getter on the same key is fine), so every
-    byte payload rides the string API base64-encoded instead. The facade
-    keeps the ``*_bytes`` call surface the rest of the package (and the
-    FakeKVStore / ChaosKVClient doubles) speaks; anything else delegates
-    to the real client untouched.
-    """
-
-    def __init__(self, inner):
-        self._inner = inner
-
-    def key_value_set_bytes(self, key: str, value: bytes,
-                            allow_overwrite: bool = False) -> None:
-        import base64
-        self._inner.key_value_set(key,
-                                  base64.b64encode(value).decode("ascii"),
-                                  allow_overwrite=allow_overwrite)
-
-    def blocking_key_value_get_bytes(self, key: str,
-                                     timeout_ms: int) -> bytes:
-        import base64
-        return base64.b64decode(
-            self._inner.blocking_key_value_get(key, timeout_ms))
-
-    def wait_at_barrier(self, key: str, timeout_ms: int):
-        return self._inner.wait_at_barrier(key, timeout_ms)
-
-    def key_value_delete(self, key: str):
-        return self._inner.key_value_delete(key)
-
-    def __getattr__(self, name):
-        return getattr(self._inner, name)
-
-
-_safe_kv_client = None
-
-
 def distributed_client():
-    """The jax coordination-service client wrapped in the bytes-safe KV
-    facade, or None when not running under jax.distributed (single probe
-    point for the private-API access)."""
-    global _safe_kv_client
+    """The jax coordination-service client, or None when not running under
+    jax.distributed (single probe point for the private-API access)."""
     from jax._src import distributed as _dist
-    raw = _dist.global_state.client
-    if raw is None:
-        return None
-    if _safe_kv_client is None or _safe_kv_client._inner is not raw:
-        _safe_kv_client = _SafeKVClient(raw)
-    return _safe_kv_client
+    return _dist.global_state.client
 
 
 def init_distributed(config) -> bool:
@@ -1211,14 +1138,24 @@ def select_devices(config):
     """Devices for this booster, honoring the reference's ``device`` param:
     ``tpu`` (default) uses the accelerator backend; ``cpu`` forces the host
     CPU backend — which under `--xla_force_host_platform_device_count=N`
-    exposes N virtual devices, the test bed for every parallel strategy."""
+    exposes N virtual devices, the test bed for every parallel strategy.
+
+    A run that asked for the accelerator never finishes on the CPU by
+    accident: if jax found no accelerator and nobody pinned the CPU
+    (``JAX_PLATFORMS=cpu``, which the test harness and the CPU-only bench
+    modes set), this fails instead of training on the host."""
     want = getattr(config, "device", "tpu")
     if want == "cpu":
-        try:
-            return jax.devices("cpu")
-        except RuntimeError:
-            return jax.devices()
-    return jax.devices()
+        return jax.devices("cpu")
+    devices = jax.devices()
+    if (devices[0].platform == "cpu"
+            and "cpu" not in (jax.config.jax_platforms or "")):
+        from ..utils.log import Log
+        Log.fatal("device=%s requested but jax found no accelerator "
+                  "(default backend: cpu). Set device=cpu or "
+                  "JAX_PLATFORMS=cpu to train on the host CPU on purpose.",
+                  want)
+    return devices
 
 
 def make_parallel_context(config, devices=None, shape=None) -> ParallelContext:

@@ -12,8 +12,8 @@ shapes — many small concurrent batches, never one big one — hit a finite,
 warmed set of executables and NEVER recompile in steady state
 (``bench.py --serve`` pins this under a RecompileGuard). ``warmup()``
 compiles every bucket ahead of serving; with the persistent XLA compile
-cache (``LGBM_TPU_COMPILE_CACHE_DIR``) a restarted server replays the
-compiles from disk.
+cache (``utils/cache.resolve_compile_cache``) a restarted server replays
+the compiles from disk.
 
 Numerics contract: traversal is integer-exact on device (rank compares);
 leaf-value accumulation happens on the HOST in float64, sequentially in
@@ -133,9 +133,9 @@ class ServingEngine:
 
     def __init__(self, model, params: Optional[Dict] = None,
                  num_iteration: Optional[int] = None, warmup: bool = True):
-        from ..utils.cache import maybe_enable_compile_cache
+        from ..utils.cache import resolve_compile_cache
 
-        maybe_enable_compile_cache()
+        resolve_compile_cache()
         booster = self._load_booster(model, params)
         self.config = booster.config
         self.buckets = sorted(bucket_ladder(self.config))

@@ -40,7 +40,7 @@ contract("TX91", "planted required-collective violation", ENTRY,
 
 @program_builder(ENTRY, "f64_leak")
 def _f64_leak():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.zeros(8, jnp.float32))
     return TracedProgram(ENTRY, "f64_leak", jx)

@@ -7,7 +7,7 @@ reference CLI (cmake + make from /root/reference, v2.0.10), re-runs the
 exact workloads, parses the printed valid_1 metrics, and writes the fixture
 with the config/data hashes of everything that determined each number — so
 any drift in the bundled confs or data is caught as a hash mismatch rather
-than a silently mismeasured anchor (VERDICT r4 #8).
+than a silently mismeasured anchor.
 
 Run:  python tests/gen_oracles.py [--skip-build]
 

@@ -85,8 +85,8 @@ if mode == "prepart_efb":
     assert bst._gbdt.bundle is not None, "EFB must engage under pre-partition"
 if mode == "prepart":
     # C-API LGBM_BoosterGetPredict under is_pre_partition must select the
-    # real rows out of the block-padded device layout (_real_rows, ADVICE
-    # r4 #2) in global block order — compare against host-tree predictions
+    # real rows out of the block-padded device layout (_real_rows)
+    # in global block order — compare against host-tree predictions
     # of the full matrix. _fetch allgathers across processes, so BOTH
     # ranks make the same calls.
     import ctypes

@@ -109,7 +109,7 @@ def test_device_forest_root_is_leaf_only():
 @pytest.mark.slow
 def test_device_forest_large_batch():
     """Correctness at the 1M-row-tree routing scale (absolute wall-clock is
-    a bench concern — the VERDICT target of 1M x 28 x 100 trees < 2s is
+    a bench concern — the target of 1M x 28 x 100 trees < 2s is
     measured on the chip, not this CPU test backend)."""
     bst, _ = _train(n=3000, f=28, trees=40)
     rng = np.random.RandomState(2)

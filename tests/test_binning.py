@@ -144,7 +144,7 @@ def test_collect_distinct_interior_zero_splice_unguarded():
     """A fully-dense column crossing negative->positive still gets a
     (0.0, 0) distinct entry: the reference's interior splice
     (bin.cpp:245-248) is unguarded, unlike the all-positive/all-negative
-    edge splices which only fire when zeros exist (ADVICE r4 #1)."""
+    edge splices which only fire when zeros exist."""
     from lightgbm_tpu.binning import BinMapper
 
     vals = np.array([-2.0, -1.0, 1.0, 2.0], dtype=np.float64)

@@ -402,9 +402,9 @@ def _child_env(mode, extra_env=None):
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = force_device_count_flags(
         env.get("XLA_FLAGS", ""), 8 if mode == "data8" else None)
-    # inherit the repo compile cache so child compiles are mostly warm
-    env.setdefault("LGBM_TPU_COMPILE_CACHE_DIR",
-                   os.path.join(REPO, ".jax_cache"))
+    # children run from tmp_path: the checkout must be on their path (they
+    # then place the compile cache in <checkout>/.jax_cache themselves)
+    env["PYTHONPATH"] = REPO
     env.update(extra_env or {})
     return env
 

@@ -306,22 +306,52 @@ def test_booster_publishes_comm_gauges(cost_capture):
 
 # ------------------------------------------------------------------- ledger
 
-def test_ledger_builds_from_checked_in_history():
-    entries = ledger.load_history(REPO)
-    assert len(entries) >= 10
-    doc = ledger.build_ledger(REPO)
-    key = ("platform=tpu|rows=10500000|kernel=xla|n_devices=None"
-           "|residency=None|serve=None|serve_chaos=None|chaos_dist=None"
-           "|bundle=None|linear=None|ingest=None")
-    assert doc["best"][key]["value"] == 6.0
-    assert doc["best"][key]["source"] == "BENCH_r05.json"
-    # the committed ledger matches the history (no drift) — the same
-    # invariant `make bench-diff` enforces
+_HEADLINE_KEY = ("platform=tpu|rows=10500000|kernel=xla|n_devices=None"
+                 "|residency=None|serve=None|serve_chaos=None|chaos_dist=None"
+                 "|bundle=None|linear=None|ingest=None")
+
+
+def _synthetic_history(root):
+    """A history directory in every shape load_history must read: a driver
+    wrapper with no parsed payload, a bare errored payload, a wrapper
+    holding a clean payload, and a bare clean payload that is the best."""
+    head = {"metric": "higgs_train_throughput", "unit": "Mrow-tree/s",
+            "platform": "tpu", "rows": 10_500_000, "kernel": "xla"}
+    docs = {
+        "BENCH_r01.json": {"n": 1, "cmd": "python bench.py", "rc": 1,
+                           "tail": "backend init failed", "parsed": None},
+        "BENCH_r02.json": dict(head, value=0.0, error="no chip"),
+        "BENCH_r03.json": {"n": 3, "cmd": "python bench.py", "rc": 0,
+                           "tail": "", "parsed": dict(head, value=4.0)},
+        "BENCH_r04.json": dict(head, value=6.0, auc=0.9,
+                               recompiles_post_warmup=0),
+        "STREAM_r05.json": dict(head, value=2.0, residency="stream"),
+    }
+    for name, doc in docs.items():
+        (root / name).write_text(json.dumps(doc))
+    return str(root)
+
+
+def test_ledger_builds_from_history(tmp_path):
+    root = _synthetic_history(tmp_path)
+    entries = ledger.load_history(root)
+    assert [e["source"] for e in entries] == [
+        "BENCH_r01.json", "BENCH_r02.json", "BENCH_r03.json",
+        "BENCH_r04.json", "STREAM_r05.json"]
+    assert entries[0]["error"] == "unparseable history file"
+    doc = ledger.build_ledger(root)
+    assert doc["best"][_HEADLINE_KEY]["value"] == 6.0
+    assert doc["best"][_HEADLINE_KEY]["source"] == "BENCH_r04.json"
+    # the written ledger matches the history (no drift) — the same
+    # invariant `make bench-diff` enforces on the repo's own
+    ledger.write_ledger(root)
+    assert ledger.check_ledger(root)
+    # ... and the repo's committed ledger matches ITS history files
     assert ledger.check_ledger(REPO)
 
 
-def test_compare_flags_injected_throughput_regression():
-    entries = ledger.load_history(REPO)
+def test_compare_flags_injected_throughput_regression(tmp_path):
+    entries = ledger.load_history(_synthetic_history(tmp_path))
     bad = {"metric": "higgs_train_throughput", "value": 3.0,
            "unit": "Mrow-tree/s", "platform": "tpu", "rows": 10_500_000,
            "kernel": "xla"}
@@ -381,14 +411,15 @@ def test_cost_capture_scoped_to_the_run():
         obs.reset_for_tests()
 
 
-def test_compare_rejects_unclean_candidate():
-    problems, _ = ledger.compare({"value": 0.0, "error": "dead tunnel"},
-                                 ledger.load_history(REPO))
+def test_compare_rejects_unclean_candidate(tmp_path):
+    problems, _ = ledger.compare(
+        {"value": 0.0, "error": "no chip"},
+        ledger.load_history(_synthetic_history(tmp_path)))
     assert any("no clean measurement" in p for p in problems)
 
 
-def test_quick_prebank_not_judged_against_headline():
-    entries = ledger.load_history(REPO)
+def test_quick_prebank_not_judged_against_headline(tmp_path):
+    entries = ledger.load_history(_synthetic_history(tmp_path))
     quick = {"value": 4.0, "platform": "tpu", "rows": 2_100_000}
     problems, notes = ledger.compare(quick, entries)
     assert problems == []
@@ -396,22 +427,26 @@ def test_quick_prebank_not_judged_against_headline():
 
 
 @pytest.mark.slow
-def test_bench_compare_cli_exit_codes(tmp_path):
+def test_bench_compare_exit_codes(tmp_path, monkeypatch, capsys):
+    """bench.py --compare against a synthetic history directory (the mode
+    resolves its history next to bench.py's own path)."""
+    sys.path.insert(0, REPO)
+    import bench
+    root = _synthetic_history(tmp_path)
+    monkeypatch.setattr(bench, "__file__", os.path.join(root, "bench.py"))
     bad = tmp_path / "regressed.json"
     bad.write_text(json.dumps(
         {"metric": "higgs_train_throughput", "value": 3.0,
          "platform": "tpu", "rows": 10_500_000, "kernel": "xla"}))
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py"),
-                        "--compare", str(bad)],
-                       capture_output=True, text=True, cwd=REPO)
-    assert r.returncode == 2, r.stdout + r.stderr
-    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert bench.run_compare(["--compare", str(bad)]) == 2
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["ok"] is False and out["problems"]
-    # the newest checked-in BENCH judged against earlier history: clean
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py"),
-                        "--compare"],
-                       capture_output=True, text=True, cwd=REPO)
-    assert r.returncode == 0, r.stdout + r.stderr
+    # the newest history file judged against the earlier ones: clean
+    assert bench.run_compare(["--compare"]) == 0
+    capsys.readouterr()
+    # ... and the repo's own (headline-less) history judges clean too
+    monkeypatch.setattr(bench, "__file__", os.path.join(REPO, "bench.py"))
+    assert bench.run_compare(["--compare"]) == 0
 
 
 def test_ledger_check_detects_drift(tmp_path):

@@ -89,8 +89,7 @@ def test_register_rejects_unjitted():
 
 def test_booster_steady_state_holds():
     """5 post-warm-up boosting iterations reuse ONE compiled step — the
-    enforced form of the round-5 per-shape gate, and the in-suite twin of
-    `bench.py --smoke`."""
+    in-suite twin of `bench.py --smoke`."""
     rng = np.random.RandomState(0)
     X = rng.rand(2000, 8).astype(np.float32)
     y = (X[:, 0] + X[:, 1] * X[:, 2] > 0.8).astype(np.float32)

@@ -326,16 +326,17 @@ def test_e2e_training_bit_identity_serial():
 
 @pytest.mark.slow
 def test_e2e_sharded_placement_identity():
-    """8-device data-parallel: device ingest builds on one device and
-    reshards onto the row mesh — placement and training stay bit-identical
-    to the host path."""
+    """8-device data-parallel: every device ingests its own row block and
+    the shards assemble into the row mesh — placement and training stay
+    bit-identical to the host path."""
     rng = np.random.RandomState(21)
     X = rng.rand(4096, 10).astype(np.float32)
     y = (X[:, 0] + X[:, 1] > 1.0).astype(np.float32)
     extra = {"tree_learner": "data", "num_machines": 1}
     bh = _train(X, y, "host", dict(extra))
     bd = _train(X, y, "device", dict(extra))
-    assert bd._gbdt._ingest_report is not None
+    assert bd._gbdt._ingest_report["devices"] == 8
+    assert bd._gbdt._ingest_report["compiles"] == 1
     xh, xd = bh._gbdt.Xb, bd._gbdt.Xb
     assert np.array_equal(np.asarray(xh), np.asarray(xd))
     assert xh.sharding.is_equivalent_to(xd.sharding, xh.ndim)

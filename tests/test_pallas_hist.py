@@ -268,45 +268,27 @@ def test_slot_starts_permutation_matches_prefix_layout():
                                   np.asarray(ref[..., 2]))
 
 
-def test_auto_kernel_gated_by_onchip_marker(monkeypatch, tmp_path):
-    """pallas_validated_on_chip trusts a kernel shape class ONLY when the
-    on-chip gate marker lists it, all pins match, AND the backend is a
-    real TPU (utils/cache.py) — the runtime analog of the reference
-    gating its GPU learner on GPU_DEBUG_COMPARE passing. (Round 6:
-    tpu_hist_kernel=auto resolves to the MIXED dispatch on a real TPU iff
-    this trust record validates the booster's shape class, xla otherwise;
-    the explicit pallas/mixed knobs consult it to warn on un-gated shapes.)
-    """
-    import json
+def test_kernel_choice_is_a_function_of_config_only():
+    """tpu_hist_kernel resolves from committed code and configuration:
+    auto is the xla kernel, explicit values are honoured (f64 histograms
+    force xla), and no file on disk takes part — a fresh checkout and a
+    long-lived one train with the same kernel. chip_smoke.py is what proves
+    on the chip that `mixed` still compiles."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(0)
+    X = rng.rand(400, 4)
+    y = (X[:, 0] > 0.5).astype(float)
+    base = {"objective": "binary", "verbose": -1, "num_leaves": 7,
+            "min_data_in_leaf": 10, "max_bin": 15}
 
-    import jax
+    def resolved(**extra):
+        params = dict(base, **extra)
+        bst = lgb.Booster(params=params,
+                          train_set=lgb.Dataset(X, label=y, params=params))
+        return bst._gbdt.spec.hist_kernel
 
-    from lightgbm_tpu.utils import cache
-
-    marker = tmp_path / "ok.json"
-    monkeypatch.setattr(cache, "pallas_gate_marker_path",
-                        lambda: str(marker))
-    key = cache.pallas_config_key(1, 256, 25, 28, 5)
-    pins = {"jax": jax.__version__, "libtpu": cache._libtpu_version(),
-            "kernel_src": cache.pallas_kernel_source_hash(),
-            "configs": [key]}
-    # CPU backend: auto stays xla even with the marker present
-    marker.write_text(json.dumps(pins))
-    assert not cache.pallas_validated_on_chip(key)
-    # simulate a TPU backend: marker decides, per shape class
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert cache.pallas_validated_on_chip(key)
-    assert cache.pallas_validated_on_chip()          # any-config probe
-    assert not cache.pallas_validated_on_chip(
-        cache.pallas_config_key(2, 512, 8, 12, 5))   # un-gated shape
-    # a pre-per-config marker (no configs list) blesses nothing
-    marker.write_text(json.dumps({k: v for k, v in pins.items()
-                                  if k != "configs"}))
-    assert not cache.pallas_validated_on_chip(key)
-    # stale under a different jax, a different libtpu, or edited kernel code
-    for bad in ({"jax": "0.0.0-other"}, {"libtpu": "other"},
-                {"kernel_src": "beef"}):
-        marker.write_text(json.dumps({**pins, **bad}))
-        assert not cache.pallas_validated_on_chip(key), bad
-    marker.unlink()
-    assert not cache.pallas_validated_on_chip(key)
+    assert resolved() == "xla"
+    assert resolved(tpu_hist_kernel="auto") == "xla"
+    assert resolved(tpu_hist_kernel="mixed") == "mixed"
+    assert resolved(tpu_hist_kernel="pallas") == "pallas"
+    assert resolved(tpu_hist_kernel="mixed", tpu_hist_f64=True) == "xla"

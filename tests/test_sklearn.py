@@ -121,7 +121,7 @@ def test_early_stopping_eval_set():
 def test_predict_proba_custom_objective_returns_raw_unchanged():
     """Reference sklearn wrapper contract: under a customized objective,
     predict_proba warns and returns the RAW 1-D score array unchanged
-    (no probability stacking) — ADVICE r4 #3."""
+    (no probability stacking)."""
     import lightgbm_tpu as lgb
 
     rng = np.random.RandomState(7)

@@ -168,13 +168,13 @@ def test_r010_fires_on_broad_silent_handlers(tmp_path):
 
 
 def test_r010_intentional_sites_are_baseline_exempt():
-    """The two audited silent broad catches — comm.py's jax-private-state
-    fallback-of-the-fallback and cache.py's libtpu version probe — are
-    seen by R010 and absorbed by the committed baseline; the rest of the
+    """The audited silent broad catch — comm.py's jax-private-state
+    fallback-of-the-fallback — is seen by R010 and absorbed by the
+    committed baseline, and utils/cache.py has none left; the rest of the
     package (incl. robustness/) lints clean, which is the property the
     self-healing layer rides on."""
     bl = Baseline.load(os.path.join(REPO, "tpu_lint_baseline.json"))
-    for rel, n in ((("parallel", "comm.py"), 1), (("utils", "cache.py"), 1)):
+    for rel, n in ((("parallel", "comm.py"), 1), (("utils", "cache.py"), 0)):
         findings, err = lint_file(
             os.path.join(REPO, "lightgbm_tpu", *rel),
             rel="/".join(("lightgbm_tpu",) + rel))
