@@ -1030,15 +1030,22 @@ def run_smoke():
                     <= outer["ts"] + outer["dur"] + 1)
 
         trains = [e for e in events if e.get("name") == "train"]
-        iters_ev = [e for e in events if e.get("name") == "iteration"]
-        waves = [e for e in events if e.get("name") == "wave"]
+        batches = [e for e in events if e.get("name") == "tree_batch"]
+        dispatches = [e for e in events if e.get("name") == "step.dispatch"]
         assert trains, "no train span in trace"
-        assert iters_ev, "no iteration spans in trace"
-        assert waves, "no wave spans in trace"
+        assert batches, "no tree_batch spans in trace"
+        assert dispatches, "no step.dispatch spans in trace"
         nested = [
-            (t, i, w) for w in waves for i in iters_ev for t in trains
-            if _contains(i, w) and _contains(t, i)]
-        assert nested, "spans are not nested train -> iteration -> wave"
+            (t, b, d) for d in dispatches for b in batches for t in trains
+            if _contains(b, d) and _contains(t, b)
+            and d["parent_id"] == b["span_id"]]
+        assert nested, \
+            "spans are not nested train -> tree_batch -> step.dispatch"
+        # the waves are COUNTED by the loop on the device, one record per
+        # tree, published where the trees came to the host
+        waves = obs.get_registry().summary("grow.waves")
+        assert waves.count >= 6 and min(waves.values()) >= 1, \
+            f"grow.waves: {waves.count} trees, {waves.values()}"
         # JSONL stream carries the counter snapshot next to the events
         jl = [json.loads(ln) for ln in open(obs.jsonl_path())
               if ln.strip()]

@@ -49,7 +49,9 @@ def _to_2d_float(data):
         # without ever materializing the float matrix (reference accepts
         # CSR/CSC via LGBM_DatasetCreateFromCSR/CSC, c_api.cpp:471+)
         return data.tocsr(), None
-    arr = np.asarray(data, dtype=np.float64)
+    from . import observability as obs
+    with obs.setup_span("dataset.to_float"):      # a float64 COPY of float32
+        arr = np.asarray(data, dtype=np.float64)
     if arr.ndim == 1:
         arr = arr.reshape(1, -1)
     return arr, None

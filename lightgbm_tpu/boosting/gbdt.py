@@ -21,7 +21,7 @@ Semantics kept from the reference:
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,8 +31,9 @@ from .. import observability as obs
 from ..observability import costs as obs_costs
 from ..config import Config
 from ..dataset import ConstructedDataset, Metadata, MetadataDuckTyping
-from ..grower import GrowerSpec, TreeArrays, grow_tree, waves_for_tree
-from ..ops.histogram import table_lookup
+from ..grower import (GrowerSpec, TreeArrays, WaveStats, grow_tree,
+                      wave_totals)
+from ..ops.histogram import num_channels, table_lookup
 from ..parallel.comm import make_parallel_context
 from ..metrics import Metric, create_metrics
 from ..robustness import allowed_host_sync
@@ -73,6 +74,34 @@ from ..analysis.contracts.registry import trace_entry
 
 
 @trace_entry("train_step.fused")
+class GrowRecord(NamedTuple):
+    """What one iteration leaves on the device beside its trees, until the
+    trees are fetched: the leaf counts (the no-splits check reads them) and
+    the wave loop's own counters. One record so that a single output rides
+    through ``step_body`` / the ``tree_batch`` scan / the nan-policy tuple;
+    appended per iteration, popped on rollback, fetched lazily."""
+    num_leaves: jnp.ndarray           # i32 [K]
+    stats: Optional[WaveStats]        # leading axes [K, D]; None where the
+                                      # loop did not run in this process's
+                                      # step (checkpoint restore, streaming)
+
+
+def _abstract_signature(args) -> Dict[str, str]:
+    """``{path: "dtype[shape] committed|uncommitted"}`` of a dispatch's
+    arguments: what jit keys its trace cache on, as far as the host sees
+    it. Read only when the step has just been traced."""
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(args)[0]:
+        sig = f"{getattr(leaf, 'dtype', type(leaf).__name__)}" \
+              f"{list(getattr(leaf, 'shape', ()))}"
+        if hasattr(leaf, "committed"):
+            sig += " committed" if leaf.committed else " uncommitted"
+        if getattr(leaf, "weak_type", False):
+            sig += " weak"
+        out[jax.tree_util.keystr(path)] = sig
+    return out
+
+
 class GBDT:
     """Boosting driver (reference class GBDT, src/boosting/gbdt.h:25)."""
 
@@ -731,10 +760,13 @@ class GBDT:
         self.score = self._put(base, "rows1")
 
         self.models: List[List] = []        # per iteration: list of K device TreeArrays
-        self._num_leaves_dev: List = []     # per iteration: [K] device array
+        self._grow_records: List[GrowRecord] = []   # per iteration, on device
+        self._step_traces = 0               # times jax traced a step body
+        self._trace_signatures: Dict[str, Dict[str, str]] = {}   # per site
         self.iter_ = 0
         # telemetry high-water mark: iterations already counted into the
-        # monotonic trees.trained/rows.routed counters (publish_telemetry).
+        # monotonic trees.trained/rows.routed/grow.* metrics
+        # (_publish_grow_records, where the trees come to the host).
         # Checkpoint restore and repeated train() calls on one booster bump
         # it so restored/already-published iterations are never re-counted.
         self._telemetry_iters_base = 0
@@ -1184,26 +1216,30 @@ class GBDT:
         score: ``(new_score_k, [new_valid_k...])``. Linear trees swap the
         constant-leaf table lookup for the per-row linear epilogue
         (ops/linear.linear_leaf_scores) on both paths."""
-        if self.linear_tree:
-            from ..ops.linear import linear_leaf_scores
-            contrib = linear_leaf_scores(tree, leaf_ids, self.Xraw,
-                                         self.Xmiss)
-        else:
-            contrib = table_lookup(leaf_ids, tree.leaf_value)
-        new_score_k = self._score_update(score_k, contrib, it)
-        new_valid_k = []
-        for vi in range(len(valid_Xb)):
-            vleaf = leaves_from_binned(
-                tree, valid_Xb[vi], self.num_bins, self.missing_code,
-                self.default_bin,
-                use_categorical=self.spec.use_categorical)
+        with jax.named_scope("step.score_update"):
             if self.linear_tree:
                 from ..ops.linear import linear_leaf_scores
-                vs = self.valid_sets[vi]
-                vcontrib = linear_leaf_scores(tree, vleaf, vs.Xraw, vs.Xmiss)
+                contrib = linear_leaf_scores(tree, leaf_ids, self.Xraw,
+                                             self.Xmiss)
             else:
-                vcontrib = table_lookup(vleaf, tree.leaf_value)
-            new_valid_k.append(self._score_update(valid_k[vi], vcontrib, it))
+                contrib = table_lookup(leaf_ids, tree.leaf_value)
+            new_score_k = self._score_update(score_k, contrib, it)
+        new_valid_k = []
+        for vi in range(len(valid_Xb)):
+            with jax.named_scope("step.valid_update"):
+                vleaf = leaves_from_binned(
+                    tree, valid_Xb[vi], self.num_bins, self.missing_code,
+                    self.default_bin,
+                    use_categorical=self.spec.use_categorical)
+                if self.linear_tree:
+                    from ..ops.linear import linear_leaf_scores
+                    vs = self.valid_sets[vi]
+                    vcontrib = linear_leaf_scores(tree, vleaf, vs.Xraw,
+                                                  vs.Xmiss)
+                else:
+                    vcontrib = table_lookup(vleaf, tree.leaf_value)
+                new_valid_k.append(
+                    self._score_update(valid_k[vi], vcontrib, it))
         return new_score_k, new_valid_k
 
     # device-array attributes captured by the training step; under
@@ -1250,6 +1286,11 @@ class GBDT:
             # hook (_gradients/_sampling/RF/GOSS overrides) reads arguments,
             # not baked-in constants. Python-level state is restored after
             # tracing; compiled executions never run this body again.
+            # So this line counts TRACES, at no cost per dispatch
+            # (``_dispatch`` counts executables and records the signature
+            # that caused each).
+            self._step_traces += 1
+            obs.inc("compile.step_traces")
             saved = {a: getattr(self, a) for a in self._STEP_CONSTS}
             saved_vXb = [vs.Xb for vs in self.valid_sets]
             saved_vraw = [(vs.Xraw, vs.Xmiss) for vs in self.valid_sets]
@@ -1278,7 +1319,7 @@ class GBDT:
         def batch_body(score, valid_scores, bag_mask, key, it, shrinkage):
             # tree_batch fusion: `batch` whole iterations under ONE lax.scan
             # — the carry (scores, bagging mask, device iteration counter)
-            # stays in HBM between trees; per-iteration trees / leaf counts
+            # stays in HBM between trees; per-iteration trees / grow records
             # (/ non-finite flags) stack along the leading batch axis. The
             # scan body IS step_body, so K=1 and K>1 run identical math per
             # iteration (bit-identity is pinned by tests/test_tree_batch.py).
@@ -1306,42 +1347,49 @@ class GBDT:
             # exactly (fold_in is value-deterministic) with zero per-step
             # host->device transfers
             key = jax.random.fold_in(key, it)
-            if custom_grads:
-                g, h = grads
-            else:
-                g, h = self._gradients(score)
             bad_g = bad_h = bad_leaf = None
-            if nan_policy != "none":
-                # detect BEFORE any sanitizing so every policy can report
-                # which of g/h/leaf went non-finite
-                bad_g, bad_h = nonfinite_flag(g), nonfinite_flag(h)
-                if nan_policy == "clip":
-                    g, h = clip_nonfinite(g), clip_nonfinite(h)
+            with jax.named_scope("step.gradients"):
+                if custom_grads:
+                    g, h = grads
+                else:
+                    g, h = self._gradients(score)
+                if nan_policy != "none":
+                    # detect BEFORE any sanitizing so every policy can
+                    # report which of g/h/leaf went non-finite
+                    bad_g, bad_h = nonfinite_flag(g), nonfinite_flag(h)
+                    if nan_policy == "clip":
+                        g, h = clip_nonfinite(g), clip_nonfinite(h)
             bkey, fkey = jax.random.split(jax.random.fold_in(key, 0))
-            mask, g, h = self._sampling(g, h, bag_mask, bkey, it)
+            with jax.named_scope("step.sampling"):
+                mask, g, h = self._sampling(g, h, bag_mask, bkey, it)
             trees = []
             nleaves = []
+            wave_stats = []
             new_scores = []
             new_valid = [list(vs) for vs in valid_scores] if valid_scores else []
             vXb = tuple(vs.Xb for vs in self.valid_sets)
             for k in range(K):
-                fmask = self._feature_mask(fkey, k)
-                tree, leaf_ids = grow(
-                    self.Xb, g[k] * mask, h[k] * mask, mask, fmask, self.is_cat,
-                    self.num_bins, self.missing_code, self.default_bin)
-                if self.linear_tree:
-                    # per-leaf ridge fit (ops/linear.py): same masked g/h
-                    # the tree grew on, BEFORE shrinkage so the intercept
-                    # and coefficients scale together (Tree::Shrinkage)
-                    from ..ops.linear import fit_linear_leaves
-                    tree = fit_linear_leaves(
-                        tree, self.Xraw, self.Xmiss, leaf_ids,
-                        g[k] * mask, h[k] * mask, mask, self.is_cat,
-                        max_features=self.config.linear_max_features,
-                        linear_lambda=self.config.linear_lambda,
-                        chunk_rows=spec.chunk_rows,
-                        max_steps=self._linear_max_steps)
-                tree, bl = self._shrink_transform_flag(tree, shrinkage)
+                with jax.named_scope("step.grow"):
+                    fmask = self._feature_mask(fkey, k)
+                    tree, leaf_ids, stats = grow(
+                        self.Xb, g[k] * mask, h[k] * mask, mask, fmask,
+                        self.is_cat, self.num_bins, self.missing_code,
+                        self.default_bin)
+                    if self.linear_tree:
+                        # per-leaf ridge fit (ops/linear.py): same masked
+                        # g/h the tree grew on, BEFORE shrinkage so the
+                        # intercept and coefficients scale together
+                        # (Tree::Shrinkage)
+                        from ..ops.linear import fit_linear_leaves
+                        tree = fit_linear_leaves(
+                            tree, self.Xraw, self.Xmiss, leaf_ids,
+                            g[k] * mask, h[k] * mask, mask, self.is_cat,
+                            max_features=self.config.linear_max_features,
+                            linear_lambda=self.config.linear_lambda,
+                            chunk_rows=spec.chunk_rows,
+                            max_steps=self._linear_max_steps)
+                with jax.named_scope("step.score_update"):
+                    tree, bl = self._shrink_transform_flag(tree, shrinkage)
                 if bl is not None:
                     bad_leaf = bl if bad_leaf is None else (bad_leaf | bl)
                 new_score_k, new_valid_k = self._tree_score_updates(
@@ -1352,11 +1400,15 @@ class GBDT:
                     new_valid[vi][k] = new_valid_k[vi]
                 trees.append(tree)
                 nleaves.append(tree.num_leaves)
+                wave_stats.append(stats)
             out_score = jnp.stack(new_scores)
             out_valid = tuple(tuple(v) for v in new_valid)
+            record = GrowRecord(
+                jnp.stack(nleaves),
+                jax.tree.map(lambda *a: jnp.stack(a), *wave_stats))
             if nan_policy == "none":
                 return (out_score, out_valid, mask, tuple(trees),
-                        jnp.stack(nleaves), it + 1)
+                        record, it + 1)
             nf = jnp.stack([bad_g, bad_h, bad_leaf])
             if nan_policy in ("raise", "skip_iter"):
                 # hardware-gate every output on the poison flag: a poisoned
@@ -1371,7 +1423,7 @@ class GBDT:
                     for old_vs, new_vs in zip(valid_scores, out_valid))
                 mask = jnp.where(bad, bag_mask, mask)
             return (out_score, out_valid, mask, tuple(trees),
-                    jnp.stack(nleaves), it + 1, nf)
+                    record, it + 1, nf)
 
         # donate the training-step carry (positions: score=2,
         # valid_scores=3, and under bagging bag_mask=4) — every one is
@@ -1410,6 +1462,39 @@ class GBDT:
         consts, valid_Xb = self._step_consts()
         return consts, valid_Xb, valid_scores
 
+    def _dispatch(self, site: str, fn, args):
+        """The jitted call itself, under the ``step.dispatch`` span: an
+        enqueue in steady state; trace and/or compile (or cache load) on a
+        call whose signature jit has not seen. Two counters tell which:
+        ``compile.step_traces`` moves when jax ran the step's Python body
+        again, ``compile.step_executables`` when the jitted function's
+        cache gained an entry (``_cache_size``, as RecompileGuard reads
+        it): a new executable was compiled or loaded, WITH OR WITHOUT a
+        retrace — arguments that only change from uncommitted to committed
+        reuse the trace and still pay the compile. Either way the instant
+        event ``compile.step_trace`` records the arguments' abstract
+        signature and which of its leaves differ from this site's previous
+        one: the answer to "what recompiled, and why"."""
+        before = (self._step_traces, fn._cache_size())
+        with obs.span("step.dispatch", site=site):
+            outs = fn(*args)
+        after = (self._step_traces, fn._cache_size())
+        if after != before:
+            obs.inc("compile.step_executables", after[1] - before[1])
+            sig = _abstract_signature(args)
+            last = self._trace_signatures.get(site, sig)
+            changed = {k: f"{last.get(k)} -> {v}" for k, v in sig.items()
+                       if last.get(k) != v}
+            self._trace_signatures[site] = sig
+            obs.event("compile.step_trace", site=site, traces=after[0],
+                      retraced=after[0] != before[0],
+                      executables=after[1], changed=changed, signature=sig)
+            if changed:
+                Log.info("%s: a new executable (number %d of this step; "
+                         "retraced: %s) because %s", site, after[1],
+                         after[0] != before[0], changed)
+        return outs
+
     def _capture_step_cost(self, site: str, fn, args, batch: int) -> None:
         """Cost-report leg of the dispatch protocol (observability/costs.py,
         gated on ``costs.enabled()`` by the callers): lower+compile the SAME
@@ -1434,45 +1519,51 @@ class GBDT:
     def _run_step(self, score, shrinkage: float, custom_gh=None):
         """Dispatch one compiled step against current state; returns new score
         and per-valid score tuples (device)."""
-        if custom_gh is None:
-            if self._step_fn is None:
-                self._step_fn = self._make_step()
-            fn, extra = self._step_fn, ()
-        else:
-            if self._custom_step_fn is None:
-                self._custom_step_fn = self._make_step(custom_grads=True)
-            fn, extra = self._custom_step_fn, custom_gh
-        consts, valid_Xb, valid_scores = self._dispatch_prep(shrinkage)
-        args = (consts, valid_Xb, score, valid_scores, self.bag_mask,
-                self._rng_key, self._iter_dev, self._shrink_cache[1], *extra)
-        if obs_costs.enabled():
-            # compile-time cost report of THIS dispatch signature — captured
-            # once per (site, executable), before the first call so the AOT
-            # compile primes the persistent cache the dispatch then hits
-            self._capture_step_cost(
-                "train_step.k1" + (".custom" if custom_gh is not None
-                                   else ""), fn, args, 1)
-        outs = fn(*args)
-        nf = None
-        if self.nan_policy != "none":
-            score, out_valid, self.bag_mask, trees, nl, self._iter_dev, nf = outs
-        else:
-            score, out_valid, self.bag_mask, trees, nl, self._iter_dev = outs
-        self.models.append(list(trees))
-        self._num_leaves_dev.append(nl)
-        self.iter_ += 1
-        self.mutations_ = getattr(self, "mutations_", 0) + 1
-        if nf is not None:
-            try:
-                self._apply_nan_policy(nf)
-            except Exception:
-                # the pre-step buffers were DONATED to the step — rebind the
-                # (gated, bit-identical) outputs before propagating so the
-                # booster stays usable and checkpointable after the failure
-                self.score = score
-                for vi, vs in enumerate(self.valid_sets):
-                    vs.score = jnp.stack(out_valid[vi])
-                raise
+        site = "train_step.k1" + (".custom" if custom_gh is not None else "")
+        with obs.span("step.prep"):
+            if custom_gh is None:
+                if self._step_fn is None:
+                    self._step_fn = self._make_step()
+                fn, extra = self._step_fn, ()
+            else:
+                if self._custom_step_fn is None:
+                    self._custom_step_fn = self._make_step(custom_grads=True)
+                fn, extra = self._custom_step_fn, custom_gh
+            consts, valid_Xb, valid_scores = self._dispatch_prep(shrinkage)
+            args = (consts, valid_Xb, score, valid_scores, self.bag_mask,
+                    self._rng_key, self._iter_dev, self._shrink_cache[1],
+                    *extra)
+            if obs_costs.enabled():
+                # compile-time cost report of THIS dispatch signature —
+                # captured once per (site, executable), before the first
+                # call so the AOT compile primes the persistent cache the
+                # dispatch then hits
+                self._capture_step_cost(site, fn, args, 1)
+        outs = self._dispatch(site, fn, args)
+        with obs.span("step.post"):
+            nf = None
+            if self.nan_policy != "none":
+                (score, out_valid, self.bag_mask, trees, record,
+                 self._iter_dev, nf) = outs
+            else:
+                (score, out_valid, self.bag_mask, trees, record,
+                 self._iter_dev) = outs
+            self.models.append(list(trees))
+            self._grow_records.append(record)
+            self.iter_ += 1
+            self.mutations_ = getattr(self, "mutations_", 0) + 1
+            if nf is not None:
+                try:
+                    self._apply_nan_policy(nf)
+                except Exception:
+                    # the pre-step buffers were DONATED to the step — rebind
+                    # the (gated, bit-identical) outputs before propagating
+                    # so the booster stays usable and checkpointable after
+                    # the failure
+                    self.score = score
+                    for vi, vs in enumerate(self.valid_sets):
+                        vs.score = jnp.stack(out_valid[vi])
+                    raise
         return score, out_valid
 
     def _record_nan_event(self, what: str, iteration: int) -> None:
@@ -1685,7 +1776,9 @@ class GBDT:
         else:
             score, out_valid, self.bag_mask, nl, self._iter_dev = outs
         self.models.append(list(trees))
-        self._num_leaves_dev.append(nl)
+        # the host drives a streamed tree's waves: no loop on the device,
+        # so no record of one
+        self._grow_records.append(GrowRecord(nl, None))
         self.iter_ += 1
         self.mutations_ = getattr(self, "mutations_", 0) + 1
         if nf is not None:
@@ -1720,48 +1813,53 @@ class GBDT:
             for _ in range(n):
                 self.train_one_iter()
             return
-        base_iter = self.iter_
-        with TIMERS("train_step"), obs.span("tree_batch", k=n):
+        # the fused scan is ONE dispatch: there is no host boundary between
+        # its iterations, so no per-iteration span (the device trace's
+        # ``step.*`` scopes show them)
+        with TIMERS("train_step"), \
+                obs.span("tree_batch", k=n, iteration=self.iter_):
             self._run_fused_batch(n)
-        # the fused scan is ONE dispatch — per-iteration spans inside it are
-        # derived (even slices of the batch span, labeled as such); recorded
-        # after the span closes, host-side only
-        obs.get_tracer().subdivide_last("tree_batch", "iteration", n,
-                                        base_iteration=base_iter)
 
     def _run_fused_batch(self, n: int) -> None:
-        fn = self._batch_step_fns.get(n)
-        if fn is None:
-            fn = self._make_step(batch=n)
-            self._batch_step_fns[n] = fn
-        consts, valid_Xb, valid_scores = self._dispatch_prep(
-            self._step_shrinkage())
-        args = (consts, valid_Xb, self.score, valid_scores, self.bag_mask,
-                self._rng_key, self._iter_dev, self._shrink_cache[1])
-        if obs_costs.enabled():
-            self._capture_step_cost(f"train_step.k{n}", fn, args, n)
-        outs = fn(*args)
-        nf = None
-        if self.nan_policy != "none":
-            score, out_valid, self.bag_mask, trees, nl, self._iter_dev, nf = outs
-        else:
-            score, out_valid, self.bag_mask, trees, nl, self._iter_dev = outs
-        # per-iteration bookkeeping from the stacked batch outputs: lazy
-        # device-side slices (no host sync), so checkpoints / rollback /
-        # finalize keep their list-of-iterations contract unchanged
-        base_iter = self.iter_
-        base_len = len(self.models)
-        for i in range(n):
-            self.models.append([
-                jax.tree.map(lambda x, i=i: x[i], tk) for tk in trees])
-            self._num_leaves_dev.append(nl[i])
-        self.iter_ += n
-        self.mutations_ = getattr(self, "mutations_", 0) + n
-        self.score = score
-        for vi, vs in enumerate(self.valid_sets):
-            vs.score = jnp.stack(out_valid[vi])
-        if nf is not None:
-            self._apply_nan_policy_batch(nf, base_iter, base_len, n)
+        site = f"train_step.k{n}"
+        with obs.span("step.prep"):
+            fn = self._batch_step_fns.get(n)
+            if fn is None:
+                fn = self._make_step(batch=n)
+                self._batch_step_fns[n] = fn
+            consts, valid_Xb, valid_scores = self._dispatch_prep(
+                self._step_shrinkage())
+            args = (consts, valid_Xb, self.score, valid_scores,
+                    self.bag_mask, self._rng_key, self._iter_dev,
+                    self._shrink_cache[1])
+            if obs_costs.enabled():
+                self._capture_step_cost(site, fn, args, n)
+        outs = self._dispatch(site, fn, args)
+        with obs.span("step.post"):
+            nf = None
+            if self.nan_policy != "none":
+                (score, out_valid, self.bag_mask, trees, records,
+                 self._iter_dev, nf) = outs
+            else:
+                (score, out_valid, self.bag_mask, trees, records,
+                 self._iter_dev) = outs
+            # per-iteration bookkeeping from the stacked batch outputs: lazy
+            # device-side slices (no host sync), so checkpoints / rollback /
+            # finalize keep their list-of-iterations contract unchanged
+            base_iter = self.iter_
+            base_len = len(self.models)
+            for i in range(n):
+                self.models.append([
+                    jax.tree.map(lambda x, i=i: x[i], tk) for tk in trees])
+                self._grow_records.append(
+                    jax.tree.map(lambda x, i=i: x[i], records))
+            self.iter_ += n
+            self.mutations_ = getattr(self, "mutations_", 0) + n
+            self.score = score
+            for vi, vs in enumerate(self.valid_sets):
+                vs.score = jnp.stack(out_valid[vi])
+            if nf is not None:
+                self._apply_nan_policy_batch(nf, base_iter, base_len, n)
 
     @allowed_host_sync("nan_policy guard: one [K, 3] flag fetch per fused "
                        "batch, only while the guard is enabled")
@@ -1822,7 +1920,7 @@ class GBDT:
             Log.warning("nan_policy=skip_iter: dropped iteration %d "
                         "(non-finite %s)", base_iter + int(i), _what(i))
             del self.models[base_len + int(i)]
-            del self._num_leaves_dev[base_len + int(i)]
+            del self._grow_records[base_len + int(i)]
         self.mutations_ = getattr(self, "mutations_", 0) + 1
         # consecutive-skip accounting walks the batch in order
         for i in range(n):
@@ -1899,7 +1997,7 @@ class GBDT:
         if not self.models:
             return
         trees = self.models.pop()
-        self._num_leaves_dev.pop()
+        self._grow_records.pop()
         self.iter_ -= 1
         self.mutations_ = getattr(self, "mutations_", 0) + 1
         self._iter_dev = None           # device counter resyncs next step
@@ -2001,7 +2099,7 @@ class GBDT:
         scores (the no-splits pop; a nan_policy-gated no-op step). Contrast
         rollback_one_iter, which also subtracts the trees' contribution."""
         self.models.pop()
-        self._num_leaves_dev.pop()
+        self._grow_records.pop()
         self.iter_ -= 1
         self.mutations_ = getattr(self, "mutations_", 0) + 1
         self._iter_dev = None           # device counter resyncs next step
@@ -2013,8 +2111,8 @@ class GBDT:
         metric_freq>1) several zero-value single-leaf trees can accumulate
         between checks."""
         popped = False
-        while self._num_leaves_dev and \
-                (np.asarray(self._num_leaves_dev[-1]) <= 1).all():
+        while self._grow_records and \
+                (np.asarray(self._grow_records[-1].num_leaves) <= 1).all():
             self._pop_last_iteration()
             popped = True
         if popped:
@@ -2141,7 +2239,8 @@ class GBDT:
             "bag_mask": np.asarray(self._fetch(self.bag_mask), np.float32),
             "rng_key": np.asarray(self._rng_key),
             "models": jax.device_get(self.models),
-            "num_leaves": jax.device_get(self._num_leaves_dev),
+            "num_leaves": jax.device_get(
+                [r.num_leaves for r in self._grow_records]),
             "valid_scores": {vs.name: np.asarray(vs.score)
                              for vs in self.valid_sets},
             "best_iteration": int(getattr(self, "best_iteration", 0)),
@@ -2240,7 +2339,10 @@ class GBDT:
         self._rng_key = self._put(np.asarray(state["rng_key"]))
         self.models = [[jax.tree.map(self._put, t) for t in it_trees]
                        for it_trees in state["models"]]
-        self._num_leaves_dev = [self._put(nl) for nl in state["num_leaves"]]
+        # restored iterations were grown (and their waves counted) by the
+        # run that wrote the snapshot: leaf counts only
+        self._grow_records = [GrowRecord(self._put(nl), None)
+                              for nl in state["num_leaves"]]
         self.iter_ = int(state["iter"])
         # restored iterations were trained (and counted) by the run that
         # wrote the snapshot — telemetry must only count what THIS run adds
@@ -2263,53 +2365,105 @@ class GBDT:
 
     # -------------------------------------------------------------- telemetry
 
-    @allowed_host_sync("telemetry flush: one per-training-run leaf-count "
-                       "fetch at an iteration boundary, only while span "
-                       "recording is enabled")
+    def _fetch_records(self, records: List[GrowRecord]) -> List[GrowRecord]:
+        """Grow records as host values. Single process: one ``device_get``
+        (callers batch it with the trees). Multi-host: the counters are
+        one row per DEVICE, so each process reads the rows of its own
+        devices and publishes its own shards' maximum."""
+        if not self.pctx.multi_process:
+            return jax.device_get(records)
+
+        def local_rows(a):          # [K, D, ...] -> [K, D_local, ...]
+            return np.concatenate([np.asarray(sh.data)
+                                   for sh in a.addressable_shards], axis=1)
+        return [GrowRecord(np.asarray(r.num_leaves),
+                           None if r.stats is None
+                           else jax.tree.map(local_rows, r.stats))
+                for r in records]
+
+    def _publish_grow_records(self, host_records: List[GrowRecord]) -> None:
+        """Publish what the iterations above the high-water mark counted
+        about themselves (``host_records``: their fetched records, in
+        order) to the process-wide registry: trees trained, leaves per
+        tree, and — per tree, in tree order, from the wave loop's own
+        record (grower.WaveStats / wave_totals), nothing modelled —
+        ``grow.waves``, ``grow.hist_rows_touched``, ``grow.hist_rows_active``,
+        ``grow.rows_split``, ``grow.compact_passes``, ``grow.stream_passes``
+        and the counters ``rows.routed`` and ``hist.mxu_flops`` /
+        ``hist.floor_flops``. Under a row-sharded mesh every row count is
+        the pace-setting shard's (per-wave maximum over devices): compare
+        with the rows of ONE device."""
+        self._telemetry_iters_base = len(self.models)
+        if not host_records:
+            return
+        reg = obs.get_registry()
+        reg.counter("trees.trained").inc(len(host_records) * self.num_models)
+        leaf_hist = reg.histogram("tree.leaves")
+        for rec in host_records:
+            for leaves in np.asarray(rec.num_leaves).reshape(-1):
+                leaf_hist.observe(int(leaves))
+        counted = [rec.stats for rec in host_records if rec.stats is not None]
+        if not counted:
+            return
+        spec = self.spec
+        n_dev = self.pctx.num_devices
+        rows = self.num_data_padded // (
+            n_dev if self.pctx.strategy in ("data", "voting") else 1)
+        # a compacted pass runs whole chunks of its kernel's own size
+        chunk = min(spec.chunk_rows, 512) \
+            if spec.hist_kernel in ("pallas", "mixed") else spec.chunk_rows
+        # MACs per histogrammed row: every code of the device's feature
+        # block against every (padded) bin, into S slots x ch weight
+        # channels (the kernel's formulation) or into the 3 channels no
+        # histogram GBDT can avoid (the floor)
+        cells = (self.Xb.shape[1]
+                 // (n_dev if self.pctx.strategy == "feature" else 1)) \
+            * (spec.hist_bins or spec.num_bins_padded)
+        ch = num_channels("f32" if spec.hist_f64 else spec.hist_hilo)
+        for stats in counted:
+            for k in range(self.num_models):
+                t = wave_totals(jax.tree.map(lambda a, k=k: a[k], stats),
+                                rows, chunk)
+                for name in ("waves", "hist_rows_touched", "hist_rows_active",
+                             "rows_split", "compact_passes", "stream_passes"):
+                    if t[name] is not None:
+                        reg.summary("grow." + name).observe(t[name])
+                reg.counter("rows.routed").inc(t["rows_routed"])
+                reg.counter("hist.mxu_flops").inc(
+                    2 * t["hist_rows_touched"] * cells * spec.hist_slots * ch)
+                reg.counter("hist.floor_flops").inc(
+                    2 * t["hist_rows_touched"] * cells * 3)
+
+    @allowed_host_sync("telemetry flush: one fetch of the iterations' small "
+                       "grow records at the end of a training run, beside "
+                       "the tree fetch that follows it")
     def publish_telemetry(self) -> None:
         """Flush this booster's per-run training facts into the telemetry
-        subsystem (engine.train calls it once, after the loop): trained-tree
-        and routed-row counters always; with span recording enabled, one
-        batched leaf-count fetch derives the per-tree wave counts
-        (grower.waves_for_tree — a host-side model of the wave loop, no
-        per-wave device traffic) that become the ``wave`` child spans of
-        each recorded ``iteration`` span and the ``tree.waves``/
-        ``tree.leaves`` histograms."""
-        reg = obs.get_registry()
+        subsystem (engine.train calls it once, after the loop, on every
+        exit path): the records of the iterations not yet published,
+        through ``_publish_grow_records`` — the same publication
+        ``finalize_model`` makes when the trees come to the host first."""
         base = min(self._telemetry_iters_base, len(self.models))
-        n_new = len(self.models) - base
-        self._telemetry_iters_base = len(self.models)
-        if n_new:
-            # only the iterations THIS run trained: restored-checkpoint and
-            # already-published iterations sit below the high-water mark
-            reg.counter("trees.trained").inc(n_new * self.num_models)
-            reg.counter("rows.routed").inc(
-                n_new * self.num_models * self.num_data)
-        if not obs.enabled() or not n_new:
-            return
-        leaves = jax.device_get(self._num_leaves_dev[base:])  # [n_new][K]
-        wave_hist = reg.histogram("tree.waves")
-        leaf_hist = reg.histogram("tree.leaves")
-        counts = []
-        for nl in leaves:
-            nl = np.atleast_1d(np.asarray(nl))
-            # K trees grow concurrently inside one iteration's dispatch;
-            # the iteration's wave count is the deepest tree's
-            counts.append(max(waves_for_tree(int(v), self.spec.wave_size,
-                                             self.spec.hist_slots)
-                              for v in nl))
-            wave_hist.observe(counts[-1])
-            for v in nl:
-                leaf_hist.observe(int(v))
-        obs.get_tracer().derive_children("iteration", "wave", counts)
+        self._publish_grow_records(
+            self._fetch_records(self._grow_records[base:]))
 
     # ------------------------------------------------------------------ model
 
     def finalize_model(self) -> List[List[Tree]]:
         """Fetch device trees to host Tree objects (one transfer), fold the
-        boost-from-average bias into the first tree (gbdt.cpp:445-447)."""
-        with TIMERS("finalize_fetch"):
-            host = jax.device_get(self.models)
+        boost-from-average bias into the first tree (gbdt.cpp:445-447).
+        The grow records not yet published ride in the same transfer and
+        are published here: wherever the trees come to the host, the wave
+        loop's counters come with them, and nowhere else."""
+        base = min(self._telemetry_iters_base, len(self.models))
+        with TIMERS("finalize_fetch"), obs.setup_span("finalize.fetch"):
+            if self.pctx.multi_process:
+                host = jax.device_get(self.models)
+                records = self._fetch_records(self._grow_records[base:])
+            else:
+                host, records = jax.device_get(
+                    (self.models, self._grow_records[base:]))
+        self._publish_grow_records(records)
         mappers = self.train_set.mappers
         rfi = self.train_set.real_feature_idx
         forest: List[List[Tree]] = []

@@ -478,23 +478,26 @@ def construct_dataset(
     cat_set.update(_parse_column_spec(config.categorical_column, feature_names))
     ignore_set = set(_parse_column_spec(config.ignore_column, feature_names))
 
-    # sampling (dataset_loader.cpp:688-746)
-    _, per_feature_samples = sample_for_binning(
-        data, config.bin_construct_sample_cnt, config.data_random_seed)
-    total_sample_cnt = min(num_data, config.bin_construct_sample_cnt)
-    # reference: filter_cnt = min_data_in_leaf * sample / num_data (dataset_loader.cpp:495)
-    filter_cnt = int(config.min_data_in_leaf * total_sample_cnt / max(num_data, 1))
+    from . import observability as obs
+    # bin finding: the row sample, then the quantiles of each feature
+    with obs.setup_span("dataset.find_bins"):
+        # sampling (dataset_loader.cpp:688-746)
+        _, per_feature_samples = sample_for_binning(
+            data, config.bin_construct_sample_cnt, config.data_random_seed)
+        total_sample_cnt = min(num_data, config.bin_construct_sample_cnt)
+        # reference: filter_cnt = min_data_in_leaf * sample / num_data (dataset_loader.cpp:495)
+        filter_cnt = int(config.min_data_in_leaf * total_sample_cnt / max(num_data, 1))
 
-    def _find_one(j: int) -> BinMapper:
-        mapper = BinMapper()
-        bin_type = BIN_CATEGORICAL if j in cat_set else BIN_NUMERICAL
-        mapper.find_bin(per_feature_samples[j], total_sample_cnt,
-                        config.max_bin, config.min_data_in_bin, filter_cnt,
-                        bin_type, config.use_missing, config.zero_as_missing)
-        return mapper
+        def _find_one(j: int) -> BinMapper:
+            mapper = BinMapper()
+            bin_type = BIN_CATEGORICAL if j in cat_set else BIN_NUMERICAL
+            mapper.find_bin(per_feature_samples[j], total_sample_cnt,
+                            config.max_bin, config.min_data_in_bin, filter_cnt,
+                            bin_type, config.use_missing, config.zero_as_missing)
+            return mapper
 
-    active = [j for j in range(num_total_features) if j not in ignore_set]
-    mappers_by_idx = _find_bins(active, _find_one, config)
+        active = [j for j in range(num_total_features) if j not in ignore_set]
+        mappers_by_idx = _find_bins(active, _find_one, config)
     features: List[FeatureInfo] = [
         FeatureInfo(j, mappers_by_idx[j]) for j in active
         if not mappers_by_idx[j].is_trivial]

@@ -121,6 +121,73 @@ def decode_bundled_bin(Xb: jnp.ndarray, f: jnp.ndarray,
     return jnp.where(in_rng, c - bundle.off[f], default_bin[f])
 
 
+class WaveStats(NamedTuple):
+    """What the resident wave loop counts about its own work, exactly, as
+    it runs: one small record per tree, written once per wave at index
+    ``waves`` and carried in the loop's state (``GrowState.stats``). At
+    most ``num_leaves - 1`` waves run (each wave but the last applies a
+    split), so the per-wave arrays have that length — a few KB at 255
+    leaves, no per-row array, int32 exact up to 2^31 rows per device.
+
+    Under ``shard_map`` each device counts its OWN row shard; the step
+    returns the record with a leading device axis and the host takes the
+    per-wave maximum over devices, the shard that sets the pace
+    (``wave_totals``)."""
+    waves: jnp.ndarray          # i32 []  waves run for this tree
+    rows_active: jnp.ndarray    # i32 [W] rows of the pending leaves, what
+                                # the histogram pass usefully reads (-1:
+                                # not counted, row_compact is off)
+    compacted: jnp.ndarray      # bool [W] the pass was compacted: the
+                                # lax.cond's own predicate
+    rows_split: jnp.ndarray     # i32 [W] rows of the leaves split this
+                                # wave: what routing and the partition
+                                # usefully move
+
+
+def _empty_stats(L: int) -> WaveStats:
+    W = max(L - 1, 1)
+    return WaveStats(waves=jnp.asarray(0, jnp.int32),
+                     rows_active=jnp.zeros(W, jnp.int32),
+                     compacted=jnp.zeros(W, bool),
+                     rows_split=jnp.zeros(W, jnp.int32))
+
+
+def wave_totals(stats, rows_per_device: int, chunk_rows: int) -> Dict[str, int]:
+    """Per-tree totals the host derives from one tree's ``WaveStats`` as
+    fetched (numpy, leading device axis ``[D, ...]``), with no model of the
+    loop: every number is a sum over the waves the loop itself recorded.
+    With several devices each per-wave term is the maximum over devices.
+
+    ``hist_rows_touched`` is what the histogram kernel passed over: all of
+    a device's rows for a streamed pass, ``ceil(rows_active / chunk) *
+    chunk`` for a compacted one (build_histograms' trip count).
+    ``hist_rows_active`` is the useful part of it (None where the loop did
+    not count it). Routing and the partition pass over every row every
+    wave (``rows_routed``); ``rows_split`` is the useful part of that."""
+    waves = int(np.max(stats.waves))
+
+    def per_wave(a, dtype):                              # -> [D, waves]
+        return np.asarray(a, dtype).reshape(-1, np.shape(a)[-1])[:, :waves]
+
+    active = per_wave(stats.rows_active, np.int64)
+    compacted = per_wave(stats.compacted, bool)
+    split = per_wave(stats.rows_split, np.int64)
+    chunks = np.minimum(-(-np.maximum(active, 0) // chunk_rows),
+                        rows_per_device // chunk_rows)
+    touched = np.where(compacted, chunks * chunk_rows, rows_per_device)
+    # a wave counts as streamed when any shard streams it: that shard sets
+    # the wave's pace
+    streamed = int((~compacted).any(axis=0).sum())
+    return {"waves": waves,
+            "stream_passes": streamed,
+            "compact_passes": waves - streamed,
+            "hist_rows_touched": int(touched.max(axis=0).sum()),
+            "hist_rows_active": (int(active.max(axis=0).sum())
+                                 if (active >= 0).all() else None),
+            "rows_routed": waves * int(rows_per_device),
+            "rows_split": int(split.max(axis=0).sum())}
+
+
 class GrowState(NamedTuple):
     """Wave-loop carry. Buffer lifetime note: everything here — including
     the [L+1, F, B, 3] histogram cache, the largest allocation after the
@@ -156,6 +223,9 @@ class GrowState(NamedTuple):
     perm: Optional[jnp.ndarray] = None       # i32 [N] leaf-contiguous rows
     seg_start: Optional[jnp.ndarray] = None  # i32 [L+1]
     seg_rows: Optional[jnp.ndarray] = None   # i32 [L+1]
+    # the loop's own counters (resident loop only; None under streaming,
+    # where the host drives the waves)
+    stats: Optional[WaveStats] = None
 
 
 @dataclass(frozen=True)
@@ -243,20 +313,6 @@ class GrowerSpec:
                     min_data_per_group=self.min_data_per_group)
 
 
-def waves_for_tree(num_leaves: int, wave_size: int, hist_slots: int) -> int:
-    """Host-side wave-count model of the while_loop below, for telemetry
-    attribution (GBDT.publish_telemetry): a tree that finished with
-    ``num_leaves`` leaves applied ``num_leaves - 1`` splits in batches of
-    ``min(wave_size, hist_slots)`` — the cap step 5's top_k enforces. The
-    count is derived from the finished tree alone (no per-wave device
-    traffic); it undercounts by the terminal no-split wave when growth
-    stopped on gain rather than the leaf budget, which the derived "wave"
-    spans document via their ``derived`` tag."""
-    cap = max(1, min(wave_size, hist_slots) if wave_size > 0 else hist_slots)
-    splits = max(0, int(num_leaves) - 1)
-    return max(1, -(-splits // cap))
-
-
 def _empty_tree(L: int, B: int) -> TreeArrays:
     M = L - 1
     return TreeArrays(
@@ -309,6 +365,7 @@ def _empty_cand(L: int, B: int) -> SplitCandidates:
     )
 
 
+@jax.named_scope("wave.split")
 def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
                        leaf_of_slot: jnp.ndarray, bm, spec: "GrowerSpec",
                        comm, scan_bundle: Optional[BundleDecode],
@@ -323,7 +380,9 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     Shared VERBATIM by the resident wave body (``grow_tree``) and the
     streamed ``wave_update`` (``StreamedGrower``): residency is a transport
     decision, so the split math must have exactly one home or the two
-    modes drift apart bit by bit. ``new_hist`` arrives post-``reduce_hist``
+    modes drift apart bit by bit (and its device operations carry the
+    scope ``wave.split`` in both; ``_route_rows``: ``wave.route``).
+    ``new_hist`` arrives post-``reduce_hist``
     (and post-early-unbundle where that applies); ``scan_bundle`` is the
     EFB decode table when the histograms are bundle-space — with
     ``spec.efb_unpack`` the LEGACY arm unpacks them to feature space here
@@ -496,14 +555,16 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
                                                          mode="drop")
 
     done = (n_apply == 0) | (state.num_leaves_cur + n_apply >= L)
-    state2 = GrowState(t, state.leaf_id, hist, sum_g, sum_h, cnt, leaf_depth,
-                       leaf_is_right, cand, needs_hist, sib_leaf, parent_cache,
-                       state.num_leaves_cur + n_apply, done,
-                       state.perm, state.seg_start, state.seg_rows)
+    state2 = state._replace(
+        tree=t, hist=hist, sum_g=sum_g, sum_h=sum_h, cnt=cnt,
+        leaf_depth=leaf_depth, leaf_is_right=leaf_is_right, cand=cand,
+        needs_hist=needs_hist, sib_leaf=sib_leaf, parent_cache=parent_cache,
+        num_leaves_cur=state.num_leaves_cur + n_apply, done=done)
     return state2, table, map_mask, p, q, n_apply
 
 
 @trace_entry("routing.bundle_space")
+@jax.named_scope("wave.route")
 def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: jnp.ndarray,
                 map_mask: Optional[jnp.ndarray], spec: "GrowerSpec",
                 bundle: Optional[BundleDecode], default_bin: jnp.ndarray):
@@ -575,8 +636,9 @@ def grow_tree(
     spec: GrowerSpec,
     comm=None,
     bundle: Optional[BundleDecode] = None,
-) -> Tuple[TreeArrays, jnp.ndarray]:
-    """Grow one tree; returns (tree arrays, final leaf_id per row).
+) -> Tuple[TreeArrays, jnp.ndarray, WaveStats]:
+    """Grow one tree; returns (tree arrays, final leaf_id per row, the
+    loop's own per-wave counters with a leading device axis of 1).
 
     With a distributed ``comm`` (parallel/comm.py) this body runs inside
     shard_map: X/grad/hess/leaf_id may be row-local shards, the histogram
@@ -634,7 +696,8 @@ def grow_tree(
         B_cache = B_hist
     bm = comm.block_meta(feature_ok, num_bins, missing_code, default_bin, is_cat)
 
-    rg, rh, rc = comm.reduce_scalars(*root_sums(grad, hess, included))
+    with jax.named_scope("tree.root_sums"):
+        rg, rh, rc = comm.reduce_scalars(*root_sums(grad, hess, included))
 
     # one packed u8 row array per TREE (bin-code bytes + bf16 g/h channel
     # bytes): the compacted waves gather rows from it with a single random
@@ -649,8 +712,9 @@ def grow_tree(
     wmode = "f32" if spec.hist_f64 else spec.hist_hilo
     if spec.row_compact:
         from .ops.histogram import pack_rows
-        packed_rows, _ = pack_rows(X_hist, grad, hess, included,
-                                   wmode, spec.code_mode)
+        with jax.named_scope("tree.pack_rows"):
+            packed_rows, _ = pack_rows(X_hist, grad, hess, included,
+                                       wmode, spec.code_mode)
     else:
         packed_rows = None
 
@@ -680,19 +744,22 @@ def grow_tree(
         seg_start=jnp.zeros(L + 1, jnp.int32) if use_inc else None,
         seg_rows=(jnp.zeros(L + 1, jnp.int32).at[0].set(N)
                   if use_inc else None),
+        stats=_empty_stats(L),
     )
 
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
 
     def wave(state: GrowState) -> GrowState:
         # ---- 1. slot assignment for leaves needing histograms --------------
-        pending = state.needs_hist
-        slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
-        slot_of_leaf = jnp.where(pending, slot_rank, -1).astype(jnp.int32)  # [L+1]
-        # leaf served by each slot (or L = scratch)
-        leaf_of_slot = jnp.full(S, L, jnp.int32).at[
-            jnp.where(pending, slot_rank, S)  # invalid -> dropped (index S OOB)
-        ].set(leaf_iota, mode="drop")
+        with jax.named_scope("wave.slots"):
+            pending = state.needs_hist
+            slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
+            slot_of_leaf = jnp.where(pending, slot_rank,
+                                     -1).astype(jnp.int32)            # [L+1]
+            # leaf served by each slot (or L = scratch)
+            leaf_of_slot = jnp.full(S, L, jnp.int32).at[
+                jnp.where(pending, slot_rank, S)  # invalid -> dropped (S OOB)
+            ].set(leaf_iota, mode="drop")
 
         # ---- 2. one masked pass builds S histograms ------------------------
         # then the distributed reduction: psum_scatter for data-parallel
@@ -746,12 +813,15 @@ def grow_tree(
                 # legacy path all disappear from the wave body.
                 # leaf_of_slot == L for empty slots and seg_rows[L] stays 0,
                 # so invalid slots contribute nothing.
-                slot_counts_inc = state.seg_rows[leaf_of_slot]        # [S]
-                slot_starts_inc = state.seg_start[leaf_of_slot]       # [S]
-                n_active = jnp.sum(slot_counts_inc)
+                with jax.named_scope("wave.slots"):
+                    slot_counts_inc = state.seg_rows[leaf_of_slot]    # [S]
+                    slot_starts_inc = state.seg_start[leaf_of_slot]   # [S]
+                    n_active = jnp.sum(slot_counts_inc)
             else:
-                slot_row = table_lookup(state.leaf_id, slot_of_leaf)  # [N] i32
-                n_active = jnp.sum((slot_row >= 0).astype(jnp.int32))
+                with jax.named_scope("wave.slots"):
+                    slot_row = table_lookup(state.leaf_id,
+                                            slot_of_leaf)             # [N] i32
+                    n_active = jnp.sum((slot_row >= 0).astype(jnp.int32))
 
             def compact_pass():
                 if use_inc:
@@ -764,12 +834,14 @@ def grow_tree(
                 # legacy rebuild: rows grouped by slot, original order
                 # within a slot (stable) — kept as the A/B + parity pin for
                 # the incremental path (tpu_incremental_partition=false)
-                key = jnp.where(slot_row >= 0, slot_row, jnp.int32(2 ** 30))
-                row_idx = jnp.argsort(key, stable=True).astype(jnp.int32)
-                counts = jnp.sum(
-                    (slot_row[:, None]
-                     == jnp.arange(S, dtype=jnp.int32)[None, :])
-                    .astype(jnp.int32), axis=0)
+                with jax.named_scope("wave.partition"):
+                    key = jnp.where(slot_row >= 0, slot_row,
+                                    jnp.int32(2 ** 30))
+                    row_idx = jnp.argsort(key, stable=True).astype(jnp.int32)
+                    counts = jnp.sum(
+                        (slot_row[:, None]
+                         == jnp.arange(S, dtype=jnp.int32)[None, :])
+                        .astype(jnp.int32), axis=0)
                 return hist_pass(row_idx, n_active, counts)
 
             # the threshold is a static Python int, so the predicate cannot
@@ -779,22 +851,35 @@ def grow_tree(
             frac = spec.compact_frac
             if spec.hist_kernel in ("pallas", "mixed"):
                 frac = min(frac, 0.25)
-            new_hist = jax.lax.cond(n_active < int(N * frac), compact_pass,
-                                    lambda: hist_pass(None, None))
+            compacted = n_active < int(N * frac)
+
+            def compact_arm():
+                with jax.named_scope("wave.hist.compact"):
+                    return compact_pass()
+
+            def stream_arm():
+                with jax.named_scope("wave.hist.stream"):
+                    return hist_pass(None, None)
+
+            new_hist = jax.lax.cond(compacted, compact_arm, stream_arm)
         else:
-            new_hist = hist_pass(None, None)
-        if unbundle_early:
-            # this shard's leaf totals: any bundled column's bins partition
-            # the shard's included rows, so column 0's bin sums ARE them —
-            # exactly what _unpack_bundled's FixHistogram-by-subtraction
-            # needs for LOCAL histograms (global totals would mis-size the
-            # reconstructed default bin before the psum)
-            lpg = jnp.sum(new_hist[:, 0, :, 0], axis=-1)
-            lph = jnp.sum(new_hist[:, 0, :, 1], axis=-1)
-            lpc = jnp.sum(new_hist[:, 0, :, 2], axis=-1)
-            new_hist = _unpack_bundled(new_hist, bundle, lpg, lph, lpc,
-                                       default_bin)
-        new_hist = comm.reduce_hist(new_hist)
+            n_active = jnp.asarray(-1, jnp.int32)   # not counted in this arm
+            compacted = jnp.asarray(False)
+            with jax.named_scope("wave.hist.stream"):
+                new_hist = hist_pass(None, None)
+        with jax.named_scope("wave.hist.reduce"):
+            if unbundle_early:
+                # this shard's leaf totals: any bundled column's bins partition
+                # the shard's included rows, so column 0's bin sums ARE them —
+                # exactly what _unpack_bundled's FixHistogram-by-subtraction
+                # needs for LOCAL histograms (global totals would mis-size the
+                # reconstructed default bin before the psum)
+                lpg = jnp.sum(new_hist[:, 0, :, 0], axis=-1)
+                lph = jnp.sum(new_hist[:, 0, :, 1], axis=-1)
+                lpc = jnp.sum(new_hist[:, 0, :, 2], axis=-1)
+                new_hist = _unpack_bundled(new_hist, bundle, lpg, lph, lpc,
+                                           default_bin)
+            new_hist = comm.reduce_hist(new_hist)
 
         # ---- 3-6 + routing table: the shared wave tail ---------------------
         state2, table, map_mask, p, q, _n_apply = _apply_wave_splits(
@@ -821,51 +906,67 @@ def grow_tree(
         # from the SAME table_lookup output (q = num_leaves_cur + srank), so
         # no extra per-row lookup runs.
         if use_inc:
-            k_row = jnp.where(f_row >= 0,
-                              right_row - state.num_leaves_cur, -1)   # [N]
-            code_row = jnp.where(f_row >= 0,
-                                 2 * k_row + jnp.where(go_left, 0, 1), -1)
-            code_pos = jnp.take(code_row, state.perm)      # row -> position
-            in_split = code_pos >= 0
-            left_pos = in_split & ((code_pos & 1) == 0)
-            right_pos = in_split & ((code_pos & 1) == 1)
-            k_pos = code_pos >> 1                          # -1 stays -1
-            cl = jnp.cumsum(left_pos.astype(jnp.int32))    # inclusive
-            cr = jnp.cumsum(right_pos.astype(jnp.int32))
-            # cl0[j] = lefts strictly before position j (length N+1 so the
-            # one-past-the-end segment boundary reads the segment total)
-            cl0 = jnp.concatenate([jnp.zeros(1, jnp.int32), cl])
-            cr0 = jnp.concatenate([jnp.zeros(1, jnp.int32), cr])
-            start_k = state.seg_start[p]                   # [S]; p==L inert
-            n_k = state.seg_rows[p]
-            clb = jnp.take(cl0, start_k)
-            crb = jnp.take(cr0, start_k)
-            nL = jnp.take(cl0, start_k + n_k) - clb        # raw left rows
-            # per-slot additive bases resolved per position by an INTEGER
-            # one-hot multiply-sum (exact at any N — no f32 2^24 ceiling)
-            k_onehot = (k_pos[:, None]
-                        == jnp.arange(S, dtype=jnp.int32)[None, :])
-            base_l = jnp.sum(k_onehot * (start_k - clb)[None, :], axis=1)
-            base_r = jnp.sum(k_onehot * (start_k + nL - crb)[None, :], axis=1)
-            newpos = jnp.where(left_pos,
-                               (cl - left_pos.astype(jnp.int32)) + base_l,
-                               (cr - right_pos.astype(jnp.int32)) + base_r)
-            perm = state.perm.at[jnp.where(in_split, newpos, N)].set(
-                state.perm, mode="drop")
-            seg_start = state.seg_start.at[q].set(start_k + nL)
-            seg_rows = state.seg_rows.at[p].set(nL).at[q].set(n_k - nL)
-            # scratch leaf L must stay an empty segment (slot_counts reads
-            # seg_rows[leaf_of_slot] with leaf_of_slot==L for empty slots);
-            # masked-split writes above land there and are reset like the
-            # tree table's scratch row
-            seg_start = seg_start.at[L].set(0)
-            seg_rows = seg_rows.at[L].set(0)
+            with jax.named_scope("wave.partition"):
+                k_row = jnp.where(f_row >= 0,
+                                  right_row - state.num_leaves_cur, -1)   # [N]
+                code_row = jnp.where(f_row >= 0,
+                                     2 * k_row + jnp.where(go_left, 0, 1), -1)
+                code_pos = jnp.take(code_row, state.perm)      # row -> position
+                in_split = code_pos >= 0
+                left_pos = in_split & ((code_pos & 1) == 0)
+                right_pos = in_split & ((code_pos & 1) == 1)
+                k_pos = code_pos >> 1                          # -1 stays -1
+                cl = jnp.cumsum(left_pos.astype(jnp.int32))    # inclusive
+                cr = jnp.cumsum(right_pos.astype(jnp.int32))
+                # cl0[j] = lefts strictly before position j (length N+1 so the
+                # one-past-the-end segment boundary reads the segment total)
+                cl0 = jnp.concatenate([jnp.zeros(1, jnp.int32), cl])
+                cr0 = jnp.concatenate([jnp.zeros(1, jnp.int32), cr])
+                start_k = state.seg_start[p]                   # [S]; p==L inert
+                n_k = state.seg_rows[p]
+                clb = jnp.take(cl0, start_k)
+                crb = jnp.take(cr0, start_k)
+                nL = jnp.take(cl0, start_k + n_k) - clb        # raw left rows
+                # per-slot additive bases resolved per position by an INTEGER
+                # one-hot multiply-sum (exact at any N — no f32 2^24 ceiling)
+                k_onehot = (k_pos[:, None]
+                            == jnp.arange(S, dtype=jnp.int32)[None, :])
+                base_l = jnp.sum(k_onehot * (start_k - clb)[None, :], axis=1)
+                base_r = jnp.sum(k_onehot * (start_k + nL - crb)[None, :], axis=1)
+                newpos = jnp.where(left_pos,
+                                   (cl - left_pos.astype(jnp.int32)) + base_l,
+                                   (cr - right_pos.astype(jnp.int32)) + base_r)
+                perm = state.perm.at[jnp.where(in_split, newpos, N)].set(
+                    state.perm, mode="drop")
+                seg_start = state.seg_start.at[q].set(start_k + nL)
+                seg_rows = state.seg_rows.at[p].set(nL).at[q].set(n_k - nL)
+                # scratch leaf L must stay an empty segment (slot_counts reads
+                # seg_rows[leaf_of_slot] with leaf_of_slot==L for empty slots);
+                # masked-split writes above land there and are reset like the
+                # tree table's scratch row
+                seg_start = seg_start.at[L].set(0)
+                seg_rows = seg_rows.at[L].set(0)
         else:
             perm, seg_start, seg_rows = (state.perm, state.seg_start,
                                          state.seg_rows)
 
+        # ---- 9. the loop's own counters, one entry per wave ----------------
+        with jax.named_scope("wave.stats"):
+            # rows of the leaves split this wave: their segments' sizes
+            # (seg_rows[L] == 0, so p == L is inert), or without the
+            # carried partition a count over the routing pass's output
+            rows_split = (jnp.sum(n_k) if use_inc else
+                          jnp.sum((f_row >= 0).astype(jnp.int32)))
+            st = state.stats
+            stats = WaveStats(
+                waves=st.waves + 1,
+                rows_active=st.rows_active.at[st.waves].set(n_active),
+                compacted=st.compacted.at[st.waves].set(compacted),
+                rows_split=st.rows_split.at[st.waves].set(rows_split))
+
         return state2._replace(leaf_id=leaf_id, perm=perm,
-                               seg_start=seg_start, seg_rows=seg_rows)
+                               seg_start=seg_start, seg_rows=seg_rows,
+                               stats=stats)
 
     def cond(state: GrowState):
         return ~state.done
@@ -883,7 +984,9 @@ def grow_tree(
     tr = tr._replace(
         leaf_value=tr.leaf_value.at[L].set(0.0),
         internal_value=tr.internal_value.at[M].set(0.0))
-    return tr, final.leaf_id
+    # the counters leave with a leading device axis: under shard_map each
+    # device's own record is one row of the global array (comm.shard_grow)
+    return tr, final.leaf_id, jax.tree.map(lambda a: a[None], final.stats)
 
 
 # ======================================================================

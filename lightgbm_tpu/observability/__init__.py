@@ -3,15 +3,21 @@
 One subsystem for every runtime signal the boosting stack produces:
 
 - ``SpanTracer`` (tracer.py)      — nested host-side spans
-  (train -> tree_batch -> iteration -> wave, plus eval/comm/checkpoint),
-  recorded at dispatch boundaries only so the fused step and the
-  recompile-free steady state are preserved.
+  (train -> tree_batch -> iteration -> step.prep/dispatch/post, plus
+  eval/comm/checkpoint and the set-up boundaries), recorded at dispatch
+  boundaries only so the fused step and the recompile-free steady state
+  are preserved. ``span()`` also enters a ``jax.profiler.TraceAnnotation``
+  named ``lgbm.<name>`` while a profiler session is open: the program's
+  spans then sit on the device trace's own clock. "Tracing on" IS "a
+  profiler session is open" — there is no other switch.
 - ``MetricsRegistry`` (metrics.py) — process-wide counters/gauges/
   histograms/quantile summaries absorbing ``RecompileGuard.report()``,
   ``PhaseBreakdown``, comm retries/timeouts, ``nan_policy`` events,
-  checkpoint writes, per-booster kernel choice, waves per tree, rows
-  routed, and the serving subsystem's per-request latency p50/p99
-  (``serve.*``, docs/Serving.md).
+  checkpoint writes, per-booster kernel choice, the wave loop's own
+  per-tree counters (``grow.*``, ``rows.routed``, ``hist.mxu_flops``),
+  the retrace counter ``compile.step_traces``, set-up seconds
+  (``setup.*_s``), and the serving subsystem's per-request latency
+  p50/p99 (``serve.*``, docs/Serving.md).
 - exporters (export.py)           — JSONL event stream + Chrome trace-event
   JSON (Perfetto-loadable) under ``LGBM_TPU_TELEMETRY_DIR`` / config
   ``telemetry_dir``; ``snapshot()`` is the point-in-time serving API.
@@ -32,15 +38,18 @@ bench harness, and a serving probe all read the same registry. Everything
 here is jax-free at import time (the lint CLI and guards publish through
 it in jax-free environments).
 
-Overhead contract: with no telemetry directory configured the tracer is
-disabled — ``span()`` returns a shared no-op and the registry costs one
-dict lookup + int add per event, at host boundaries only. ``bench.py
+Overhead contract: with no telemetry directory configured and no profiler
+session open, ``span()`` returns a shared no-op (one ``sys.modules`` lookup
+and one flag check) and the registry costs one dict lookup + int add per
+event, at host boundaries only. ``bench.py
 --smoke`` enforces that telemetry-on adds zero steady-state recompiles and
 zero new host syncs inside the fused step.
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import sys
 import time
 from typing import Dict, Optional
 
@@ -111,10 +120,49 @@ def maybe_configure_from_env() -> None:
 
 # ----------------------------------------------------------------- recording
 
+PROFILER_PREFIX = "lgbm."
+
+
+def _live_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is open,
+    else None. jax is looked up in ``sys.modules``, never imported: a
+    process that has not imported it has no session, and this package
+    stays importable without it. ``is_enabled`` is the profiler's own
+    flag check."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
 def span(name: str, **args):
-    """``with observability.span("tree_batch", k=4): ...`` — no-op when
-    telemetry is disabled."""
-    return _tracer.span(name, **args)
+    """``with observability.span("tree_batch", k=4): ...``. Recorded in
+    memory when the tracer is on; entered as the profiler annotation
+    ``lgbm.<name>`` when a profiler session is open (the same span on the
+    device trace's clock); the shared no-op when neither."""
+    ann = _live_annotation()
+    if ann is None:
+        return _tracer.span(name, **args)
+    live = ann(PROFILER_PREFIX + name, **args)
+    return _tracer.span(name, _annotation=live, **args) \
+        if _tracer.enabled else live
+
+
+@contextlib.contextmanager
+def setup_span(name: str, **args):
+    """``span(name)`` around a boundary of set-up (dataset construction,
+    ingest, the finalize fetch), whose seconds are also ALWAYS written to
+    the gauge ``setup.<name with dots as underscores>_s``: set-up runs once
+    per dataset or booster, so timing it costs nothing that matters, and
+    the benchmark reads these with the tracer off."""
+    t0 = time.perf_counter()
+    try:
+        with span(name, **args):
+            yield
+    finally:
+        _registry.gauge(f"setup.{name.replace('.', '_')}_s").set(
+            time.perf_counter() - t0)
 
 
 def event(name: str, **args) -> None:

@@ -6,9 +6,11 @@ runtime signal the training stack used to scatter across ad-hoc consumers:
 publishes them on guard exit), comm retry/timeout events
 (robustness/retry.py, parallel/comm.py), ``nan_policy`` events
 (boosting/gbdt.py), checkpoint writes (robustness/checkpoint.py), per-booster
-kernel choice, waves per tree, rows routed, and the serving subsystem's
-per-request traffic (``serve.*`` counters plus the quantile-capable
-``Summary`` latency metrics — docs/Serving.md). ``bench.py`` reads the same
+kernel choice, the wave loop's own per-tree counters (``grow.*``,
+``rows.routed``, ``hist.mxu_flops`` — counted on the device, published where
+the trees come to the host), and the serving subsystem's per-request traffic
+(``serve.*`` counters plus the quantile-capable ``Summary`` latency metrics
+— docs/Serving.md). ``bench.py`` reads the same
 registry for its ``telemetry`` summary block instead of keeping parallel
 bookkeeping.
 
@@ -57,7 +59,7 @@ class Gauge:
 
 class Histogram:
     """Streaming summary (count/sum/min/max) of an observed distribution
-    (e.g. ``tree.waves``). No buckets: the consumers here want the shape of
+    (e.g. ``tree.leaves``). No buckets: the consumers here want the shape of
     a per-run distribution in a snapshot, not a full HDR histogram."""
     __slots__ = ("name", "_lock", "count", "sum", "min", "max")
 
@@ -126,6 +128,16 @@ class Summary:
             out[key] = None if n == 0 else \
                 data[min(n - 1, max(0, math.ceil(q * n) - 1))]
         return out
+
+    def values(self) -> list:
+        """The window's observations, oldest first. While ``count`` has not
+        passed ``window`` this is every observation in order, so a producer
+        that observes once per item (``grow.waves``: once per tree, in tree
+        order) can be read back item by item."""
+        with self._lock:
+            if len(self._ring) < self.window:
+                return list(self._ring)
+            return self._ring[self._next:] + self._ring[:self._next]
 
     def quantiles(self, qs=(0.5, 0.9, 0.99)) -> Dict[str, Optional[float]]:
         with self._lock:

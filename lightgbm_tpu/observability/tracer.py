@@ -1,25 +1,35 @@
 """Host-side span tracer: nested wall-clock spans at dispatch boundaries.
 
-The span model mirrors the training stack's host-visible structure:
+The span tree mirrors the training stack's host-visible structure; every
+span is a MEASUREMENT of a host boundary that exists (nothing is sliced or
+modelled):
 
     train                       engine.train (one per call)
       tree_batch                one jit dispatch (K fused iterations)
-        iteration               per boosting iteration; DERIVED slices of
-                                the tree_batch span when K > 1 (the fused
-                                scan is opaque to the host by design)
-          wave                  DERIVED from the finished tree's leaf count
-                                (grower.waves_for_tree) at telemetry-publish
-                                time — the while_loop runs device-side
+        iteration               tree_batch=1 only: one iteration, one dispatch
+          step.prep             argument assembly (_dispatch_prep)
+          step.dispatch         the jitted call: enqueue, or trace + compile
+                                + cache load on a first call
+          step.post             bookkeeping, nan policy
       eval | comm | checkpoint  real host-side operations
+    dataset.* | ingest | ingest.compile | finalize.fetch   set-up boundaries
+
+What happens INSIDE a dispatch has no host boundary: the wave loop runs on
+the device. Its phases are ``jax.named_scope`` names in the compiled
+program (``wave.*``, ``step.*``, ``hist.kernel``; docs/Observability.md)
+that a ``jax.profiler`` trace carries per device operation, and its work
+is counted by the loop itself (grower.WaveStats), fetched with the trees.
+
+One clock: ``observability.span`` also enters a
+``jax.profiler.TraceAnnotation("lgbm.<name>")`` whenever a profiler
+session is open, so the same spans sit in the profiler's host plane beside
+the device trace. Each recorded span carries ``span_id`` and the
+``parent_id`` of the span that was open on its thread when it started.
 
 Spans are recorded ONLY at host dispatch boundaries: entering/leaving a span
 costs two ``time.perf_counter()`` calls and one dict append — no device
 array is ever touched, so the fused ``tree_batch`` path stays recompile-free
 and host-sync-free with telemetry on (asserted by ``bench.py --smoke``).
-Device-internal phases (histogram / split / partition) have no host
-boundary; their true timing comes from the optional ``jax.profiler`` window
-(``tpu_profile_iters``, observability/profiler.py) — the derived iteration/
-wave spans are explicitly labeled ``"derived": true`` in their args.
 
 When disabled (the default), ``span()`` returns a shared no-op context
 manager: the hot loop pays one attribute check per dispatch and nothing
@@ -52,19 +62,27 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_annotation", "span_id",
+                 "parent_id")
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: Dict):
+    def __init__(self, tracer: "SpanTracer", name: str, args: Dict,
+                 annotation=None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = annotation
 
     def __enter__(self):
+        self.span_id, self.parent_id = self._tracer._push()
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self._tracer._finish(self, self._t0, exc_type)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -77,6 +95,8 @@ class SpanTracer:
         self.dropped = 0
         self._events: List[Dict] = []
         self._lock = threading.Lock()
+        self._open = threading.local()      # per-thread stack of open ids
+        self._next_id = 0
         self._epoch = time.perf_counter()
         self._epoch_unix = time.time()
 
@@ -85,20 +105,36 @@ class SpanTracer:
     def _now_us(self) -> int:
         return int((time.perf_counter() - self._epoch) * 1e6)
 
-    def span(self, name: str, **args):
-        """Context manager recording one complete ("X") span on exit."""
+    def span(self, name: str, _annotation=None, **args):
+        """Context manager recording one complete ("X") span on exit.
+        ``_annotation`` (observability.span: an un-entered profiler
+        annotation) is entered and left with the span."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, args)
+        return _Span(self, name, args, _annotation)
+
+    def _push(self):
+        """(new span id, id of the span open on this thread or None)."""
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        with self._lock:
+            self._next_id += 1
+            sid = self._next_id
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        return sid, parent
 
     def _finish(self, span: _Span, t0: int, exc_type) -> None:
+        self._open.stack.pop()
         args = span.args
         if exc_type is not None:
             args = dict(args, error=exc_type.__name__)
         self._record({"name": span.name, "ph": "X", "ts": t0,
                       "dur": max(self._now_us() - t0, 0),
                       "pid": os.getpid(), "tid": threading.get_ident(),
-                      "cat": "lightgbm_tpu", "args": args})
+                      "cat": "lightgbm_tpu", "args": args,
+                      "span_id": span.span_id, "parent_id": span.parent_id})
 
     def event(self, name: str, **args) -> None:
         """Instant ("i") event — e.g. a nan_policy trip, a booster init."""
@@ -114,61 +150,6 @@ class SpanTracer:
                 self.dropped += 1
                 return
             self._events.append(ev)
-
-    # ------------------------------------------------------- derived children
-
-    def subdivide_last(self, parent_name: str, child_name: str, n: int,
-                       base_iteration: int = 0) -> None:
-        """Slice the most recent ``parent_name`` span into ``n`` equal
-        ``child_name`` children (the fused-batch iteration attribution: the
-        scan body is one dispatch, so per-iteration timing inside it is an
-        even split by construction — marked ``derived``)."""
-        if not self.enabled or n <= 0:
-            return
-        with self._lock:
-            parent = next((e for e in reversed(self._events)
-                           if e["name"] == parent_name and e["ph"] == "X"),
-                          None)
-        if parent is None:
-            return
-        self._slice(parent, child_name, n,
-                    [{"iteration": base_iteration + i} for i in range(n)])
-
-    def derive_children(self, parent_name: str, child_name: str,
-                        counts: List[int]) -> None:
-        """Attach ``counts[i]`` derived children to the LAST ``len(counts)``
-        not-yet-derived ``parent_name`` spans, in order (telemetry publish:
-        wave spans from per-tree leaf counts — the publishing run's
-        iteration spans are the most recently recorded, so tail alignment
-        pairs each count with its own iteration even when earlier
-        direct-loop spans exist). Parents are marked so repeated publishes
-        (multiple train() calls per process) never double-derive."""
-        if not self.enabled or not counts:
-            return
-        with self._lock:
-            parents = [e for e in self._events
-                       if e["name"] == parent_name and e["ph"] == "X"
-                       and not e["args"].get(f"{child_name}s_derived")]
-        # tail-align both sides: a resumed booster's counts include restored
-        # iterations that never recorded a span in this process
-        n = min(len(parents), len(counts))
-        parents, counts = parents[-n:], list(counts)[-n:]
-        for parent, cnt in zip(parents, counts):
-            parent["args"][f"{child_name}s_derived"] = True
-            if cnt > 0:
-                self._slice(parent, child_name, int(cnt),
-                            [{child_name: i} for i in range(int(cnt))])
-
-    def _slice(self, parent: Dict, child_name: str, n: int,
-               args_list: List[Dict]) -> None:
-        dur = parent["dur"] / n
-        for i in range(n):
-            args = dict(args_list[i], derived=True)
-            self._record({"name": child_name, "ph": "X",
-                          "ts": int(parent["ts"] + i * dur),
-                          "dur": max(int(dur), 1),
-                          "pid": parent["pid"], "tid": parent["tid"],
-                          "cat": "lightgbm_tpu.derived", "args": args})
 
     # ----------------------------------------------------------------- export
 
@@ -190,5 +171,6 @@ class SpanTracer:
         with self._lock:
             self._events.clear()
             self.dropped = 0
+            self._next_id = 0
             self._epoch = time.perf_counter()
             self._epoch_unix = time.time()

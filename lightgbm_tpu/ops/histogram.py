@@ -377,25 +377,28 @@ def build_histograms(
     def chunk_part(i):
         sl = jax.lax.dynamic_slice_in_dim
         if compact:
-            pos = i * chunk_rows + iota_chunk
-            valid = pos < n_active
-            if slot_starts is not None:
-                # leaf-contiguous permutation: translate compacted positions
-                # into the pending segments (incremental partition) — the
-                # slot is position-derived exactly as in the prefix layout
-                raw = slot_from_position(pos, slot_cum)
-                src = pos + slot_position_base(raw, slot_cum, slot_starts)
-                idx = jnp.take(row_idx, jnp.clip(src, 0, n_rows - 1))
-            else:
-                idx = sl(row_idx, i * chunk_rows, chunk_rows)
-                if slot_cum is not None:
+            # the compacted pass's row gathers, named apart from the
+            # matmul they feed
+            with jax.named_scope("wave.hist.compact.gather"):
+                pos = i * chunk_rows + iota_chunk
+                valid = pos < n_active
+                if slot_starts is not None:
+                    # leaf-contiguous permutation: translate compacted positions
+                    # into the pending segments (incremental partition) — the
+                    # slot is position-derived exactly as in the prefix layout
                     raw = slot_from_position(pos, slot_cum)
+                    src = pos + slot_position_base(raw, slot_cum, slot_starts)
+                    idx = jnp.take(row_idx, jnp.clip(src, 0, n_rows - 1))
                 else:
-                    raw = table_lookup(jnp.take(leaf_id, idx), slot_of_leaf)
-            pk = jnp.take(packed, idx, axis=0)                    # [R, Wb] u8
-            xc = unpack_codes(pk[:, :ncb], num_features, code_mode)
-            w = unpack_weights(pk[:, ncb:], ch, f32=(hilo == "f32"))  # [R, ch]
-            slot = jnp.where(valid, raw, -1)                       # [R]
+                    idx = sl(row_idx, i * chunk_rows, chunk_rows)
+                    if slot_cum is not None:
+                        raw = slot_from_position(pos, slot_cum)
+                    else:
+                        raw = table_lookup(jnp.take(leaf_id, idx), slot_of_leaf)
+                pk = jnp.take(packed, idx, axis=0)                    # [R, Wb] u8
+                xc = unpack_codes(pk[:, :ncb], num_features, code_mode)
+                w = unpack_weights(pk[:, ncb:], ch, f32=(hilo == "f32"))  # [R, ch]
+                slot = jnp.where(valid, raw, -1)                       # [R]
         else:
             xc = sl(X, i * chunk_rows, chunk_rows)
             gc = sl(grad, i * chunk_rows, chunk_rows)
@@ -445,22 +448,25 @@ def build_histograms(
     else:
         comp0 = jnp.zeros_like(acc0) if compensated \
             else jnp.zeros((), jnp.float32)
-    if compact:
-        n_chunks_active = jnp.minimum(
-            (n_active + chunk_rows - 1) // chunk_rows, n_chunks)
+    # "hist.kernel": the name a device trace finds the kernel's operations
+    # by, whichever wave phase (or streamed shard leg) runs the pass
+    with jax.named_scope("hist.kernel"):
+        if compact:
+            n_chunks_active = jnp.minimum(
+                (n_active + chunk_rows - 1) // chunk_rows, n_chunks)
 
-        def while_body(carry):
-            i, acc, comp = carry
-            acc, comp = accumulate((acc, comp), i)
-            return i + 1, acc, comp
+            def while_body(carry):
+                i, acc, comp = carry
+                acc, comp = accumulate((acc, comp), i)
+                return i + 1, acc, comp
 
-        _, acc, comp = jax.lax.while_loop(
-            lambda c: c[0] < n_chunks_active, while_body,
-            (jnp.asarray(0, n_chunks_active.dtype), acc0, comp0))
-    else:
-        (acc, comp), _ = jax.lax.scan(
-            lambda c, i: (accumulate(c, i), ()), (acc0, comp0),
-            jnp.arange(n_chunks))
+            _, acc, comp = jax.lax.while_loop(
+                lambda c: c[0] < n_chunks_active, while_body,
+                (jnp.asarray(0, n_chunks_active.dtype), acc0, comp0))
+        else:
+            (acc, comp), _ = jax.lax.scan(
+                lambda c, i: (accumulate(c, i), ()), (acc0, comp0),
+                jnp.arange(n_chunks))
 
     if raw_output:
         return acc, comp

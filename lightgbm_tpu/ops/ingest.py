@@ -132,7 +132,10 @@ def device_ingest_blocker(data, mappers: Sequence[BinMapper]) -> Optional[str]:
         if cats and max(cats) >= _CAT_EXACT_LIMIT:
             return (f"categorical value {max(cats)} >= 2^24 "
                     f"(not exactly representable in f32)")
-    if not f32_lossless(data):
+    from .. import observability as obs
+    with obs.setup_span("dataset.lossless_check"):
+        lossless = f32_lossless(data)
+    if not lossless:
         return ("float64 values not losslessly f32-representable "
                 "(device binning compares in f32)")
     return None
@@ -455,12 +458,18 @@ def device_ingest(raw: np.ndarray, mappers: Sequence[BinMapper],
                          num_cols=num_cols, device=device,
                          depth=prefetch_depth)
     t0 = obs.clock()
-    with obs.span("ingest", rows=int(n_rows), chunks=int(n_chunks)):
+    with obs.setup_span("ingest", rows=int(n_rows), chunks=int(n_chunks)):
         feeder.prefetch(0)
         outs = []
         for i in range(n_chunks):
             chunk = feeder.get(i)
-            out = ing.bin_chunk(chunk, i * R)
+            if i == 0:
+                # the first call traces and compiles the bin kernel (or
+                # loads it from the cache) before it enqueues
+                with obs.setup_span("ingest.compile"):
+                    out = ing.bin_chunk(chunk, 0)
+            else:
+                out = ing.bin_chunk(chunk, i * R)
             for j in range(i + 1, min(i + 1 + feeder.depth, n_chunks)):
                 feeder.prefetch(j)       # copy rides under chunk i's compute
             outs.append(out)

@@ -144,23 +144,27 @@ def hist_pallas(
         _hist_kernel, chunk_rows=chunk_rows, num_bins=num_bins,
         num_features=num_features, num_slots=num_slots, cb=cb)
 
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_chunks,),
-            in_specs=[
-                pl.BlockSpec((chunk_rows, ncb), lambda i, n: (i, 0)),
-                pl.BlockSpec((chunk_rows, 1), lambda i, n: (i, 0)),
-                pl.BlockSpec((chunk_rows, ch), lambda i, n: (i, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (SC_pad, num_features * num_bins), lambda i, n: (0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (SC_pad, num_features * num_bins), jnp.float32),
-        interpret=_INTERPRET,
-    )(n_active.reshape(1), Xb8, slot.reshape(N, 1), w)
+    # the scope and the kernel's own name are what a device trace finds the
+    # Mosaic call by (the xla kernel carries the same scope, ops/histogram.py)
+    with jax.named_scope("hist.kernel"):
+        out = pl.pallas_call(
+            kernel,
+            name="hist_kernel",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n_chunks,),
+                in_specs=[
+                    pl.BlockSpec((chunk_rows, ncb), lambda i, n: (i, 0)),
+                    pl.BlockSpec((chunk_rows, 1), lambda i, n: (i, 0)),
+                    pl.BlockSpec((chunk_rows, ch), lambda i, n: (i, 0)),
+                ],
+                out_specs=pl.BlockSpec(
+                    (SC_pad, num_features * num_bins), lambda i, n: (0, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct(
+                (SC_pad, num_features * num_bins), jnp.float32),
+            interpret=_INTERPRET,
+        )(n_active.reshape(1), Xb8, slot.reshape(N, 1), w)
 
     acc = out[:SC].reshape(num_slots, ch, num_features, num_bins)
     acc = jnp.transpose(acc, (0, 2, 3, 1))                        # [S, F, B, ch]

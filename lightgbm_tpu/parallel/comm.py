@@ -803,13 +803,17 @@ class ParallelContext:
     def shard_grow(self, grow_fn: Callable) -> Callable:
         """Wrap ``grow_fn(X, grad, hess, included, feature_ok, num_bins,
         missing_code, default_bin)`` in shard_map with this strategy's specs.
-        Tree outputs are replicated; leaf_id follows the row sharding."""
+        Tree outputs are replicated; leaf_id follows the row sharding; the
+        wave loop's counters (grower.WaveStats, leading axis 1 per device)
+        concatenate over the mesh axis, one row per device: each device
+        counts its own shard, and no collective is spent on a counter."""
         if self.mesh is None:
             return grow_fn
         rows = P(self.ROW_AXIS) if self.strategy in ("data", "voting") else P()
         rows2d = P(self.ROW_AXIS, None) if self.strategy in ("data", "voting") else P()
         in_specs = (rows2d, rows, rows, rows, P(), P(), P(), P(), P())
-        out_specs = (P(), rows)       # (TreeArrays..., leaf_id)
+        # (TreeArrays..., leaf_id, WaveStats...)
+        out_specs = (P(), rows, P(self.ROW_AXIS))
         return jax.shard_map(grow_fn, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
 

@@ -1,0 +1,151 @@
+"""Every phase of the step and of the resident wave body sits in a
+``jax.named_scope`` (docs/Observability.md, "Scopes"): the lowered step
+carries each name in its ``op_name`` locations, and the operations of the
+step COMPILED for a described v5e — the fusions a device trace times —
+carry them in their ``metadata``. Metadata only: no generated code moves.
+
+The one test file that describes a TPU topology (in a fixture, skipped
+where it cannot be described; on-chip-measurement guide, section 2).
+"""
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import lightgbm_tpu as lgb
+
+STEP_SCOPES = ("step.gradients", "step.sampling", "step.grow",
+               "step.score_update", "step.valid_update")
+TREE_SCOPES = ("tree.pack_rows", "tree.root_sums")
+WAVE_SCOPES = ("wave.slots", "wave.hist.stream", "wave.hist.compact",
+               "wave.hist.compact.gather", "wave.hist.reduce", "wave.split",
+               "wave.route", "wave.partition", "wave.stats")
+KERNEL_SCOPES = ("hist.kernel",)
+ALL_SCOPES = STEP_SCOPES + TREE_SCOPES + WAVE_SCOPES + KERNEL_SCOPES
+
+PARAMS = dict(objective="binary", num_leaves=15, max_bin=31, verbose=-1,
+              min_data_in_leaf=5, metric="binary_logloss", device="cpu",
+              bagging_fraction=0.7, bagging_freq=1, tpu_compact_frac=0.5)
+
+
+def _step_and_args(**extra):
+    """A small booster's jitted step and the arguments ``_run_step`` would
+    hand it: bagging and a validation set, so every phase has operations."""
+    rng = np.random.RandomState(2)
+    X = rng.rand(2500, 8).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0.8).astype(np.float32)
+    params = dict(PARAMS, **extra)
+    ds = lgb.Dataset(X, label=y, params=params)
+    bst = lgb.Booster(params=params, train_set=ds)
+    bst.add_valid(lgb.Dataset(X[:300], label=y[:300], reference=ds), "v")
+    g = bst._gbdt
+    consts, valid_Xb, valid_scores = g._dispatch_prep(g._step_shrinkage())
+    args = (consts, valid_Xb, g.score, valid_scores, g.bag_mask, g._rng_key,
+            g._iter_dev, g._shrink_cache[1])
+    return g._make_step(), args
+
+
+def _named(text: str, scope: str) -> int:
+    """Operations whose scope path has ``scope`` as one whole component."""
+    return len(re.findall(r'[/"]' + re.escape(scope) + r'[/"]', text))
+
+
+@pytest.mark.parametrize("learner", ["serial", "data"])
+def test_lowered_step_names_every_phase(learner):
+    fn, args = _step_and_args(tree_learner=learner)
+    text = fn.lower(*args).as_text(debug_info=True)
+    missing = [s for s in ALL_SCOPES if not _named(text, s)]
+    # serial growth reduces nothing: comm.reduce_hist is the identity
+    if learner == "serial":
+        assert missing == ["wave.hist.reduce"]
+    else:
+        assert missing == []
+    if learner == "serial":
+        # the wave phases are inside the loop inside the grow phase
+        assert re.search(r'step\.grow/[^"]*while/body/[^"]*wave\.route/',
+                         text)
+        assert re.search(r'wave\.hist\.compact/[^"]*hist\.kernel/[^"]*'
+                         r'wave\.hist\.compact\.gather/', text)
+
+
+def test_legacy_argsort_arm_sorts_under_the_partition_scope():
+    """``tpu_incremental_partition=false`` rebuilds the partition with a
+    per-wave argsort: the one row-sized sort of the wave body, and it
+    carries the partition's name."""
+    fn, args = _step_and_args(tpu_incremental_partition=False)
+    hlo = fn.lower(*args).compile().as_text()
+    sorts = [ln for ln in hlo.splitlines()
+             if " sort(" in ln and "s32[2560]" in ln]        # row-sized
+    assert sorts
+    assert all("/wave.partition/" in ln for ln in sorts), sorts
+
+
+def test_streamed_legs_share_the_split_and_route_scopes():
+    """``_apply_wave_splits`` and ``_route_rows`` carry their scope inside,
+    so the host-driven streamed grower's legs are named like the resident
+    loop's."""
+    from lightgbm_tpu.grower import _route_rows
+    from lightgbm_tpu.analysis.contracts.entries import _wave_spec
+    import jax.numpy as jnp
+    spec = _wave_spec()
+    route = jax.jit(lambda X, lid, table: _route_rows(
+        X, lid, table, None, spec, None, jnp.zeros(6, jnp.int32)))
+    text = route.lower(jnp.zeros((64, 6), jnp.uint8), jnp.zeros(64, jnp.int32),
+                       jnp.zeros((16, 6), jnp.int32)).as_text(debug_info=True)
+    assert _named(text, "wave.route")
+
+
+# --------------------------------------- compiled for a described v5e chip
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # noqa: BLE001 — whatever keeps it away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_v5e_fusions_carry_the_scopes(one_chip):
+    """What the device trace times are the compiled program's fusions:
+    each phase that moves rows owns at least one, named in its metadata."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    fn, args = _step_and_args()
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip), args)
+    # a program compiled for a described chip cannot be read back from the
+    # persistent cache: keep it out, and the run silent
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        hlo = fn.lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+    ops = [ln for ln in hlo.splitlines() if "metadata={" in ln]
+    fusions = [ln for ln in ops if " fusion(" in ln]
+    assert len(fusions) > 50
+    owns_fusions = ("step.gradients", "step.grow",
+                    "step.score_update", "step.valid_update",
+                    "tree.pack_rows", "wave.slots", "wave.hist.stream",
+                    "wave.hist.compact", "wave.hist.compact.gather",
+                    "wave.split", "wave.route", "wave.partition",
+                    "hist.kernel")
+    for scope in owns_fusions:
+        assert any(re.search(r'op_name="[^"]*[/]' + re.escape(scope)
+                             + r'[/"]', ln) for ln in fusions), scope
+    # bagging's draw is a generator call whose compare fuses downstream
+    assert any("/step.sampling/" in ln for ln in ops)
+    # the matmul the histogram IS sits under hist.kernel, in both arms
+    dots = [ln for ln in ops if re.search(r"kind=kOutput|convolution\(", ln)
+            and "hist.kernel" in ln]
+    assert any("wave.hist.stream" in ln for ln in dots)
+    assert any("wave.hist.compact" in ln for ln in dots)

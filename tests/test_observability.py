@@ -1,8 +1,9 @@
 """Unified training telemetry (lightgbm_tpu/observability/;
 docs/Observability.md): metrics registry, span tracer, exporters, the
-wave-attribution model, the jax.profiler window, and the end-to-end
-engine.train wiring (spans nested train -> tree_batch -> iteration ->
-wave, counters for kernel choice / trees / rows)."""
+jax.profiler window, and the end-to-end engine.train wiring (spans nested
+train -> tree_batch -> step.dispatch with parent ids, counters for kernel
+choice / trees / rows; the wave loop's own counters have
+tests/test_wave_counters.py)."""
 import json
 import logging
 import os
@@ -47,6 +48,15 @@ PARAMS = dict(objective="binary", num_leaves=7, max_bin=15,
               min_data_in_leaf=5, verbose=-1, metric="none")
 
 
+def _padded(n, params=PARAMS):
+    """Rows as the device holds them (padded to the histogram chunk): what
+    the routing pass moves every wave."""
+    X, y = _data(n)
+    bst = lgb.Booster(params=dict(params),
+                      train_set=lgb.Dataset(X, label=y, params=params))
+    return int(bst._gbdt.num_data_padded)
+
+
 # ------------------------------------------------------------------ registry
 
 def test_registry_counters_gauges_histograms():
@@ -73,8 +83,6 @@ def test_tracer_disabled_is_a_noop():
     with t.span("a", k=1):
         pass
     t.event("e")
-    t.subdivide_last("a", "b", 3)
-    t.derive_children("a", "b", [1])
     assert t.events() == []
 
 
@@ -90,39 +98,6 @@ def test_tracer_spans_nest_by_containment():
     assert outer["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1
     assert inner["args"]["k"] == 2
-
-
-def test_tracer_subdivide_and_derive():
-    t = SpanTracer()
-    t.enabled = True
-    with t.span("tree_batch", k=4):
-        pass
-    t.subdivide_last("tree_batch", "iteration", 4, base_iteration=8)
-    iters = [e for e in t.events() if e["name"] == "iteration"]
-    assert [e["args"]["iteration"] for e in iters] == [8, 9, 10, 11]
-    assert all(e["args"]["derived"] for e in iters)
-    parent = next(e for e in t.events() if e["name"] == "tree_batch")
-    assert all(parent["ts"] <= e["ts"]
-               and e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1
-               for e in iters)
-    t.derive_children("iteration", "wave", [2, 1, 1, 3])
-    assert len([e for e in t.events() if e["name"] == "wave"]) == 7
-    # a second publish must not re-derive (parents are marked)
-    t.derive_children("iteration", "wave", [2, 1, 1, 3])
-    assert len([e for e in t.events() if e["name"] == "wave"]) == 7
-
-
-def test_tracer_derive_tail_aligns_counts():
-    """A resumed booster's leaf counts cover restored iterations that never
-    recorded spans in this process: newest pairs with newest."""
-    t = SpanTracer()
-    t.enabled = True
-    for _ in range(2):
-        with t.span("iteration"):
-            pass
-    t.derive_children("iteration", "wave", [9, 9, 9, 1, 2])   # 3 restored
-    waves = [e for e in t.events() if e["name"] == "wave"]
-    assert len(waves) == 3                                    # 1 + 2
 
 
 def test_tracer_bounded_events():
@@ -161,17 +136,6 @@ def test_flush_appends_jsonl_incrementally(telemetry):
     recs2 = read_jsonl(obs.jsonl_path())
     assert len([r for r in recs2 if r.get("type") == "span"]) == 1
     assert len([r for r in recs2 if r.get("type") == "counters"]) == 2
-
-
-# ------------------------------------------------------- wave model (grower)
-
-def test_waves_for_tree_model():
-    from lightgbm_tpu.grower import waves_for_tree
-    assert waves_for_tree(1, 25, 25) == 1          # stump: one no-split wave
-    assert waves_for_tree(26, 25, 25) == 1         # 25 splits / cap 25
-    assert waves_for_tree(31, 25, 25) == 2
-    assert waves_for_tree(31, 1, 25) == 30         # exact leaf-wise order
-    assert waves_for_tree(255, 0, 25) == 11        # wave_size=0 -> slots cap
 
 
 # ------------------------------------------------------------ PhaseBreakdown
@@ -318,21 +282,26 @@ def test_train_emits_nested_spans_and_counters(telemetry):
         events = json.load(fh)["traceEvents"]
     trains = [e for e in events if e["name"] == "train"]
     batches = [e for e in events if e["name"] == "tree_batch"]
-    iters = [e for e in events if e["name"] == "iteration"]
-    waves = [e for e in events if e["name"] == "wave"]
-    assert len(trains) == 1 and len(batches) == 3
-    assert len(iters) == 6 and len(waves) >= 6
+    dispatches = [e for e in events if e["name"] == "step.dispatch"]
+    assert len(trains) == 1 and len(batches) == 3 and len(dispatches) == 3
     assert all(_contains(trains[0], b) for b in batches)
-    assert all(any(_contains(b, i) for b in batches) for i in iters)
-    assert all(any(_contains(i, w) for i in iters) for w in waves)
-    assert all(w["args"]["derived"] for w in waves)
+    # every span is a measured host boundary: nothing derived, and each
+    # names the span that caused it
+    assert not [e for e in events if e["name"] in ("iteration", "wave")]
+    assert not [e for e in events if (e.get("args") or {}).get("derived")]
+    assert all(b["parent_id"] == trains[0]["span_id"] for b in batches)
+    for d in dispatches:
+        batch = next(b for b in batches if b["span_id"] == d["parent_id"])
+        assert _contains(batch, d)
 
     snap = obs.snapshot()
     assert snap["counters"]["trees.trained"] == 6
-    assert snap["counters"]["rows.routed"] == 6 * 400
+    waves = obs.get_registry().summary("grow.waves").values()
+    assert len(waves) == 6 and min(waves) >= 1
+    assert snap["counters"]["rows.routed"] == sum(waves) * _padded(400)
     assert snap["counters"]["booster.kernel.xla"] == 1
     assert snap["gauges"]["booster.tree_batch"] == 2
-    assert snap["histograms"]["tree.waves"]["count"] == 6
+    assert snap["histograms"]["tree.leaves"]["count"] == 6
     # JSONL stream carries the same counters next to the events
     recs = read_jsonl(obs.jsonl_path())
     counters = [r for r in recs if r.get("type") == "counters"][-1]
@@ -380,7 +349,9 @@ def test_registry_live_without_telemetry_dir(clean_registry):
     assert obs.trace_path() is None
     snap = obs.snapshot()
     assert snap["counters"]["trees.trained"] == 3
-    assert snap["counters"]["rows.routed"] == 3 * 400
+    waves = obs.get_registry().summary("grow.waves").values()
+    assert len(waves) == 3
+    assert snap["counters"]["rows.routed"] == sum(waves) * _padded(400)
     assert snap["spans_recorded"] == 0       # tracer stayed silent
 
 
@@ -397,7 +368,9 @@ def test_resume_counts_only_new_iterations(clean_registry, tmp_path):
               num_boost_round=8, resume_from="auto")
     snap = obs.snapshot()["counters"]
     assert snap["trees.trained"] == 8            # 4 first run + 4 NEW
-    assert snap["rows.routed"] == 8 * 400
+    waves = obs.get_registry().summary("grow.waves").values()
+    assert len(waves) == 8                       # restored ones not again
+    assert snap["rows.routed"] == sum(waves) * _padded(400)
 
 
 def test_flush_on_failed_training(telemetry):
