@@ -15,7 +15,7 @@ PYTEST := python -m pytest -q
 #      whole-package call graph; findings gate unless covered by
 #      tpu_lint_baseline.json.
 #   2. trace tier — contracts T001+ over the SHIPPED entry points' jaxprs
-#      and optimized HLO (sort-free wave body, gather-free bundle routing,
+#      and optimized HLO (one sort a compacted wave, gather-free bundle routing,
 #      collective set vs the cost model, f64 discipline, donation
 #      aliasing, no host transfers in loop bodies); gates unless covered
 #      by trace_lint_baseline.json.

@@ -3,7 +3,7 @@
 Each check exposes ``run(program) -> list[str]`` where every failure
 message starts with a stable kind token (``forbidden-primitive``,
 ``required-collective``, ``dtype``, ``donation``, ``host-transfer``,
-``count``) — the token is the baseline fingerprint component, so message
+``count``, ``row-pass``) — the token is the baseline fingerprint component, so message
 wording can evolve without rotting baselines.
 """
 from __future__ import annotations
@@ -57,6 +57,52 @@ class ForbidPrimitives:
         return [f"forbidden-primitive: `{n}` present in the traced program"
                 f"{' (inside a loop body)' if self.where == 'loops' else ''}"
                 for n in sorted(self.names & present)]
+
+
+class RowPassesInLoops:
+    """Row-sized passes a device loop may hold, per iteration. An operand
+    is row-sized when its leading dimension is the program's ``rows``.
+
+    - at most ``max_sorts`` row-sized ``sort`` equations inside loop
+      bodies, each inside a ``cond`` branch (only the iterations that take
+      the branch pay for it);
+    - no ``scatter``, ``scatter-add`` or ``cumsum`` over a row-sized
+      operand anywhere in a loop body: on the TPU a row-sized scatter
+      expands to a sort of (index, value) pairs plus a gather-priced write,
+      which no jaxpr-level check can see (PERF.md, PR 27/28), and a
+      row-sized cumsum to a reduce-window."""
+
+    forbid = frozenset({"scatter", "scatter-add", "cumsum"})
+
+    def __init__(self, max_sorts: int = 1):
+        self.max_sorts = max_sorts
+
+    def run(self, p: TracedProgram):
+        if not p.rows:
+            return ["row-pass: contract target states no row count "
+                    "(builder bug)"]
+        sorts, bare, forbidden = 0, 0, set()
+        for eqn, in_cond in ju.loop_body_eqns_in_cond(p.jaxpr):
+            name = eqn.primitive.name
+            if p.rows not in ju.leading_dims(eqn):
+                continue
+            if name == "sort":
+                sorts += 1
+                bare += not in_cond
+            elif name in self.forbid:
+                forbidden.add(name)
+        out = []
+        if sorts > self.max_sorts:
+            out.append(f"row-pass: {sorts} row-sized `sort`s inside loop "
+                       f"bodies, contract allows {self.max_sorts}")
+        if bare:
+            out.append(f"row-pass: {bare} row-sized `sort`(s) in a loop "
+                       f"body outside any `cond` branch — every iteration "
+                       f"pays for it")
+        out += [f"row-pass: row-sized `{n}` inside a loop body — every "
+                f"iteration pays a pass over all rows (a scatter also "
+                f"hides a sort)" for n in sorted(forbidden)]
+        return out
 
 
 class RequiredCollectives:

@@ -11,7 +11,7 @@ dead code.
 Shape classes:
 
 - ``serial``        single-device resident growth / fused train step
-- ``serial_legacy`` tpu_incremental_partition=false A/B arm (violates)
+- ``serial_carried`` tpu_incremental_partition=true parity arm (violates)
 - ``u4_packed``     u4 packed-row code layout (tpu_code_mode=u4)
 - ``data8``         data-parallel over the 8 hermetic CPU devices
 - ``stream_shard``/``stream_wave``  StreamedGrower's two device legs
@@ -45,13 +45,14 @@ def _wave_spec(**over):
               chunk_rows=256, hist_slots=4, wave_size=4, max_depth=0,
               lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=5.0,
               min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0,
-              row_compact=True, incremental_partition=True, compact_frac=1.0)
+              row_compact=True, incremental_partition=False,
+              compact_frac=1.0)
     kw.update(over)
     return GrowerSpec(**kw)
 
 
 def _wave_program(shape_class: str, spec, comm=None, comm_bytes=None,
-                  N: int = 1024, grow=None) -> TracedProgram:
+                  N: int = 1024, grow=None, rows: int = 0) -> TracedProgram:
     F, B = spec.num_features, spec.num_bins_padded
     if grow is None:
         entry = get_entry("grower.wave_body")
@@ -68,7 +69,8 @@ def _wave_program(shape_class: str, spec, comm=None, comm_bytes=None,
         lambda Xa, gg, hh, inc: grow(Xa, gg, hh, inc, jnp.ones(F, bool),
                                      jnp.zeros(F, bool), nb, zf, zf))(
         X, g, ones, ones)
-    return TracedProgram("grower.wave_body", shape_class, jx, comm=comm_bytes)
+    return TracedProgram("grower.wave_body", shape_class, jx, comm=comm_bytes,
+                         rows=rows or N)
 
 
 @program_builder("grower.wave_body", "serial")
@@ -76,11 +78,12 @@ def _wave_serial():
     return _wave_program("serial", _wave_spec())
 
 
-@program_builder("grower.wave_body", "serial_legacy")
-def _wave_serial_legacy():
-    # the pre-incremental-partition A/B arm: per-wave argsort compaction
-    return _wave_program("serial_legacy",
-                         _wave_spec(incremental_partition=False))
+@program_builder("grower.wave_body", "serial_carried")
+def _wave_serial_carried():
+    # the parity arm: the row permutation carried across waves and
+    # re-partitioned by gather + cumsums + scatter in EVERY wave
+    return _wave_program("serial_carried",
+                         _wave_spec(incremental_partition=True))
 
 
 @program_builder("grower.wave_body", "u4_packed")
@@ -110,7 +113,7 @@ def _wave_data8():
 
     sharded = pctx.shard_grow(grow_fn)
     return _wave_program(
-        "data8", spec, N=N, grow=sharded,
+        "data8", spec, N=N, grow=sharded, rows=N // D,
         comm_bytes=lambda: comm.collective_bytes(
             spec.hist_slots, B, use_categorical=False))
 
@@ -283,13 +286,18 @@ def _train_step_program():
 # --------------------------------------------------------------- contracts
 
 contract(
-    "T001", "no sort in the steady-state wave loop", "grower.wave_body",
-    checks=[C.ForbidPrimitives({"sort"})],
+    "T001", "at most one row-sized sort a wave, in the compacted arm; no "
+            "row-sized scatter or cumsum in the wave loop",
+    "grower.wave_body",
+    checks=[C.RowPassesInLoops(max_sorts=1)],
     targets=[Target("serial"), Target("u4_packed"),
-             Target("serial_legacy", "violates")],
-    doc="Incremental partition derives row grouping from carried state; "
-        "the legacy arm's per-wave argsort compaction is the A/B pin that "
-        "keeps this check sensitive.")
+             Target("serial_carried", "violates")],
+    doc="A compacted pass builds its slot-grouped row index with one sort "
+        "inside its arm of the cond; a streamed wave builds nothing. The "
+        "carried permutation (tpu_incremental_partition=true) re-partitions "
+        "all rows every wave through a row-sized scatter, which on the TPU "
+        "hides a sort of its own and cost half the tree (PERF.md, PR 28): "
+        "the arm that keeps this check sensitive.")
 
 contract(
     "T002", "no gather in bundle-space routing", "routing.bundle_space",

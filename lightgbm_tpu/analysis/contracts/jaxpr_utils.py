@@ -52,14 +52,33 @@ def count_primitive(jaxpr, name: str) -> int:
 def loop_body_eqns(jaxpr) -> Iterator:
     """Equations living INSIDE while/scan bodies (any nesting depth) —
     the per-iteration cost surface."""
+    return (eqn for eqn, _ in loop_body_eqns_in_cond(jaxpr))
+
+
+def loop_body_eqns_in_cond(jaxpr, _in_loop: bool = False,
+                           _in_cond: bool = False) -> Iterator:
+    """``(eqn, in_cond)`` for every equation inside a while/scan body;
+    ``in_cond`` says the equation sits in a branch of a ``cond`` that is
+    itself inside the loop — work only SOME iterations pay."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name in _LOOP_PRIMS:
-            for sub in _inner_jaxprs(eqn.params):
-                yield from iter_eqns(sub)
-        else:
-            for sub in _inner_jaxprs(eqn.params):
-                yield from loop_body_eqns(sub)
+        name = eqn.primitive.name
+        if _in_loop:
+            yield eqn, _in_cond
+        for sub in _inner_jaxprs(eqn.params):
+            yield from loop_body_eqns_in_cond(
+                sub, _in_loop or name in _LOOP_PRIMS,
+                _in_cond or (_in_loop and name == "cond"))
+
+
+def leading_dims(eqn) -> Set[int]:
+    """Leading dimension of every array operand of ``eqn``."""
+    out: Set[int] = set()
+    for v in eqn.invars:
+        shape = getattr(getattr(v, "aval", None), "shape", None)
+        if shape:
+            out.add(int(shape[0]))
+    return out
 
 
 def out_dtype_names(jaxpr) -> Set[str]:

@@ -14,8 +14,8 @@ Everything here is importable without jax (the decorator rides inside
 A target's ``expect`` field makes sensitivity first-class:
 
 - ``"clean"``   — every check must pass (the shipped configuration);
-- ``"violates"``— at least one check must FAIL (a legacy arm kept as the
-  A/B pin, e.g. ``tpu_incremental_partition=false``'s per-wave argsort).
+- ``"violates"``— at least one check must FAIL (an arm kept as the A/B
+  pin, e.g. ``tpu_incremental_partition=true``'s per-wave scatter).
   If a violates-target starts passing, the contract has silently lost its
   teeth and lint reports *that* — tests and lint assert the same predicate
   through this one implementation.
@@ -73,6 +73,7 @@ class TracedProgram:
     donate_argnums: Tuple[int, ...] = ()
     expected_aliases: int = 0       # flat donated array leaves
     comm: Any = None                # collective_bytes() dict / 0-arg callable
+    rows: int = 0                   # rows one device holds (0: not stated)
     notes: str = ""
 
     _hlo_text: Optional[str] = None

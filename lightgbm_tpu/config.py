@@ -341,14 +341,16 @@ class Config:
     tpu_row_compact: bool = True
     tpu_compact_frac: float = 0.25            # compact passes below this
                                               # active-row fraction
-    # incremental leaf partition (grower.py GrowState.perm — the reference's
-    # DataPartition, data_partition.hpp:94): the slot-grouped row permutation
-    # is maintained ACROSS waves by a cumsum-based stable counting-sort over
-    # the split leaves' segments, so the wave body carries no full-N stable
-    # argsort / [N,S] count reduction / slot table_lookup. false = the
-    # legacy per-wave argsort rebuild (bit-identical — the A/B + parity pin,
-    # tests/test_incremental_partition.py)
-    tpu_incremental_partition: bool = True
+    # how a compacted pass gets its slot-grouped row index (grower.py, phase
+    # wave.partition). false (default since PR 28): one stable sort of the
+    # rows by pending slot, inside the compacted arm of the wave's cond;
+    # streamed waves build nothing. true: the permutation carried ACROSS
+    # waves (GrowState.perm — the reference's DataPartition,
+    # data_partition.hpp:94), re-partitioned every wave by gather + cumsums
+    # + scatter: bit-identical trees, the parity oracle of
+    # tests/test_incremental_partition.py, and 3.4 s of a 6.6 s tree at
+    # 14.7M rows on the v5e (PERF.md, PR 28)
+    tpu_incremental_partition: bool = False
     # LEGACY EFB scan arm: unpack bundle-space histograms into full
     # [T, F, B, 3] feature space before split finding and route rows
     # through the per-row bundle-decode gather — the pre-redesign layout

@@ -209,20 +209,31 @@ class GrowState(NamedTuple):
     parent_cache: jnp.ndarray     # i32 [L+1] cache row holding the parent hist
     num_leaves_cur: jnp.ndarray   # i32
     done: jnp.ndarray             # bool
-    # Incremental leaf partition (the reference's DataPartition,
-    # data_partition.hpp:94, maintained ACROSS waves): rows of leaf l occupy
-    # positions [seg_start[l], seg_start[l] + seg_rows[l]) of `perm`, in
-    # ascending original row order — stable splits preserve that order, so
-    # the compacted gather sequence is BIT-identical to the legacy per-wave
-    # stable-argsort path. seg_rows are RAW row counts (OOB/padding rows
-    # included; they route but carry zero weights), distinct from the
-    # bagging-weighted `cnt`. All three are None when the incremental
-    # partition is off (row_compact=false or tpu_incremental_partition=
-    # false) — None is a static empty pytree leaf, so the while_loop carry
-    # stays structurally consistent.
+    # The CARRIED leaf partition (tpu_incremental_partition=true; the
+    # reference's DataPartition, data_partition.hpp:94, maintained ACROSS
+    # waves): rows of leaf l occupy positions [seg_start[l], seg_start[l] +
+    # seg_rows[l]) of `perm`, in ascending original row order — stable
+    # splits preserve that order, so the compacted gather sequence is
+    # BIT-identical to the default's per-compacted-wave sort. Not what runs
+    # by default since PR 28: re-partitioning `perm` cost 3.19 s of a 6.64 s
+    # tree at 14.7M rows on the v5e (0.126 s of gathers + 0.072 s of scatter
+    # + 0.028 s of the sort XLA puts inside that scatter, every wave; my
+    # chip run, PR 27) — kept as the parity oracle until the next
+    # `simplicity` PR deletes it. seg_rows are RAW row counts (OOB/padding
+    # rows included; they route but carry zero weights), distinct from the
+    # bagging-weighted `cnt`. All three are None unless the carried
+    # partition is on (row_compact and tpu_incremental_partition=true) —
+    # None is a static empty pytree leaf, so the while_loop carry stays
+    # structurally consistent.
     perm: Optional[jnp.ndarray] = None       # i32 [N] leaf-contiguous rows
     seg_start: Optional[jnp.ndarray] = None  # i32 [L+1]
     seg_rows: Optional[jnp.ndarray] = None   # i32 [L+1]
+    # The default's only per-row carry besides leaf_id: the histogram slot
+    # of the row's leaf in the NEXT wave (-1: its leaf is not pending),
+    # written by the routing pass that moved the row (step 8), so no wave
+    # pays a second per-row table_lookup. None with the carried partition
+    # or row_compact off.
+    slot_row: Optional[jnp.ndarray] = None   # i32 [N]
     # the loop's own counters (resident loop only; None under streaming,
     # where the host drives the waves)
     stats: Optional[WaveStats] = None
@@ -244,17 +255,21 @@ class GrowerSpec:
     min_sum_hessian_in_leaf: float
     min_gain_to_split: float
     row_compact: bool = True      # histogram only pending-leaf rows per wave
-    incremental_partition: bool = True
-                                  # maintain the leaf-contiguous row
-                                  # permutation ACROSS waves (GrowState.perm,
-                                  # the DataPartition analog): compacted
-                                  # passes read it through a per-chunk
-                                  # position remap and the per-wave full-N
-                                  # stable argsort + [N,S] count reduction +
-                                  # slot table_lookup disappear from the
-                                  # wave body. False = the legacy per-wave
-                                  # argsort rebuild (bit-identical, pinned
-                                  # by tests/test_incremental_partition.py)
+    incremental_partition: bool = False
+                                  # how a COMPACTED pass gets its
+                                  # slot-grouped row index. False (default):
+                                  # nothing is carried; the compacted arm of
+                                  # the wave's cond builds the index with
+                                  # one stable sort of the rows by pending
+                                  # slot (phase wave.partition), a streamed
+                                  # wave builds nothing. True: the carried
+                                  # permutation (GrowState.perm), re-
+                                  # partitioned EVERY wave by gather +
+                                  # cumsums + scatter — bit-identical, the
+                                  # parity oracle of
+                                  # tests/test_incremental_partition.py,
+                                  # 1.8x slower end to end at 14.7M rows
+                                  # (PERF.md, PR 28)
     compact_frac: float = 0.25    # compact when n_active < frac*N. The
                                   # round-5 trace put the hist matmul at 92%
                                   # MXU peak, so the remaining lever is the
@@ -396,10 +411,10 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     per-row decode.
 
     Returns ``(state', table, map_mask, p, q, n_apply)`` with ``state'``
-    carrying every field EXCEPT the per-row ones (leaf_id and the
-    incremental partition), which the caller owns; ``p``/``q`` are the
-    per-slot split/new-right leaves the resident partition maintenance
-    keys on.
+    carrying every field EXCEPT the per-row ones (leaf_id, the next
+    wave's slot of each row, or the carried partition), which the caller
+    owns; ``p``/``q`` are the per-slot split/new-right leaves the resident
+    loop's per-row bookkeeping (step 8) keys on.
     """
     L = spec.num_leaves
     M = L - 1
@@ -574,7 +589,7 @@ def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: jnp.ndarray,
     histogram build — under streaming it runs per shard (fused ahead of the
     shard's histogram leg) on exactly these ops. Returns
     ``(leaf_id, f_row, go_left, right_row)``; the trailing three feed the
-    resident incremental-partition maintenance (step 8)."""
+    resident loop's per-row bookkeeping (step 8)."""
     packed = table_lookup(lid, table)                         # [N, 6|11]
     f_row = packed[:, 0]
     thr_row = packed[:, 1]
@@ -620,6 +635,27 @@ def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: jnp.ndarray,
         go_left = jnp.where(cat_row, go_left_cat, go_left)
     leaf_id = jnp.where((f_row >= 0), jnp.where(go_left, lid, right_row), lid)
     return leaf_id, f_row, go_left, right_row
+
+
+def _rows_by_slot(slot_row: jnp.ndarray, num_slots: int) -> jnp.ndarray:
+    """Row numbers grouped by pending slot, ascending within a slot, the
+    rows of no pending leaf last: the index a compacted pass reads, from ONE
+    sort over the rows.
+
+    Where the slot and the row number fit one int32 word (rows <= 2^24 on
+    this device and slots < 2^7: a function of the shapes, not a knob) the
+    keys ``slot << 24 | row`` are unique, so an unstable sort of that one
+    operand returns exactly the stable order and moves half the bytes;
+    beyond that it is the stable sort of (slot, row) pairs. At 14,680,064
+    rows on the v5e: 0.0116 s against 0.0328 s, where one element gather
+    over the rows costs 0.127 s (my chip run, PR 28)."""
+    n = slot_row.shape[0]
+    key = jnp.where(slot_row >= 0, slot_row, num_slots)
+    rows = jax.lax.iota(jnp.int32, n)
+    one_word = n <= 1 << 24 and num_slots < 1 << 7
+    operands = ((key << 24) | rows,) if one_word else (key, rows)
+    out = jax.lax.sort(operands, num_keys=1, is_stable=not one_word)
+    return out[0] & ((1 << 24) - 1) if one_word else out[1]
 
 
 @trace_entry("grower.wave_body")
@@ -718,11 +754,14 @@ def grow_tree(
     else:
         packed_rows = None
 
-    # incremental partition (tentpole): rows start as ONE root segment in
+    # how a compacted pass gets its slot-grouped row index: by one sort in
+    # its own arm (the default), or from the permutation carried across
+    # waves (the parity oracle), where rows start as ONE root segment in
     # original order — the identity permutation, rebuilt per tree (iota is
     # free; a cross-tree carry would violate the ascending-within-segment
     # invariant the root segment needs)
     use_inc = spec.row_compact and spec.incremental_partition
+    use_sort = spec.row_compact and not use_inc
 
     tree = _empty_tree(L, B)
     state = GrowState(
@@ -744,18 +783,23 @@ def grow_tree(
         seg_start=jnp.zeros(L + 1, jnp.int32) if use_inc else None,
         seg_rows=(jnp.zeros(L + 1, jnp.int32).at[0].set(N)
                   if use_inc else None),
+        # the root is pending in slot 0, and every row is in it
+        slot_row=jnp.zeros(N, jnp.int32) if use_sort else None,
         stats=_empty_stats(L),
     )
 
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
 
+    def slots_of(pending):
+        """leaf -> histogram slot (-1: not pending), and the slots' ranks."""
+        slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
+        return jnp.where(pending, slot_rank, -1).astype(jnp.int32), slot_rank
+
     def wave(state: GrowState) -> GrowState:
         # ---- 1. slot assignment for leaves needing histograms --------------
         with jax.named_scope("wave.slots"):
             pending = state.needs_hist
-            slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
-            slot_of_leaf = jnp.where(pending, slot_rank,
-                                     -1).astype(jnp.int32)            # [L+1]
+            slot_of_leaf, slot_rank = slots_of(pending)               # [L+1]
             # leaf served by each slot (or L = scratch)
             leaf_of_slot = jnp.full(S, L, jnp.int32).at[
                 jnp.where(pending, slot_rank, S)  # invalid -> dropped (S OOB)
@@ -808,9 +852,7 @@ def grow_tree(
             if use_inc:
                 # slot bookkeeping straight from the carried partition:
                 # counts/starts are [S]-sized gathers from the per-leaf
-                # segment tables, n_active a [S] reduction — the per-wave
-                # full-N table_lookup + compare-sum + stable argsort of the
-                # legacy path all disappear from the wave body.
+                # segment tables, n_active a [S] reduction.
                 # leaf_of_slot == L for empty slots and seg_rows[L] stays 0,
                 # so invalid slots contribute nothing.
                 with jax.named_scope("wave.slots"):
@@ -818,26 +860,24 @@ def grow_tree(
                     slot_starts_inc = state.seg_start[leaf_of_slot]   # [S]
                     n_active = jnp.sum(slot_counts_inc)
             else:
+                # the slot of every row's leaf came with the routing pass
+                # that moved the row (step 8): one reduction decides the arm
                 with jax.named_scope("wave.slots"):
-                    slot_row = table_lookup(state.leaf_id,
-                                            slot_of_leaf)             # [N] i32
+                    slot_row = state.slot_row                         # [N] i32
                     n_active = jnp.sum((slot_row >= 0).astype(jnp.int32))
 
             def compact_pass():
                 if use_inc:
                     # rows already slot-grouped inside the carried
                     # permutation; the kernels map compacted positions into
-                    # the pending segments via slot_starts (active chunks
-                    # only — steady-state waves never touch inactive rows)
+                    # the pending segments via slot_starts
                     return hist_pass(state.perm, n_active, slot_counts_inc,
                                      slot_starts_inc)
-                # legacy rebuild: rows grouped by slot, original order
-                # within a slot (stable) — kept as the A/B + parity pin for
-                # the incremental path (tpu_incremental_partition=false)
+                # the default: rows grouped by slot, original order within
+                # a slot, built here and now by one sort — only the waves
+                # that take this arm pay for it, and nothing is carried
                 with jax.named_scope("wave.partition"):
-                    key = jnp.where(slot_row >= 0, slot_row,
-                                    jnp.int32(2 ** 30))
-                    row_idx = jnp.argsort(key, stable=True).astype(jnp.int32)
+                    row_idx = _rows_by_slot(slot_row, S)
                     counts = jnp.sum(
                         (slot_row[:, None]
                          == jnp.arange(S, dtype=jnp.int32)[None, :])
@@ -893,18 +933,19 @@ def grow_tree(
         leaf_id, f_row, go_left, right_row = _route_rows(
             X, state.leaf_id, table, map_mask, spec, bundle, default_bin)
 
-        # ---- 8. incremental partition maintenance --------------------------
-        # The reference's DataPartition::Split (data_partition.hpp:94): only
-        # the split leaves' segments re-partition — STABLY, via the same
-        # prefix-sum + monotonic-scatter machinery as compact_rows
-        # (ops/histogram.py:251), never a sort. Leaf p keeps the front of
-        # its old segment (its go-left rows, original order), new leaf q
-        # takes the back — so within-segment ascending row order survives
-        # and the next wave's compacted gather sequence is bit-identical to
-        # the legacy stable-argsort path. All bookkeeping piggybacks on the
-        # routing pass above: the split ordinal of a row's leaf is recovered
-        # from the SAME table_lookup output (q = num_leaves_cur + srank), so
-        # no extra per-row lookup runs.
+        # ---- 8. per-row bookkeeping for the next wave ----------------------
+        # Carried-partition arm (the parity oracle): the reference's
+        # DataPartition::Split (data_partition.hpp:94). Only the split
+        # leaves' segments re-partition — STABLY, by prefix sums and a
+        # monotonic scatter over ALL positions (which the TPU's compiler
+        # expands into a sort of its own: 3.19 s of a 6.64 s tree at 14.7M
+        # rows, my chip run, PR 27). Leaf p keeps the front of its old
+        # segment (its go-left rows, original order), new leaf q takes the
+        # back — so within-segment ascending row order survives and the
+        # next wave's compacted gather sequence is bit-identical to the
+        # default's stable order by (slot, row). The split ordinal of a
+        # row's leaf is recovered from the routing pass's own table_lookup
+        # output (q = num_leaves_cur + srank).
         if use_inc:
             with jax.named_scope("wave.partition"):
                 k_row = jnp.where(f_row >= 0,
@@ -949,6 +990,25 @@ def grow_tree(
         else:
             perm, seg_start, seg_rows = (state.perm, state.seg_start,
                                          state.seg_rows)
+        slot_row_next = None
+        if use_sort:
+            # The next wave's pending leaves are children of THIS wave's
+            # splits (needs_hist is reset and set for the smaller child
+            # only), so the slot of a row's new leaf is a function of its
+            # split's ordinal k and its side, both already in the routing
+            # pass's output: an integer one-hot multiply-sum over the S
+            # ordinals, as exact and as cheap as the routing's own, where a
+            # table_lookup(leaf_id, slot_of_leaf) would be a second pass
+            # over a 256-wide one-hot. Rows of unsplit leaves get -1.
+            with jax.named_scope("wave.slots"):
+                slot_next, _ = slots_of(state2.needs_hist)            # [L+1]
+                k_row = jnp.where(f_row >= 0,
+                                  right_row - state.num_leaves_cur, -1)
+                k_onehot = (k_row[:, None]
+                            == jnp.arange(S, dtype=jnp.int32)[None, :])
+                slot_l = jnp.sum(k_onehot * (slot_next[p] + 1)[None, :], axis=1)
+                slot_r = jnp.sum(k_onehot * (slot_next[q] + 1)[None, :], axis=1)
+                slot_row_next = jnp.where(go_left, slot_l, slot_r) - 1
 
         # ---- 9. the loop's own counters, one entry per wave ----------------
         with jax.named_scope("wave.stats"):
@@ -966,7 +1026,7 @@ def grow_tree(
 
         return state2._replace(leaf_id=leaf_id, perm=perm,
                                seg_start=seg_start, seg_rows=seg_rows,
-                               stats=stats)
+                               slot_row=slot_row_next, stats=stats)
 
     def cond(state: GrowState):
         return ~state.done

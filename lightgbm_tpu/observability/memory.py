@@ -112,7 +112,7 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
                             chunk_rows: int, channels: int,
                             channel_bytes: int, packed_row_bytes: int = 0,
                             row_compact: bool = True,
-                            incremental: bool = True, bagging: bool = False,
+                            incremental: bool = False, bagging: bool = False,
                             has_weight: bool = False, tree_batch: int = 1,
                             compensated: bool = False,
                             valid_bytes: int = 0,
@@ -132,8 +132,10 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
     - metadata:   label/pad_mask(/bag_mask/weight) row vectors, f32
     - scores:     the [K, N] carried score (donation keeps ONE copy live)
     - gradients:  g and h, [K, N] f32 each
-    - partition:  leaf_id (+ the carried permutation and segment tables
-                  under the incremental partition)
+    - partition:  leaf_id, and with row_compact one more int32 a row (the
+                  row's slot in the next wave; with
+                  tpu_incremental_partition=true the carried permutation
+                  instead, and its two segment tables)
     - packed:     the per-tree packed gather rows (code bytes + weight
                   channel bytes per row)
     - hist_cache: the [L+1, F_cache, B_cache, 3] f32 per-leaf cache
@@ -154,7 +156,7 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
     comp["metadata"] = rows * f32 * (2 + int(bagging) + int(has_weight))
     comp["scores"] = num_models * rows * f32
     comp["gradients"] = 2 * num_models * rows * f32
-    comp["partition"] = rows * f32 * (2 if incremental else 1) \
+    comp["partition"] = rows * f32 * (2 if row_compact else 1) \
         + (2 * (num_leaves + 1) * f32 if incremental else 0)
     comp["packed"] = rows * packed_row_bytes if row_compact else 0
     comp["hist_cache"] = (num_leaves + 1) * cache_cols * cache_bins * 3 * f32

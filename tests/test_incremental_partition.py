@@ -1,19 +1,27 @@
-"""Incremental leaf partition (grower.py GrowState.perm — the reference's
-DataPartition analog, maintained across waves).
+"""How a compacted histogram pass gets its slot-grouped row index
+(grower.py ``grow_tree``, phase ``wave.partition``).
 
-Pins the tentpole contracts of the wave-loop fixed-cost PR:
+Two arms, pinned BIT-identical to each other:
 
-- the steady-state wave body compiles to a jaxpr with NO sort primitive
-  (the per-wave full-N stable argsort is gone); the legacy path
-  (tpu_incremental_partition=false) still contains one — which both keeps
-  the A/B comparison honest and proves the inspection itself is sensitive;
-- trees grown with the incremental partition are BIT-identical to the
-  legacy per-wave argsort rebuild: serial and tree_learner=data, bagging +
-  feature_fraction RNG, forced compaction (tpu_compact_frac=1.0), u4
-  bit-packed code mode, exact leaf-wise ordering (tpu_wave_size=1),
-  tree_batch>1, checkpoint-resume mid-tree-batch, and the mixed
-  XLA/Pallas kernel dispatch (interpret mode);
-- the config knob round-trips.
+- the default (``tpu_incremental_partition=false``, since PR 28): nothing
+  is carried; a wave whose pass is compacted builds the index with ONE
+  stable sort of the rows by pending slot, inside the compacted arm of the
+  wave's ``cond``; a streamed wave builds nothing;
+- the carried partition (``tpu_incremental_partition=true``; GrowState.perm,
+  the reference's DataPartition analog): re-partitioned in EVERY wave by
+  gather + cumsums + a row-sized scatter. On the v5e that scatter hides a
+  sort of its own and the arm cost half the tree (PERF.md, PR 28); it stays
+  as the parity oracle.
+
+Pins: the wave body holds at most one row-sized sort, in the compacted arm,
+and no row-sized scatter or cumsum (contract T001; the carried arm is the
+target that violates; the COMPILED step's row-sized sorts are counted in
+tests/test_named_scopes.py); trees are bit-identical
+between the arms: serial and tree_learner=data, bagging + feature_fraction
+RNG, forced compaction (tpu_compact_frac=1.0), u4 bit-packed code mode,
+exact leaf-wise ordering (tpu_wave_size=1), tree_batch>1, checkpoint-resume
+mid-tree-batch, and the mixed XLA/Pallas kernel dispatch (interpret mode);
+the config knob round-trips.
 """
 import numpy as np
 import pytest
@@ -59,29 +67,48 @@ def _assert_identical(b1, b2, X):
 
 
 # ---------------------------------------------------------------- jaxpr pin
-# The wave-loop sort pin lives in the trace-contract registry (contract
-# T001, analysis/contracts/entries.py) — this test asserts THROUGH the
-# registry, so the test and `python -m lightgbm_tpu.analysis --trace`
-# check the same predicate via one implementation.
+# The wave-loop pin lives in the trace-contract registry (contract T001,
+# analysis/contracts/entries.py) — this test asserts THROUGH the registry,
+# so the test and `python -m lightgbm_tpu.analysis --trace` check the same
+# predicate via one implementation.
 
 @pytest.mark.parametrize("shape_class,expect_sort",
-                         [("serial", False), ("serial_legacy", True)])
+                         [("serial", True), ("serial_carried", False)])
 def test_wave_loop_jaxpr_sort_presence(shape_class, expect_sort):
-    """The steady-state wave body carries NO sort op on the incremental
-    path; the legacy path still does — proving both the tentpole claim and
-    the sensitivity of this very inspection."""
+    """The default wave body carries ONE sort primitive (the compacted
+    arm's) and no row-sized scatter; the carried arm carries no sort
+    primitive at all — and a row-sized scatter every wave, in which the
+    TPU's compiler hides a sort that no jaxpr walk can see (the device
+    trace did: PERF.md, PR 27)."""
     from lightgbm_tpu.analysis.contracts import (CONTRACTS, build_program,
-                                                 evaluate)
+                                                 evaluate, evaluate_target)
     from lightgbm_tpu.analysis.contracts import jaxpr_utils as ju
     import lightgbm_tpu.analysis.contracts.entries  # noqa: F401
 
     program = build_program("grower.wave_body", shape_class)
-    assert ju.has_primitive(program.jaxpr, "sort") == expect_sort
+    assert ju.count_primitive(program.jaxpr, "sort") == int(expect_sort)
     # and the registered contract reaches the same verdict: no findings,
     # on the clean arm OR the violates arm (whose failure is expected)
     c = CONTRACTS["T001"]
     t = next(t for t in c.targets if t.shape_class == shape_class)
     assert evaluate(c, t, program) == []
+    assert bool(evaluate_target(c, program)) == (t.expect == "violates")
+
+
+@pytest.mark.parametrize("num_slots", [25, 128])
+def test_rows_by_slot_is_the_stable_order(num_slots):
+    """One sort gives the stable order: by slot, ascending row within a
+    slot, rows of no pending leaf last — through the one-word key (slots
+    below 2^7) and through the (slot, row) pair sort alike."""
+    from lightgbm_tpu.grower import _rows_by_slot
+    rng = np.random.RandomState(num_slots)
+    slot = np.where(rng.rand(5000) < 0.3,
+                    rng.randint(0, num_slots, 5000), -1).astype(np.int32)
+    want = np.argsort(np.where(slot >= 0, slot, num_slots), kind="stable")
+    got = jax.jit(_rows_by_slot, static_argnums=1)(jnp.asarray(slot),
+                                                   num_slots)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # ------------------------------------------------------- bit-identity pins
@@ -173,12 +200,12 @@ def test_incremental_mixed_kernel_interpret(monkeypatch):
 def test_incremental_off_when_row_compact_off():
     """row_compact=false never builds the permutation carry (perm stays a
     None pytree leaf) and still trains; the knob round-trips through
-    Config."""
+    Config, and the default is the sort rebuild."""
     from lightgbm_tpu.config import Config
-    assert Config.from_params({}).tpu_incremental_partition is True
+    assert Config.from_params({}).tpu_incremental_partition is False
     assert Config.from_params(
-        dict(tpu_incremental_partition=False)).tpu_incremental_partition \
-        is False
+        dict(tpu_incremental_partition=True)).tpu_incremental_partition \
+        is True
     X, y = _make_binary(n=800)
     b = _train(X, y, True, tpu_row_compact=False, rounds=3)
     b2 = _train(X, y, False, tpu_row_compact=False, rounds=3)

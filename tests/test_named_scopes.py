@@ -70,16 +70,36 @@ def test_lowered_step_names_every_phase(learner):
                          r'wave\.hist\.compact\.gather/', text)
 
 
-def test_legacy_argsort_arm_sorts_under_the_partition_scope():
-    """``tpu_incremental_partition=false`` rebuilds the partition with a
-    per-wave argsort: the one row-sized sort of the wave body, and it
-    carries the partition's name."""
-    fn, args = _step_and_args(tpu_incremental_partition=False)
+def _row_sized(hlo: str, rows: int, op: str):
+    """The compiled program's ``op`` instructions over a row-sized array."""
+    return [ln for ln in hlo.splitlines()
+            if f" {op}(" in ln and f"[{rows}]" in ln]
+
+
+_IN_COMPACT_ARM = re.compile(r'op_name="[^"]*/while/body/[^"]*cond/[^"]*'
+                             r'wave\.hist\.compact/wave\.partition/')
+
+
+@pytest.mark.parametrize("carried", [False, True])
+def test_row_sized_sorts_of_the_compiled_step(carried):
+    """The default step as compiled: exactly ONE row-sized sort, under
+    ``wave.partition`` inside the compacted arm of the wave's ``cond``, and
+    no row-sized reduce-window (a cumsum) or scatter anywhere. The carried
+    arm (``tpu_incremental_partition=true``) has no sort of its own here,
+    and the row-sized scatter and cumsums that the TPU's compiler turns
+    into a sort and reduce-windows in every wave (the v5e case below)."""
+    fn, args = _step_and_args(tpu_incremental_partition=carried)
     hlo = fn.lower(*args).compile().as_text()
-    sorts = [ln for ln in hlo.splitlines()
-             if " sort(" in ln and "s32[2560]" in ln]        # row-sized
-    assert sorts
-    assert all("/wave.partition/" in ln for ln in sorts), sorts
+    sorts = _row_sized(hlo, 2560, "sort")            # 2500 rows, padded
+    if carried:
+        assert not sorts
+        scatters = _row_sized(hlo, 2560, "scatter")
+        assert scatters and all("/wave.partition/" in ln for ln in scatters)
+        assert not any(_IN_COMPACT_ARM.search(ln) for ln in scatters)
+    else:
+        assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
+        assert not _row_sized(hlo, 2560, "reduce-window")
+        assert not _row_sized(hlo, 2560, "scatter")
 
 
 def test_streamed_legs_share_the_split_and_route_scopes():
@@ -112,9 +132,9 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_v5e_fusions_carry_the_scopes(one_chip):
-    """What the device trace times are the compiled program's fusions:
-    each phase that moves rows owns at least one, named in its metadata."""
+@pytest.fixture(scope="module")
+def v5e_hlo(one_chip):
+    """The default step compiled for the described chip, as text."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     fn, args = _step_and_args()
     shapes = jax.tree.map(
@@ -126,11 +146,16 @@ def test_v5e_fusions_carry_the_scopes(one_chip):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        hlo = fn.lower(*shapes).compile().as_text()
+        return fn.lower(*shapes).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
-    ops = [ln for ln in hlo.splitlines() if "metadata={" in ln]
+
+
+def test_v5e_fusions_carry_the_scopes(v5e_hlo):
+    """What the device trace times are the compiled program's fusions:
+    each phase that moves rows owns at least one, named in its metadata."""
+    ops = [ln for ln in v5e_hlo.splitlines() if "metadata={" in ln]
     fusions = [ln for ln in ops if " fusion(" in ln]
     assert len(fusions) > 50
     owns_fusions = ("step.gradients", "step.grow",
@@ -149,3 +174,15 @@ def test_v5e_fusions_carry_the_scopes(one_chip):
             and "hist.kernel" in ln]
     assert any("wave.hist.stream" in ln for ln in dots)
     assert any("wave.hist.compact" in ln for ln in dots)
+
+
+def test_v5e_step_sorts_rows_once_in_the_compacted_arm(v5e_hlo):
+    """What no jaxpr walk can see: the sorts the TPU's compiler itself puts
+    in (it expands a row-sized scatter into a sort of (index, value) pairs:
+    the carried arm paid one in every wave, PERF.md PR 27). The default
+    step compiled for the v5e holds exactly one row-sized sort, the
+    compacted arm's own, and no row-sized reduce-window or scatter."""
+    sorts = _row_sized(v5e_hlo, 2560, "sort")
+    assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
+    assert not _row_sized(v5e_hlo, 2560, "reduce-window")
+    assert not _row_sized(v5e_hlo, 2560, "scatter")

@@ -91,12 +91,12 @@ def test_violates_targets_actually_violate():
     """The sensitivity arms really fail a check — otherwise evaluate()
     would have reported 'sensitivity lost' above, but assert the raw
     failures directly too."""
-    for cid, shape_class in [("T001", "serial_legacy"),
+    for cid, shape_class in [("T001", "serial_carried"),
                              ("T002", "bundled_unpack")]:
         c, t, program = _cell(cid, shape_class)
         assert t.expect == "violates"
         assert evaluate_target(c, program), \
-            f"{cid}: legacy arm {shape_class} no longer violates"
+            f"{cid}: parity arm {shape_class} no longer violates"
 
 
 def test_lost_sensitivity_is_reported():
