@@ -18,6 +18,9 @@ built in dataset.py.
 """
 from __future__ import annotations
 
+import bisect
+import math
+import os
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -34,6 +37,9 @@ MISSING_NAN = "nan"
 
 BIN_NUMERICAL = "numerical"
 BIN_CATEGORICAL = "categorical"
+
+# columns copied out of a row-major sample at once (sample_for_binning)
+_COLUMN_BLOCK = 64
 
 
 def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray, max_bin: int,
@@ -62,23 +68,53 @@ def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray, max_bin: in
     rest_sample_cnt = int(total_cnt - counts[is_big].sum())
     mean_bin_size = rest_sample_cnt / rest_bin_cnt if rest_bin_cnt > 0 else np.inf
 
+    # The reference walks the distinct values one by one and closes a bin at
+    # the first value i where (a) i is big, (b) the bin holds the mean size,
+    # or (c) i + 1 is big and the bin holds half of it. With j the next big
+    # value, (a) and (c) can only fire at j or j - 1, and (b) is a
+    # first-index query on the counts' prefix sum: the walk jumps from close
+    # to close, at most max_bin - 1 steps, where the loop took one Python
+    # step per distinct value (200,000 for a continuous column: 0.16 s of
+    # the GIL per feature). `bisect` and not `np.searchsorted`: the latter
+    # drops the GIL at every call, and sixteen pool threads each asking for
+    # it back a thousand times a column ran three times slower than one.
+    counts = np.asarray(counts, dtype=np.int64)
+    prefix = np.concatenate([[0], np.cumsum(counts)])          # [n+1]
+    prefix_small = np.concatenate([[0], np.cumsum(np.where(is_big, 0, counts))])
+    big_at = np.flatnonzero(is_big).tolist()
+    rest_total = rest_sample_cnt
+    last = num_distinct - 2                  # the loop's last index
+
     upper_bounds: List[float] = []
     lower_bounds: List[float] = [float(distinct_values[0])]
-    cur_cnt_inbin = 0
-    for i in range(num_distinct - 1):
+    start = 0
+    k = 0                                    # big_at[k]: next big value >= start
+    while start <= last:
+        held = int(prefix[start])
+        # (b): first i >= start whose bin [start, i] holds >= mean_bin_size
+        if mean_bin_size == np.inf:
+            i = num_distinct
+        else:
+            i = max(start, bisect.bisect_left(
+                prefix, held + math.ceil(mean_bin_size), lo=start) - 1)
+        while k < len(big_at) and big_at[k] < start:
+            k += 1
+        if k < len(big_at):
+            j = big_at[k]
+            half = max(1.0, mean_bin_size * 0.5)
+            # (c) at j - 1 when the bin up to there holds half, else (a) at j
+            i = min(i, j - 1 if j > start and int(prefix[j]) - held >= half else j)
+        if i > last:
+            break
+        upper_bounds.append(float(distinct_values[i]))
+        lower_bounds.append(float(distinct_values[i + 1]))
+        if len(upper_bounds) >= max_bin - 1:
+            break
         if not is_big[i]:
-            rest_sample_cnt -= int(counts[i])
-        cur_cnt_inbin += int(counts[i])
-        if (is_big[i] or cur_cnt_inbin >= mean_bin_size
-                or (is_big[i + 1] and cur_cnt_inbin >= max(1.0, mean_bin_size * 0.5))):
-            upper_bounds.append(float(distinct_values[i]))
-            lower_bounds.append(float(distinct_values[i + 1]))
-            if len(upper_bounds) >= max_bin - 1:
-                break
-            cur_cnt_inbin = 0
-            if not is_big[i]:
-                rest_bin_cnt -= 1
-                mean_bin_size = rest_sample_cnt / rest_bin_cnt if rest_bin_cnt > 0 else np.inf
+            rest_bin_cnt -= 1
+            rest_sample_cnt = rest_total - int(prefix_small[i + 1])
+            mean_bin_size = rest_sample_cnt / rest_bin_cnt if rest_bin_cnt > 0 else np.inf
+        start = i + 1
 
     bin_cnt = len(upper_bounds) + 1
     out = [(upper_bounds[i] + lower_bounds[i + 1]) / 2.0 for i in range(bin_cnt - 1)]
@@ -386,16 +422,36 @@ def sample_for_binning(data: np.ndarray, sample_cnt: int, seed: int) -> Tuple[np
     else:
         idx = np.arange(num_data)
         sample = data.tocsc() if sparse else data
-    per_feature = []
-    for j in range(sample.shape[1]):
-        if sparse:
+    if sparse:
+        per_feature = []
+        for j in range(sample.shape[1]):
             # stored entries only — implicit zeros are exactly what the
             # nonzero/NaN filter below drops for dense input (indptr slicing
             # works for csc_matrix and csc_array alike)
             lo, hi = sample.indptr[j], sample.indptr[j + 1]
             col = np.asarray(sample.data[lo:hi], dtype=np.float64)
-        else:
-            col = np.asarray(sample[:, j], dtype=np.float64)
-        keep = (np.abs(col) > K_EPSILON) | np.isnan(col)
-        per_feature.append(col[keep])
-    return idx, per_feature
+            per_feature.append(col[(np.abs(col) > K_EPSILON) | np.isnan(col)])
+        return idx, per_feature
+
+    # Dense rows are row-major: one column is a read at a stride of the row's
+    # width, a cache line fetched per value (10 s for 2,000 columns of a
+    # 200,000-row sample). A block of columns is transposed at once instead,
+    # each row giving up _COLUMN_BLOCK adjacent values, and the columns of
+    # the block are then contiguous; blocks go to a few threads (the copies
+    # and the filter release the GIL).
+    n_cols = sample.shape[1]
+
+    def block(j0: int) -> List[np.ndarray]:
+        cols = np.array(sample[:, j0:j0 + _COLUMN_BLOCK].T, dtype=np.float64,
+                        order="C")
+        return [c[(np.abs(c) > K_EPSILON) | np.isnan(c)] for c in cols]
+
+    starts = range(0, n_cols, _COLUMN_BLOCK)
+    workers = min(8, os.cpu_count() or 1, len(starts))
+    if workers <= 1:
+        blocks = [block(j0) for j0 in starts]
+    else:
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(workers) as pool:
+            blocks = list(pool.map(block, starts))
+    return idx, [c for b in blocks for c in b]

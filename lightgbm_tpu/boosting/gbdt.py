@@ -33,7 +33,7 @@ from ..config import Config
 from ..dataset import ConstructedDataset, Metadata, MetadataDuckTyping
 from ..grower import (GrowerSpec, TreeArrays, WaveStats, grow_tree,
                       wave_totals)
-from ..ops.histogram import num_channels, table_lookup
+from ..ops.histogram import hist_pass_shape, num_channels, table_lookup
 from ..parallel.comm import make_parallel_context
 from ..metrics import Metric, create_metrics
 from ..robustness import allowed_host_sync
@@ -360,11 +360,20 @@ class GBDT:
                 cols_pad = G_raw
         else:
             cols_pad = F_pad
-        chunk = min(config.tpu_hist_chunk, _round_up(per_target, 256))
         _kernel_dtype = (bundle_plan.X_bundled.dtype
                          if bundle_plan is not None
                          else train_set.code_dtype)
         _kernel_bins = Bb_pad if bundle_plan is not None else Bpad
+        # rows a chunk of the histogram pass: a static function of the
+        # shapes, with tpu_hist_chunk as its upper bound — today's 32,768
+        # rows up to 256 columns at 256 bins, fewer for a wider table
+        # (ops/histogram.hist_pass_shape). Feature-parallel devices
+        # histogram their own column block only.
+        _hist_cols = (cols_pad // self.pctx.num_devices
+                      if self.pctx.strategy == "feature" else cols_pad)
+        chunk, _shape_rule = hist_pass_shape(
+            per_target, _hist_cols, _kernel_bins,
+            4 if config.tpu_hist_f64 else 2, config.tpu_hist_chunk)
         # ---- residency (ROADMAP item 3, docs/TPU-Performance.md): decide
         #      BEFORE any device placement whether the binned code matrix
         #      is HBM-resident ("device") or streams from host shards
@@ -851,6 +860,23 @@ class GBDT:
         reg.gauge("booster.tree_batch").set(tb)
         reg.gauge("booster.wave_size").set(self.spec.wave_size)
         reg.gauge("booster.hist_slots").set(self.spec.hist_slots)
+        # the histogram pass as the shapes sized it: rows a chunk, the
+        # one-hot operand of one chunk's matmul (what the rule bounds; the
+        # TPU allocates none of it), the f32 accumulator every chunk reads
+        # and writes once
+        self._hist_acc_bytes = (
+            _hist_cols * _kernel_bins * self.spec.hist_slots
+            * num_channels("f32" if self.spec.hist_f64
+                           else self.spec.hist_hilo) * 4)
+        reg.gauge("hist.chunk_rows").set(self.spec.chunk_rows)
+        reg.gauge("hist.onehot_bytes").set(
+            self.spec.chunk_rows * _hist_cols * _kernel_bins
+            * (4 if self.spec.hist_f64 else 2))
+        reg.gauge("hist.acc_bytes").set(self._hist_acc_bytes)
+        obs.event("hist_pass_shape", rule=_shape_rule,
+                  chunk_rows=int(self.spec.chunk_rows),
+                  rows=int(per_target), features=int(_hist_cols),
+                  bins=int(_kernel_bins))
         if self._stream_store is not None:
             reg.gauge("stream.n_shards").set(self._stream_store.n_shards)
             reg.gauge("stream.shard_bytes").set(
@@ -2388,8 +2414,12 @@ class GBDT:
         tree, and — per tree, in tree order, from the wave loop's own
         record (grower.WaveStats / wave_totals), nothing modelled —
         ``grow.waves``, ``grow.hist_rows_touched``, ``grow.hist_rows_active``,
-        ``grow.rows_split``, ``grow.compact_passes``, ``grow.stream_passes``
-        and the counters ``rows.routed`` and ``hist.mxu_flops`` /
+        ``grow.rows_split``, ``grow.compact_passes``, ``grow.stream_passes``,
+        ``grow.hist_chunks`` (chunk matmuls the passes ran),
+        ``grow.hist_acc_bytes`` (accumulator bytes the passes read and
+        wrote: every chunk folds into it once), ``grow.scan_slots`` and
+        ``grow.scan_slots_pending``, and the counters ``rows.routed`` and
+        ``hist.mxu_flops`` /
         ``hist.floor_flops``. Under a row-sharded mesh every row count is
         the pace-setting shard's (per-wave maximum over devices): compare
         with the rows of ONE device."""
@@ -2423,11 +2453,18 @@ class GBDT:
         for stats in counted:
             for k in range(self.num_models):
                 t = wave_totals(jax.tree.map(lambda a, k=k: a[k], stats),
-                                rows, chunk)
+                                rows, chunk, spec.hist_slots)
                 for name in ("waves", "hist_rows_touched", "hist_rows_active",
-                             "rows_split", "compact_passes", "stream_passes"):
+                             "rows_split", "compact_passes", "stream_passes",
+                             "scan_slots", "scan_slots_pending"):
                     if t[name] is not None:
                         reg.summary("grow." + name).observe(t[name])
+                # the Pallas kernel keeps its accumulator in VMEM: its
+                # passes are not counted here
+                if spec.hist_kernel == "xla":
+                    reg.summary("grow.hist_chunks").observe(t["hist_chunks"])
+                    reg.summary("grow.hist_acc_bytes").observe(
+                        t["hist_chunks"] * self._hist_acc_bytes * 2)
                 reg.counter("rows.routed").inc(t["rows_routed"])
                 reg.counter("hist.mxu_flops").inc(
                     2 * t["hist_rows_touched"] * cells * spec.hist_slots * ch)

@@ -142,6 +142,10 @@ class WaveStats(NamedTuple):
     rows_split: jnp.ndarray     # i32 [W] rows of the leaves split this
                                 # wave: what routing and the partition
                                 # usefully move
+    scan_pending: jnp.ndarray   # i32 [W] of the 2 x hist_slots slots the
+                                # split scan runs over every wave, those
+                                # that held a leaf (a pending leaf or its
+                                # sibling by subtraction)
 
 
 def _empty_stats(L: int) -> WaveStats:
@@ -149,10 +153,12 @@ def _empty_stats(L: int) -> WaveStats:
     return WaveStats(waves=jnp.asarray(0, jnp.int32),
                      rows_active=jnp.zeros(W, jnp.int32),
                      compacted=jnp.zeros(W, bool),
-                     rows_split=jnp.zeros(W, jnp.int32))
+                     rows_split=jnp.zeros(W, jnp.int32),
+                     scan_pending=jnp.zeros(W, jnp.int32))
 
 
-def wave_totals(stats, rows_per_device: int, chunk_rows: int) -> Dict[str, int]:
+def wave_totals(stats, rows_per_device: int, chunk_rows: int,
+                hist_slots: int) -> Dict[str, int]:
     """Per-tree totals the host derives from one tree's ``WaveStats`` as
     fetched (numpy, leading device axis ``[D, ...]``), with no model of the
     loop: every number is a sum over the waves the loop itself recorded.
@@ -163,7 +169,11 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int) -> Dict[str, int]:
     chunk`` for a compacted one (build_histograms' trip count).
     ``hist_rows_active`` is the useful part of it (None where the loop did
     not count it). Routing and the partition pass over every row every
-    wave (``rows_routed``); ``rows_split`` is the useful part of that."""
+    wave (``rows_routed``); ``rows_split`` is the useful part of that.
+    ``hist_chunks`` is the chunks the passes ran (each folds into the
+    accumulator once). The split scan runs over 2 x ``hist_slots`` slots
+    every wave (``scan_slots``); ``scan_slots_pending`` of them held a
+    leaf."""
     waves = int(np.max(stats.waves))
 
     def per_wave(a, dtype):                              # -> [D, waves]
@@ -172,6 +182,7 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int) -> Dict[str, int]:
     active = per_wave(stats.rows_active, np.int64)
     compacted = per_wave(stats.compacted, bool)
     split = per_wave(stats.rows_split, np.int64)
+    scanned = per_wave(stats.scan_pending, np.int64)
     chunks = np.minimum(-(-np.maximum(active, 0) // chunk_rows),
                         rows_per_device // chunk_rows)
     touched = np.where(compacted, chunks * chunk_rows, rows_per_device)
@@ -182,6 +193,9 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int) -> Dict[str, int]:
             "stream_passes": streamed,
             "compact_passes": waves - streamed,
             "hist_rows_touched": int(touched.max(axis=0).sum()),
+            "hist_chunks": int(touched.max(axis=0).sum()) // chunk_rows,
+            "scan_slots": waves * 2 * hist_slots,
+            "scan_slots_pending": int(scanned.max(axis=0).sum()),
             "hist_rows_active": (int(active.max(axis=0).sum())
                                  if (active >= 0).all() else None),
             "rows_routed": waves * int(rows_per_device),
@@ -245,7 +259,8 @@ class GrowerSpec:
     num_leaves: int
     num_features: int             # width of X (histogram-build features)
     num_bins_padded: int
-    chunk_rows: int
+    chunk_rows: int               # rows a chunk of the histogram pass
+                                  # (ops/histogram.hist_pass_shape)
     hist_slots: int               # leaves histogrammed per pass == max splits/wave
     wave_size: int                # splits applied per wave (1 = exact leaf-wise)
     max_depth: int                # <=0: unlimited
@@ -423,14 +438,18 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
 
     # ---- 3. cache write + sibling by subtraction -----------------------
+    # "wave.cache": the parent read, the subtraction and the two write-backs
+    # of the [L+1, F, B, 3] cache, [S, F, B, 3] each (154 MB a wave at
+    # 2,000 columns), named apart from the scan they feed
     slot_valid = leaf_of_slot < L
     sibs = state.sib_leaf[leaf_of_slot]                       # [S]
-    parent_rows = state.parent_cache[leaf_of_slot]            # [S]
-    parent_hist = state.hist[parent_rows]                     # [S, F, B, 3]
-    sib_hist = parent_hist - new_hist
-    hist = state.hist
-    hist = hist.at[jnp.where(slot_valid, leaf_of_slot, L)].set(new_hist)
-    hist = hist.at[jnp.where(slot_valid, sibs, L)].set(sib_hist)
+    with jax.named_scope("wave.cache"):
+        parent_rows = state.parent_cache[leaf_of_slot]        # [S]
+        parent_hist = state.hist[parent_rows]                 # [S, F, B, 3]
+        sib_hist = parent_hist - new_hist
+        hist = state.hist
+        hist = hist.at[jnp.where(slot_valid, leaf_of_slot, L)].set(new_hist)
+        hist = hist.at[jnp.where(slot_valid, sibs, L)].set(sib_hist)
 
     # ---- 4. split scan for the 2S touched leaves -----------------------
     scan_leaves = jnp.concatenate([leaf_of_slot, jnp.where(slot_valid, sibs, L)])
@@ -1017,12 +1036,19 @@ def grow_tree(
             # carried partition a count over the routing pass's output
             rows_split = (jnp.sum(n_k) if use_inc else
                           jnp.sum((f_row >= 0).astype(jnp.int32)))
+            # slots of this wave's split scan that held a leaf: the pending
+            # leaves, and those of their siblings that exist (the root has
+            # none)
+            held = leaf_of_slot < L
+            scan_pending = jnp.sum(held.astype(jnp.int32)) + jnp.sum(
+                (held & (state.sib_leaf[leaf_of_slot] < L)).astype(jnp.int32))
             st = state.stats
             stats = WaveStats(
                 waves=st.waves + 1,
                 rows_active=st.rows_active.at[st.waves].set(n_active),
                 compacted=st.compacted.at[st.waves].set(compacted),
-                rows_split=st.rows_split.at[st.waves].set(rows_split))
+                rows_split=st.rows_split.at[st.waves].set(rows_split),
+                scan_pending=st.scan_pending.at[st.waves].set(scan_pending))
 
         return state2._replace(leaf_id=leaf_id, perm=perm,
                                seg_start=seg_start, seg_rows=seg_rows,

@@ -139,8 +139,12 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
     - packed:     the per-tree packed gather rows (code bytes + weight
                   channel bytes per row)
     - hist_cache: the [L+1, F_cache, B_cache, 3] f32 per-leaf cache
-    - wave_temps: the per-chunk one-hot operand, the [chunk, S*ch] rhs, and
-                  the [F, B, S*ch] f32 accumulator (x2 Kahan-compensated)
+    - wave_temps: the [chunk, S*ch] rhs and the [F, B, S*ch] f32 accumulator
+                  (x2 Kahan-compensated). NOT the [chunk, F, B] one-hot
+                  operand: the TPU compiler fuses its producer into the
+                  matmul and allocates none of it (0.68 GiB of temporaries
+                  at 2,000 columns whatever the chunk, where the operand
+                  would be 33.6 GB; PERF.md, PR 30)
     - trees:      stacked per-batch tree outputs (small)
     - valid:      attached validation sets (codes + scores), if any
     - linear:     linear_tree=true only (``linear_max_features`` > 0): the
@@ -162,7 +166,6 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
     comp["hist_cache"] = (num_leaves + 1) * cache_cols * cache_bins * 3 * f32
     acc = hist_cols * hist_bins * slots * channels * f32
     comp["wave_temps"] = (acc * (2 if compensated else 1)
-                          + chunk_rows * hist_cols * hist_bins * channel_bytes
                           + chunk_rows * slots * channels * channel_bytes)
     per_tree = ((num_leaves) * num_bins_padded          # cat_mask, bool
                 + 13 * (num_leaves + 1) * f32)          # node/leaf arrays

@@ -44,6 +44,36 @@ def num_channels(hilo) -> int:
     return NUM_CHANNELS if hilo is True else NUM_CHANNELS_FAST
 
 
+# ---- the pass, sized by the table's width ----------------------------------
+# One chunk of the pass is one matmul of a [chunk_rows, F, B] one-hot operand
+# against [chunk_rows, S*ch] weight columns into the [F, B, S*ch] f32
+# accumulator. The TPU fuses the operand's producer into the matmul and
+# allocates none of it (temporaries 0.68 GiB at 2,000 columns whatever the
+# chunk; PERF.md, PR 30), so its size is no memory bound there; the CPU
+# backend allocates it whole. What the chunk decides on the chip is the
+# pass's grain: the rows are padded to a whole number of chunks and a
+# compacted pass cannot be shorter than one, so a 32,768-row chunk of a
+# 400,000-row table pads 6.5% and makes every late wave cost a thirteenth
+# of the table. The rule holds chunk_rows x F x B x channel bytes to
+# _ONEHOT_BYTES_A_CHUNK: 32,768 rows up to 256 columns at 256 bins in bf16
+# (every table measured before PR 30), fewer rows a chunk beyond.
+_ONEHOT_BYTES_A_CHUNK = 4 << 30
+
+
+def hist_pass_shape(rows: int, features: int, bins_padded: int,
+                    channel_bytes: int, max_chunk: int) -> Tuple[int, str]:
+    """(rows a chunk, the rule that chose them) of the one-hot matmul pass
+    over ``rows`` rows a device: a static function of the shapes.
+    ``max_chunk`` (``tpu_hist_chunk``) is an upper bound on the chunk."""
+    chunk = min(int(max_chunk), -(-int(rows) // 256) * 256)
+    by_width = max(256, _ONEHOT_BYTES_A_CHUNK
+                   // (int(features) * int(bins_padded) * int(channel_bytes))
+                   // 256 * 256)
+    if by_width >= chunk:
+        return chunk, "max_chunk"
+    return by_width, "width"
+
+
 def weight_channels(grad, hess, included, hilo):
     """[N, ch] weight channels for the one-hot matmul (dtype by mode)."""
     if hilo is True:

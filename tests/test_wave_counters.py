@@ -65,7 +65,7 @@ def _replay(tree, Xb, real, L, frac):
     N = Xb.shape[0]
     if n_nodes == 0:
         return dict(waves=1, rows_active=[N], rows_split=[0],
-                    compacted=[N < int(N * frac)])
+                    compacted=[N < int(N * frac)], scan_pending=[1])
     left, right = np.asarray(tree.left_child), np.asarray(tree.right_child)
     feat, thr = np.asarray(tree.split_feature), np.asarray(tree.threshold_bin)
     wave_of = np.zeros(n_nodes, int)
@@ -83,18 +83,24 @@ def _replay(tree, Xb, real, L, frac):
         smaller_rows[i] = len(kids[0] if n_real[0] <= n_real[1] else kids[1])
         rows_at[("split", i)] = len(rows)
     n_waves = int(wave_of.max()) + 1
-    active, split = [N], []
+    # the split scan of a wave holds the wave's pending leaves (the smaller
+    # children of the nodes the wave before split) and their siblings by
+    # subtraction; the root has no sibling
+    active, split, scanned = [N], [], [1]
     for w in range(n_waves):
         nodes = np.nonzero(wave_of == w)[0]
         split.append(sum(rows_at[("split", int(i))] for i in nodes))
         active.append(sum(smaller_rows[int(i)] for i in nodes))
+        scanned.append(2 * len(nodes))
     if int(tree.num_leaves) < L:       # stopped on gain: a wave with no split
         n_waves += 1
         split.append(0)
     else:
         active.pop()                   # budget spent: the loop ends there
+        scanned.pop()
     return dict(waves=n_waves, rows_active=active, rows_split=split,
-                compacted=[a < int(N * frac) for a in active])
+                compacted=[a < int(N * frac) for a in active],
+                scan_pending=scanned)
 
 
 @pytest.mark.parametrize("frac,min_leaf", [(0.5, 5), (1.0, 5), (1e-9, 5),
@@ -120,6 +126,7 @@ def test_counters_equal_numpy_replay(clean_registry, frac, min_leaf):
         assert st.rows_active[:w].tolist() == want["rows_active"]
         assert st.rows_split[:w].tolist() == want["rows_split"]
         assert st.compacted[:w].tolist() == want["compacted"]
+        assert st.scan_pending[:w].tolist() == want["scan_pending"]
         assert int(rec.num_leaves[0]) == int(tree.num_leaves)
         seen["compact"] += sum(want["compacted"])
         seen["stream"] += w - sum(want["compacted"])
@@ -161,13 +168,16 @@ def test_wave_totals_takes_the_pace_setting_shard():
                    rows_active=np.array([[100, 10, 0], [100, 30, 0]]),
                    compacted=np.array([[False, True, False],
                                        [False, False, False]]),
-                   rows_split=np.array([[100, 40, 0], [100, 70, 0]]))
-    t = wave_totals(st, rows_per_device=100, chunk_rows=20)
+                   rows_split=np.array([[100, 40, 0], [100, 70, 0]]),
+                   scan_pending=np.array([[1, 2, 0], [1, 2, 0]]))
+    t = wave_totals(st, rows_per_device=100, chunk_rows=20, hist_slots=4)
     assert t == {"waves": 2, "stream_passes": 2, "compact_passes": 0,
-                 "hist_rows_touched": 200, "hist_rows_active": 130,
-                 "rows_routed": 200, "rows_split": 170}
+                 "hist_rows_touched": 200, "hist_chunks": 10,
+                 "hist_rows_active": 130,
+                 "rows_routed": 200, "rows_split": 170,
+                 "scan_slots": 16, "scan_slots_pending": 3}
     one = jax.tree.map(lambda a: a[:1], st)
-    t = wave_totals(one, rows_per_device=100, chunk_rows=20)
+    t = wave_totals(one, rows_per_device=100, chunk_rows=20, hist_slots=4)
     assert (t["stream_passes"], t["compact_passes"]) == (1, 1)
     assert t["hist_rows_touched"] == 100 + 20          # ceil(10/20) chunks
 
