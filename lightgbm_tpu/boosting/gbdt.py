@@ -33,7 +33,8 @@ from ..config import Config
 from ..dataset import ConstructedDataset, Metadata, MetadataDuckTyping
 from ..grower import (GrowerSpec, TreeArrays, WaveStats, grow_tree,
                       wave_totals)
-from ..ops.histogram import hist_pass_shape, num_channels, table_lookup
+from ..ops.histogram import (hist_pass_shape, num_channels,
+                             resolve_compact_frac, table_lookup)
 from ..parallel.comm import make_parallel_context
 from ..metrics import Metric, create_metrics
 from ..robustness import allowed_host_sync
@@ -615,7 +616,8 @@ class GBDT:
         # (the reference's Dense4bitsBin analog, dense_nbits_bin.hpp:37, and
         # its own GPU bench config max_bin=63). The Pallas kernel's in-kernel
         # unpack handles plain byte layouts only — keep u8/u16 there.
-        from ..ops.histogram import code_mode_for, default_code_mode
+        from ..ops.histogram import (code_mode_for, default_code_mode,
+                                     packed_row_bytes)
         max_code = (bundle_plan.max_bundle_bins if bundle_plan is not None
                     else train_set.max_num_bin)
         _xb_dtype = Xb.dtype if Xb is not None else train_set.code_dtype
@@ -624,6 +626,16 @@ class GBDT:
         else:
             code_mode = code_mode_for(int(max_code), _xb_dtype)
 
+        # stream or compact a wave's histogram pass: tpu_compact_frac, 0 =
+        # auto = the break-even of the two arms' costs at THIS shape (build
+        # width x bins, the packed row's bytes, the weight mode, rows a
+        # device), a Python float resolved once, here
+        _weight_mode = "f32" if config.tpu_hist_f64 else config.tpu_hist_hilo
+        compact_frac = resolve_compact_frac(
+            config.tpu_compact_frac, hist_kernel,
+            rows=Npad // Drow, features=_hist_cols, bins_padded=_kernel_bins,
+            row_bytes=packed_row_bytes(_hist_cols, code_mode, _weight_mode),
+            num_slots=slots, hilo=_weight_mode)
         wave = config.tpu_wave_size or slots
         self.spec = GrowerSpec(
             num_leaves=num_leaves,
@@ -645,7 +657,7 @@ class GBDT:
             row_compact=(config.tpu_row_compact
                          and self.residency != "stream"),
             incremental_partition=config.tpu_incremental_partition,
-            compact_frac=config.tpu_compact_frac,
+            compact_frac=compact_frac,
             hist_kernel=hist_kernel,
             hist_hilo=config.tpu_hist_hilo,
             hist_f64=config.tpu_hist_f64,
@@ -866,17 +878,20 @@ class GBDT:
         # and writes once
         self._hist_acc_bytes = (
             _hist_cols * _kernel_bins * self.spec.hist_slots
-            * num_channels("f32" if self.spec.hist_f64
-                           else self.spec.hist_hilo) * 4)
+            * num_channels(_weight_mode) * 4)
         reg.gauge("hist.chunk_rows").set(self.spec.chunk_rows)
         reg.gauge("hist.onehot_bytes").set(
             self.spec.chunk_rows * _hist_cols * _kernel_bins
             * (4 if self.spec.hist_f64 else 2))
         reg.gauge("hist.acc_bytes").set(self._hist_acc_bytes)
+        reg.gauge("hist.compact_frac").set(self.spec.compact_frac)
         obs.event("hist_pass_shape", rule=_shape_rule,
                   chunk_rows=int(self.spec.chunk_rows),
                   rows=int(per_target), features=int(_hist_cols),
-                  bins=int(_kernel_bins))
+                  bins=int(_kernel_bins),
+                  compact_frac=float(self.spec.compact_frac),
+                  compact_rule=("explicit" if config.tpu_compact_frac
+                                else "auto"))
         if self._stream_store is not None:
             reg.gauge("stream.n_shards").set(self._stream_store.n_shards)
             reg.gauge("stream.shard_bytes").set(

@@ -339,8 +339,18 @@ class Config:
     # prefix-compacted index gather (the analog of the reference's
     # smaller-leaf histogramming, serial_tree_learner.cpp:354-362)
     tpu_row_compact: bool = True
-    tpu_compact_frac: float = 0.25            # compact passes below this
-                                              # active-row fraction
+    # a wave's histograms come from a COMPACTED pass (one sort, then only the
+    # pending rows, each fetched by a row gather) when fewer than this share
+    # of a device's rows are pending, from a STREAMED pass over all rows
+    # otherwise. 0 = auto: the break-even of the two arms' costs at this
+    # table's shape (ops/histogram.compact_break_even: width x bins, the
+    # packed row's bytes, the weight mode, rows a device). On the v5e that
+    # reads 0.70 at 67 columns x 256 bins and 0.95 at 2,000, which is
+    # "stream a full root, compact the rest" (every later wave has under
+    # half of the rows pending), and 0.26 at 28 columns, 0.35 at 10, where
+    # early waves still stream (PERF.md, PR 31). An explicit value in
+    # (0, 1] forces it: 1.0 compacts every wave but a full root.
+    tpu_compact_frac: float = 0.0             # 0 = auto
     # how a compacted pass gets its slot-grouped row index (grower.py, phase
     # wave.partition). false (default since PR 28): one stable sort of the
     # rows by pending slot, inside the compacted arm of the wave's cond;
@@ -622,13 +632,15 @@ class Config:
         if self.tpu_hbm_budget_bytes < 0:
             Log.fatal("tpu_hbm_budget_bytes must be >= 0 (0 = device "
                       "capacity), got %d", self.tpu_hbm_budget_bytes)
-        if not 0.0 < self.tpu_compact_frac <= 1.0:
-            # <=0 silently disables compaction; >1 forces the argsort+gather
-            # path on every pass (n_active < frac*N is always true)
-            Log.fatal("tpu_compact_frac must be in (0, 1], got %g — values "
-                      "<= 0 disable row compaction entirely and values > 1 "
-                      "force the compacted argsort+gather path on every "
-                      "histogram pass", self.tpu_compact_frac)
+        if not 0.0 <= self.tpu_compact_frac <= 1.0:
+            # 0 is auto; <0 would silently disable compaction and >1 force
+            # the sort+gather path on every pass, the root's included
+            # (n_active < frac*N always true)
+            Log.fatal("tpu_compact_frac must be 0 (auto) or in (0, 1], got "
+                      "%g — negative values disable row compaction entirely "
+                      "(that is tpu_row_compact=false) and values > 1 force "
+                      "the compacted sort+gather path on every histogram "
+                      "pass", self.tpu_compact_frac)
         if self.tree_batch < 1:
             Log.fatal("tree_batch must be >= 1, got %d", self.tree_batch)
         if self.boosting_type in ("rf", "random_forest"):

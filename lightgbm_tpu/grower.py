@@ -44,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .analysis.contracts.registry import trace_entry
-from .ops.histogram import build_histograms, root_sums, table_lookup
+from .ops.histogram import (PALLAS_COMPACT_FRAC_CAP, build_histograms,
+                            root_sums, sort_is_one_word, table_lookup)
 from .ops.split_finder import SplitCandidates, leaf_output
 from .robustness import allowed_host_sync
 
@@ -285,15 +286,16 @@ class GrowerSpec:
                                   # tests/test_incremental_partition.py,
                                   # 1.8x slower end to end at 14.7M rows
                                   # (PERF.md, PR 28)
-    compact_frac: float = 0.25    # compact when n_active < frac*N. The
-                                  # round-5 trace put the hist matmul at 92%
-                                  # MXU peak, so the remaining lever is the
-                                  # FLOP volume itself: a full streaming
-                                  # pass pays all N rows even at 30-50%
-                                  # active; compacting there trades a
-                                  # gather+argsort for a ~2x smaller matmul.
-                                  # The pallas kernel's skip-grid buffers
-                                  # are sized to N/4 — keep <= 0.25 there
+    compact_frac: float = 1.0     # a wave compacts its histogram pass when
+                                  # n_active < int(N * compact_frac), else
+                                  # it streams all N rows. RESOLVED where
+                                  # the spec is built (tpu_compact_frac, 0 =
+                                  # auto = ops/histogram.compact_break_even
+                                  # of the shapes, capped at 0.25 for the
+                                  # Pallas kernels), so the predicate is a
+                                  # static int compare. 1.0 = stream a full
+                                  # root only: every later wave histograms
+                                  # smaller children, under half of the rows
     hist_bins: int = 0            # bin axis of the histogram BUILD (EFB bundle
                                   # space); 0 = num_bins_padded (unbundled)
     efb_unpack: bool = False      # LEGACY EFB scan arm (tpu_efb_unpack):
@@ -671,7 +673,7 @@ def _rows_by_slot(slot_row: jnp.ndarray, num_slots: int) -> jnp.ndarray:
     n = slot_row.shape[0]
     key = jnp.where(slot_row >= 0, slot_row, num_slots)
     rows = jax.lax.iota(jnp.int32, n)
-    one_word = n <= 1 << 24 and num_slots < 1 << 7
+    one_word = sort_is_one_word(n, num_slots)
     operands = ((key << 24) | rows,) if one_word else (key, rows)
     out = jax.lax.sort(operands, num_keys=1, is_stable=not one_word)
     return out[0] & ((1 << 24) - 1) if one_word else out[1]
@@ -860,14 +862,21 @@ def grow_tree(
                 code_mode=spec.code_mode, compensated=spec.hist_f64)
 
         if spec.row_compact:
-            # Adaptive: a compacted pass pays one random row gather per
-            # active row (~2.5x the per-row cost of the streaming masked
-            # pass), so it only wins when few rows are active. The
-            # breakeven is set at ~25% active (tpu_compact_frac); early
-            # waves (incl. the root) therefore run the full masked pass,
-            # late waves the compacted one — the TPU analog of the reference
-            # histogramming only the smaller leaf's rows
-            # (serial_tree_learner.cpp:354-362).
+            # Adaptive, the TPU analog of the reference histogramming only
+            # the smaller leaf's rows (serial_tree_learner.cpp:354-362): a
+            # streamed pass pays the chunk matmul for every row of the
+            # device, pending or not; a compacted pass pays it for the
+            # pending rows only, plus one packed-row gather each and one
+            # sort of the rows. spec.compact_frac is the pending share
+            # where the two cost the same at this table's shape
+            # (ops/histogram.compact_break_even; on the v5e 0.70 at 67
+            # columns x 256 bins, 0.95 at 2,000, 0.26 at 28 columns and
+            # 0.35 at 10: PERF.md, PR 31). The root has every row pending,
+            # out-of-bag rows included, and streams; a later wave
+            # histograms smaller children, under half of the rows, and
+            # compacts unless the table is narrow. Under tree_learner=data
+            # N is a shard's rows and each shard decides for itself, inside
+            # its own cond; the reduction is outside it.
             if use_inc:
                 # slot bookkeeping straight from the carried partition:
                 # counts/starts are [S]-sized gathers from the per-leaf
@@ -905,11 +914,11 @@ def grow_tree(
 
             # the threshold is a static Python int, so the predicate cannot
             # overflow int32 at any N. Pallas/mixed kernels keep the N/4
-            # cap regardless of compact_frac: their skip-grid buffers are
+            # cap whatever the spec says: their skip-grid buffers are
             # provably sized by max_rows=(N+3)//4 (n_active < N//4).
             frac = spec.compact_frac
             if spec.hist_kernel in ("pallas", "mixed"):
-                frac = min(frac, 0.25)
+                frac = min(frac, PALLAS_COMPACT_FRAC_CAP)
             compacted = n_active < int(N * frac)
 
             def compact_arm():

@@ -74,6 +74,83 @@ def hist_pass_shape(rows: int, features: int, bins_padded: int,
     return by_width, "width"
 
 
+# ---- stream or compact: which arm builds a wave's histograms ---------------
+# A wave with n of a device's N rows pending can STREAM the pass over all N
+# rows, or COMPACT it: sort the rows by pending slot once (N keys), then run
+# ceil(n / chunk) chunks whose rows come by a row gather from the packed
+# array. The compacted arm is the cheaper one when
+#     n x (matmul + gather) + N x sort  <  N x (matmul + stream_fixed)
+# with every term in ns a row as read ON THE v5e (PERF.md, PR 31: whole
+# trees at four shapes with the threshold swept, each arm's pass alone at
+# sixteen widths, one gather alone at thirty-five row widths):
+#   matmul        _MATMUL_NS_A_CELL x F x B, the chunk matmul of either arm
+#                 with bf16 hi/lo weights (1.36 ps a cell in the trees of
+#                 400,000 x 2,000, 1.40 in passes alone from 6 to 512
+#                 columns), times _MATMUL_SCALE in the other weight modes
+#                 (f32 columns at Precision.HIGHEST: 5.1x; three plain bf16
+#                 columns a slot: 3.8x, not 0.6x)
+#   stream_fixed  what a streamed row pays whatever the width: its slot
+#                 lookup, slices and weight channels
+#   gather        one packed row, BY ITS BYTES and not smoothly: rows of 28
+#                 to 59 bytes cost three times what rows of 60 to 128 do
+#   sort          the one-word sort and the per-slot counts; (slot, row)
+#                 pairs beyond 2^24 rows a device or 127 slots
+# So the threshold follows the width (a wasted streamed row is dearer the
+# wider the table) and the packed row. Every wave but the root histograms
+# smaller children only, under half of the rows: a value above 0.5 means
+# "stream a full root, compact the rest".
+_MATMUL_NS_A_CELL = 1.38e-3
+_MATMUL_SCALE = {True: 1.0, False: 3.8, "f32": 5.1}    # by weight mode (hilo)
+_STREAM_FIXED_NS = 1.8
+# (row bytes up to, ns a gathered row): one gather alone at each width, times
+# the 0.9 that whole trees read of it at 20 and at 38 bytes
+_GATHER_NS_BY_ROW_BYTES = (
+    (8, 4.0), (16, 5.0), (24, 9.0), (32, 24.4), (40, 31.0), (48, 36.0),
+    (59, 38.3), (128, 11.5), (256, 13.8))
+_GATHER_NS_A_BYTE_BEYOND = 0.0138              # 38 ns at 2,010 bytes
+_SORT_NS_A_ROW = {True: 1.0, False: 3.1}       # one word | (slot, row) pairs
+_MIN_COMPACT_FRAC = 1.0 / 64
+# the Pallas / mixed kernels size their skip-grid buffers for N/4 rows
+PALLAS_COMPACT_FRAC_CAP = 0.25
+
+
+def sort_is_one_word(rows: int, num_slots: int) -> bool:
+    """Slot and row number fit one int32 key (``slot << 24 | row``)."""
+    return rows <= 1 << 24 and num_slots < 1 << 7
+
+
+def _gather_ns(row_bytes: int) -> float:
+    for upto, ns in _GATHER_NS_BY_ROW_BYTES:
+        if row_bytes <= upto:
+            return ns
+    return ns + _GATHER_NS_A_BYTE_BEYOND * (row_bytes - upto)
+
+
+def compact_break_even(rows: int, features: int, bins_padded: int,
+                       row_bytes: int, num_slots: int, hilo=True) -> float:
+    """The share of a device's rows below which a wave's compacted pass is
+    cheaper than a streamed one: a static function of the shapes, in
+    (0, 1]. ``features`` x ``bins_padded`` is the histogram BUILD's width
+    (bundle space under EFB), ``row_bytes`` the packed row the gather
+    fetches, ``rows`` the rows a device, ``hilo`` the weight mode."""
+    matmul = (_MATMUL_NS_A_CELL * int(features) * int(bins_padded)
+              * _MATMUL_SCALE[hilo])
+    sort = _SORT_NS_A_ROW[sort_is_one_word(int(rows), int(num_slots))]
+    frac = ((matmul + _STREAM_FIXED_NS - sort)
+            / (matmul + _gather_ns(int(row_bytes))))
+    return float(min(1.0, max(_MIN_COMPACT_FRAC, frac)))
+
+
+def resolve_compact_frac(requested: float, hist_kernel: str, **shape) -> float:
+    """``tpu_compact_frac`` as the grower takes it: an explicit value as it
+    is, 0 (auto) as ``compact_break_even`` of the shapes; either way under
+    the Pallas kernels' cap where they build the compacted passes."""
+    frac = float(requested) or compact_break_even(**shape)
+    if hist_kernel in ("pallas", "mixed"):
+        frac = min(frac, PALLAS_COMPACT_FRAC_CAP)
+    return frac
+
+
 def weight_channels(grad, hess, included, hilo):
     """[N, ch] weight channels for the one-hot matmul (dtype by mode)."""
     if hilo is True:
@@ -161,6 +238,13 @@ def code_mode_for(max_code: int, dtype) -> str:
 def code_bytes_total(F: int, code_mode: str) -> int:
     return {"u8": F, "u16": 2 * F, "u4": (F + 1) // 2,
             "u6": ((F + 3) // 4) * 3}[code_mode]
+
+
+def packed_row_bytes(F: int, code_mode: str, hilo) -> int:
+    """Bytes of one row of ``pack_rows``: the code bytes, then the weight
+    channels (f32 in the "f32" mode, bf16 otherwise)."""
+    return (code_bytes_total(F, code_mode)
+            + num_channels(hilo) * (4 if hilo == "f32" else 2))
 
 
 def _pack_codes(X: jnp.ndarray, code_mode: str) -> jnp.ndarray:

@@ -263,15 +263,19 @@ def test_tree_batch_checkpoint_resume_bit_identical(tmp_path):
     np.testing.assert_array_equal(full.predict(X), resumed.predict(X))
 
 
-def test_config_validates_tree_batch_and_compact_frac():
+@pytest.mark.parametrize("params,ok", [
+    (dict(tree_batch=0), False), (dict(tree_batch=8), True),
+    (dict(tpu_compact_frac=-0.5), False), (dict(tpu_compact_frac=1.5), False),
+    (dict(tpu_compact_frac=0.0), True),            # 0 = auto, the default
+    (dict(tpu_compact_frac=0.25), True), (dict(tpu_compact_frac=1.0), True)],
+    ids=["batch-0", "batch-8", "frac-negative", "frac-over-1", "frac-auto",
+         "frac-quarter", "frac-one"])
+def test_config_validates_tree_batch_and_compact_frac(params, ok):
     from lightgbm_tpu.config import Config
-    with pytest.raises(LightGBMError):
-        Config.from_params(dict(tree_batch=0))
-    with pytest.raises(LightGBMError):
-        Config.from_params(dict(tpu_compact_frac=0.0))
-    with pytest.raises(LightGBMError):
-        Config.from_params(dict(tpu_compact_frac=-0.5))
-    with pytest.raises(LightGBMError):
-        Config.from_params(dict(tpu_compact_frac=1.5))
-    assert Config.from_params(dict(tpu_compact_frac=1.0)).tpu_compact_frac == 1.0
-    assert Config.from_params(dict(tree_batch=8)).tree_batch == 8
+    if not ok:
+        with pytest.raises(LightGBMError):
+            Config.from_params(params)
+        return
+    cfg = Config.from_params(params)
+    assert all(getattr(cfg, k) == v for k, v in params.items())
+    assert Config.from_params({}).tpu_compact_frac == 0.0

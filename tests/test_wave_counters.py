@@ -40,8 +40,14 @@ BASE = dict(objective="binary", num_leaves=15, max_bin=31, learning_rate=0.2,
             tpu_hist_chunk=256)
 
 
-def _booster(params, n=3000, rounds=0):
-    X, y = _data(n)
+# the widths of the default rule's two sides: 8 columns x 32 bins puts the
+# break-even far under a half, 64 x 256 (74-byte packed rows) above it
+NARROW = dict(f=8, max_bin=31)
+WIDE = dict(f=64, max_bin=255)
+
+
+def _booster(params, n=3000, rounds=0, f=8):
+    X, y = _data(n, f)
     bst = lgb.Booster(params=dict(params),
                       train_set=lgb.Dataset(X, label=y, params=params))
     for _ in range(rounds):
@@ -103,14 +109,25 @@ def _replay(tree, Xb, real, L, frac):
                 scan_pending=scanned)
 
 
-@pytest.mark.parametrize("frac,min_leaf", [(0.5, 5), (1.0, 5), (1e-9, 5),
-                                           (0.25, 400)],
-                         ids=["mixed", "all-compact", "all-stream",
-                              "stops-on-gain"])
-def test_counters_equal_numpy_replay(clean_registry, frac, min_leaf):
-    params = dict(BASE, tpu_compact_frac=frac, min_data_in_leaf=min_leaf)
-    bst = _booster(params, rounds=4)
+@pytest.mark.parametrize("frac,min_leaf,width", [
+    (0.5, 5, NARROW), (1.0, 5, NARROW), (1e-9, 5, NARROW), (0.25, 400, NARROW),
+    (0.0, 5, NARROW), (0.0, 5, WIDE)],
+    ids=["mixed", "all-compact", "all-stream", "stops-on-gain",
+         "auto-narrow", "auto-wide"])
+def test_counters_equal_numpy_replay(clean_registry, frac, min_leaf, width):
+    """``frac`` 0 is the default: the rule of ops/histogram.py sets the
+    threshold from the table's width. A wide table streams its root and
+    nothing else; a narrow one keeps streaming its early waves."""
+    params = dict(BASE, tpu_compact_frac=frac, min_data_in_leaf=min_leaf,
+                  max_bin=width["max_bin"])
+    auto = frac == 0.0
+    if auto:
+        params.pop("tpu_compact_frac")               # the default, unsaid
+    bst = _booster(params, rounds=4, f=width["f"])
     g = bst._gbdt
+    if auto:
+        frac = g.spec.compact_frac
+        assert (frac > 0.5) == (width is WIDE) and 0.0 < frac < 1.0
     L, N = g.spec.num_leaves, int(g.num_data_padded)
     assert g.spec.hist_slots >= L - 1 and N > 3000   # no cap binds; padding
     Xb = np.asarray(g.Xb)
@@ -133,8 +150,13 @@ def test_counters_equal_numpy_replay(clean_registry, frac, min_leaf):
         seen["nosplit"] += want["rows_split"][-1] == 0
     # the arms this case is there to force did run
     assert seen["stream"] >= 4                       # every root pass streams
-    assert (seen["compact"] > 0) == (frac > 1e-9)
     assert (seen["nosplit"] > 0) == (min_leaf == 400)
+    if auto:
+        # wide: the root's pass and no other; narrow: early waves as well
+        assert (seen["stream"] == 4) == (width is WIDE)
+        assert seen["compact"] > 0 or width is NARROW
+    else:
+        assert (seen["compact"] > 0) == (frac > 1e-9)
 
     bst._ensure_finalized()                          # trees come to the host
     reg = obs.get_registry()
@@ -180,6 +202,50 @@ def test_wave_totals_takes_the_pace_setting_shard():
     t = wave_totals(one, rows_per_device=100, chunk_rows=20, hist_slots=4)
     assert (t["stream_passes"], t["compact_passes"]) == (1, 1)
     assert t["hist_rows_touched"] == 100 + 20          # ceil(10/20) chunks
+
+
+def test_bagged_root_counts_every_row_and_streams(clean_registry):
+    """``n_active`` counts the rows of the pending leaves, out-of-bag rows
+    among them (they route, with zero weights): a bagged root holds all of
+    a device's rows and streams under any threshold up to 1."""
+    params = dict(BASE, bagging_fraction=0.5, bagging_freq=1,
+                  tpu_compact_frac=1.0)
+    g = _booster(params, rounds=3)._gbdt
+    N = int(g.num_data_padded)
+    for rec in jax.device_get(g._grow_records):
+        st = jax.tree.map(lambda a: a[0, 0], rec.stats)
+        assert int(st.rows_active[0]) == N and not bool(st.compacted[0])
+        assert st.compacted[1:int(st.waves)].all()
+
+
+@pytest.mark.parametrize("learner,batch", [("serial", 1), ("serial", 4),
+                                           ("data", 1), ("data", 4)])
+def test_default_threshold_grows_the_explicit_values_trees(clean_registry,
+                                                           learner, batch):
+    """The default resolves to a number once, where the spec is built; the
+    same number given explicitly is the same program and the same trees.
+    On the wide table that is one streamed pass a tree (serial)."""
+    params = dict(BASE, tree_learner=learner, tree_batch=batch,
+                  max_bin=WIDE["max_bin"])
+
+    def grow(extra):
+        bst = _booster(dict(params, **extra), f=WIDE["f"])
+        for _ in range(8 // batch):
+            bst._gbdt.train_batch(batch)
+        bst._ensure_finalized()
+        return bst
+
+    auto = grow({})
+    frac = auto._gbdt.spec.compact_frac
+    assert 0.5 < frac < 1.0
+    streamed = obs.get_registry().summary("grow.stream_passes").values()
+    # each shard decides on ITS rows, and a wave counts as streamed when any
+    # shard streams it: a 375-row shard can hold the larger part of a leaf
+    # whose smaller child is the smaller one over all rows
+    assert streamed == [1.0] * 8 if learner == "serial" else (
+        len(streamed) == 8 and min(streamed) >= 1.0)
+    assert auto.model_to_string() == grow(
+        dict(tpu_compact_frac=frac)).model_to_string()
 
 
 # ---------------------------- (a) the counters' consumers change no tree
