@@ -228,7 +228,8 @@ def pallas_leg(X, y, Xt, rows=PALLAS_ROWS, iters=PALLAS_ITERS,
     import jax.numpy as jnp
     import lightgbm_tpu as lgb
     from lightgbm_tpu.ops import pallas_histogram as ph
-    from lightgbm_tpu.ops.histogram import build_histograms, compact_rows
+    from lightgbm_tpu.grower import _slot_grouped_rows
+    from lightgbm_tpu.ops.histogram import build_histograms, pack_rows
 
     check(ph._INTERPRET is False,
           "pallas kernel is in interpret mode — Mosaic would not compile it")
@@ -237,7 +238,10 @@ def pallas_leg(X, y, Xt, rows=PALLAS_ROWS, iters=PALLAS_ITERS,
     # sums (this is what pins the bf16 hi/lo weight split on the chip: the
     # TPU compiler may elide an f32->bf16->f32 round trip that the CPU
     # backend keeps, see ops/histogram._split_hi_lo), the two must agree,
-    # and the compiled Pallas program must hold a Mosaic custom call.
+    # each kernel's compacted pass must agree with the STREAMED xla pass
+    # over the same pending leaves (the path with no row index, no packed
+    # rows and no gather), and the compiled Pallas program must hold a
+    # Mosaic custom call.
     rng = np.random.RandomState(0)
     n, f, bins, slots = 65536, X.shape[1], 256, 25
     codes_np = rng.randint(0, 255, size=(n, f)).astype(np.uint8)
@@ -256,16 +260,21 @@ def pallas_leg(X, y, Xt, rows=PALLAS_ROWS, iters=PALLAS_ITERS,
         jnp.arange(slots))
     args = (jnp.asarray(codes_np), jnp.asarray(grad_np), jnp.asarray(hess_np),
             jnp.ones(n, jnp.float32), jnp.asarray(leaf_np), slot_of_leaf)
-    row_idx, n_active = compact_rows(args[4], slot_of_leaf)
+    # a compacted pass as the grower hands it over: the rows grouped by
+    # pending slot by its one sort, the rows a slot, the packed rows
+    row_idx, counts = _slot_grouped_rows(slot_of_leaf[args[4]], slots)
+    compacted = dict(row_idx=row_idx, n_active=jnp.sum(counts),
+                     slot_counts=counts)
+    args += (pack_rows(*args[:4], exact=False)[0],)
     static = dict(num_slots=slots, num_bins_padded=bins, chunk_rows=512)
     worst = 0.0
-    for name, kw in (("full", {}),
-                     ("compacted", dict(row_idx=row_idx, n_active=n_active))):
+    streamed = None
+    for name, kw in (("full", {}), ("compacted", compacted)):
         outs = {}
         for kernel, build in (("xla", build_histograms),
                               ("pallas", ph.build_histograms_pallas)):
             compiled = jax.jit(lambda *a, build=build, kw=kw: build(
-                *a, **static, **kw)).lower(*args).compile()
+                *a[:-1], packed=a[-1], **static, **kw)).lower(*args).compile()
             if kernel == "pallas":
                 check("tpu_custom_call" in compiled.as_text(),
                       f"no Mosaic custom call in the compiled {name} pass")
@@ -277,8 +286,14 @@ def pallas_leg(X, y, Xt, rows=PALLAS_ROWS, iters=PALLAS_ITERS,
             np.testing.assert_array_equal(outs[kernel][..., 2], ref64[..., 2])
         np.testing.assert_allclose(outs["pallas"], outs["xla"],
                                    rtol=1e-5, atol=1e-4)
+        if streamed is None:
+            streamed = outs["xla"]
+        for kernel in outs:
+            np.testing.assert_allclose(outs[kernel], streamed,
+                                       rtol=1e-5, atol=1e-4)
         say(f"pallas: {name} pass at F={f} B={bins} S={slots} chunk=512: "
-            f"Mosaic-compiled kernel matches xla, both match f64 sums")
+            f"Mosaic-compiled kernel matches xla, both match f64 sums and "
+            f"the streamed xla pass")
 
     # end to end: the mixed dispatch grows the same trees as xla
     preds = {}

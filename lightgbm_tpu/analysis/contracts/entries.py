@@ -11,7 +11,6 @@ dead code.
 Shape classes:
 
 - ``serial``        single-device resident growth / fused train step
-- ``serial_carried`` tpu_incremental_partition=true parity arm (violates)
 - ``u4_packed``     u4 packed-row code layout (tpu_code_mode=u4)
 - ``data8``         data-parallel over the 8 hermetic CPU devices
 - ``stream_shard``/``stream_wave``  StreamedGrower's two device legs
@@ -45,8 +44,7 @@ def _wave_spec(**over):
               chunk_rows=256, hist_slots=4, wave_size=4, max_depth=0,
               lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=5.0,
               min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0,
-              row_compact=True, incremental_partition=False,
-              compact_frac=1.0)
+              row_compact=True, compact_frac=1.0)
     kw.update(over)
     return GrowerSpec(**kw)
 
@@ -76,14 +74,6 @@ def _wave_program(shape_class: str, spec, comm=None, comm_bytes=None,
 @program_builder("grower.wave_body", "serial")
 def _wave_serial():
     return _wave_program("serial", _wave_spec())
-
-
-@program_builder("grower.wave_body", "serial_carried")
-def _wave_serial_carried():
-    # the parity arm: the row permutation carried across waves and
-    # re-partitioned by gather + cumsums + scatter in EVERY wave
-    return _wave_program("serial_carried",
-                         _wave_spec(incremental_partition=True))
 
 
 @program_builder("grower.wave_body", "u4_packed")
@@ -290,14 +280,15 @@ contract(
             "row-sized scatter or cumsum in the wave loop",
     "grower.wave_body",
     checks=[C.RowPassesInLoops(max_sorts=1)],
-    targets=[Target("serial"), Target("u4_packed"),
-             Target("serial_carried", "violates")],
+    targets=[Target("serial"), Target("u4_packed")],
     doc="A compacted pass builds its slot-grouped row index with one sort "
-        "inside its arm of the cond; a streamed wave builds nothing. The "
-        "carried permutation (tpu_incremental_partition=true) re-partitions "
-        "all rows every wave through a row-sized scatter, which on the TPU "
-        "hides a sort of its own and cost half the tree (PERF.md, PR 28): "
-        "the arm that keeps this check sensitive.")
+        "inside its arm of the cond; a streamed wave builds nothing. A "
+        "permutation carried across waves and re-partitioned through a "
+        "row-sized scatter hides a sort of its own on the TPU and cost half "
+        "the tree (PERF.md, PR 28; deleted in PR 32). No shipped arm "
+        "violates this any more: the planted cell TX94 of "
+        "tests/fixtures/tpu_lint/trace_violations.py (a loop body with a "
+        "row-sized scatter) keeps the check demonstrably sensitive.")
 
 contract(
     "T002", "no gather in bundle-space routing", "routing.bundle_space",

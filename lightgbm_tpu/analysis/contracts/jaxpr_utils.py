@@ -1,9 +1,8 @@
 """Structural walks over jaxprs and optimized-HLO text.
 
 This is the ONE implementation of the recursive jaxpr walk the repo used
-to carry as per-test helpers (`_jaxpr_has_sort` in
-test_incremental_partition, `_jaxpr_has_primitive` in
-test_efb_bundlespace) — those are deleted; both the trace-lint tier and
+to carry as per-test helpers (`_jaxpr_has_sort` of the wave-loop tests,
+`_jaxpr_has_primitive` in test_efb_bundlespace) — those are deleted; both the trace-lint tier and
 the tests assert through these functions. No jax import: everything here
 is duck-typed over ``.eqns`` / ``.jaxpr`` attributes, so the module loads
 in the dependency-free AST tier too.

@@ -15,7 +15,7 @@ A target's ``expect`` field makes sensitivity first-class:
 
 - ``"clean"``   — every check must pass (the shipped configuration);
 - ``"violates"``— at least one check must FAIL (an arm kept as the A/B
-  pin, e.g. ``tpu_incremental_partition=true``'s per-wave scatter).
+  pin, e.g. ``tpu_efb_unpack=true``'s per-row decode gather).
   If a violates-target starts passing, the contract has silently lost its
   teeth and lint reports *that* — tests and lint assert the same predicate
   through this one implementation.

@@ -630,12 +630,12 @@ class GBDT:
         # auto = the break-even of the two arms' costs at THIS shape (build
         # width x bins, the packed row's bytes, the weight mode, rows a
         # device), a Python float resolved once, here
-        _weight_mode = "f32" if config.tpu_hist_f64 else config.tpu_hist_hilo
+        _exact = bool(config.tpu_hist_f64)
         compact_frac = resolve_compact_frac(
             config.tpu_compact_frac, hist_kernel,
             rows=Npad // Drow, features=_hist_cols, bins_padded=_kernel_bins,
-            row_bytes=packed_row_bytes(_hist_cols, code_mode, _weight_mode),
-            num_slots=slots, hilo=_weight_mode)
+            row_bytes=packed_row_bytes(_hist_cols, code_mode, _exact),
+            num_slots=slots, exact=_exact)
         wave = config.tpu_wave_size or slots
         self.spec = GrowerSpec(
             num_leaves=num_leaves,
@@ -656,11 +656,9 @@ class GBDT:
             # against device residency with tpu_row_compact=false.
             row_compact=(config.tpu_row_compact
                          and self.residency != "stream"),
-            incremental_partition=config.tpu_incremental_partition,
             compact_frac=compact_frac,
             hist_kernel=hist_kernel,
-            hist_hilo=config.tpu_hist_hilo,
-            hist_f64=config.tpu_hist_f64,
+            hist_f64=_exact,
             hist_bins=self._hist_bins,
             efb_unpack=(self.bundle is not None and self._efb_unpack),
             code_mode=code_mode,
@@ -878,7 +876,7 @@ class GBDT:
         # and writes once
         self._hist_acc_bytes = (
             _hist_cols * _kernel_bins * self.spec.hist_slots
-            * num_channels(_weight_mode) * 4)
+            * num_channels(_exact) * 4)
         reg.gauge("hist.chunk_rows").set(self.spec.chunk_rows)
         reg.gauge("hist.onehot_bytes").set(
             self.spec.chunk_rows * _hist_cols * _kernel_bins
@@ -992,12 +990,7 @@ class GBDT:
         if budget is None:
             return "device"
         rows = _round_up(per_target, chunk)   # padded PER-DEVICE rows
-        if config.tpu_hist_f64:
-            channels, chb = 3, 4
-        elif config.tpu_hist_hilo:
-            channels, chb = 5, 2
-        else:
-            channels, chb = 3, 2
+        channels, chb = (3, 4) if config.tpu_hist_f64 else (5, 2)
         packed_row_bytes = 0
         if config.tpu_row_compact:
             from ..ops.histogram import code_bytes_total
@@ -1012,7 +1005,6 @@ class GBDT:
             chunk_rows=chunk, channels=channels, channel_bytes=chb,
             packed_row_bytes=packed_row_bytes,
             row_compact=config.tpu_row_compact,
-            incremental=config.tpu_incremental_partition,
             bagging=(config.bagging_freq > 0
                      and config.bagging_fraction < 1.0),
             tree_batch=max(1, config.tree_batch),
@@ -2464,7 +2456,7 @@ class GBDT:
         cells = (self.Xb.shape[1]
                  // (n_dev if self.pctx.strategy == "feature" else 1)) \
             * (spec.hist_bins or spec.num_bins_padded)
-        ch = num_channels("f32" if spec.hist_f64 else spec.hist_hilo)
+        ch = num_channels(spec.hist_f64)
         for stats in counted:
             for k in range(self.num_models):
                 t = wave_totals(jax.tree.map(lambda a, k=k: a[k], stats),

@@ -322,9 +322,8 @@ class Config:
     tpu_wave_size: int = 0
     # row-chunk length for the histogram one-hot matmul pass
     tpu_hist_chunk: int = 32768
-    # accumulate g/h as bf16 hi+lo pairs (~f32 precision) vs plain bf16
-    tpu_hist_hilo: bool = True
-    # High-precision histogram accumulation: full-f32 weight columns
+    # The histogram's weight mode, one of two. false (default): g/h as bf16
+    # hi+lo pairs accumulated in f32 (~f32 sums). true: full-f32 weight columns
     # contracted at Precision.HIGHEST (exact products) + Kahan-compensated
     # chunk carry — the role of the reference's double HistogramBinEntry
     # (bin.h:29-31). Measured ~30x tighter bin sums vs the bf16 hi/lo
@@ -335,9 +334,12 @@ class Config:
     tpu_hist_f64: bool = False
     # number of leaf slots whose histograms are built in one pass
     tpu_hist_slots: int = 0                   # 0 = auto
-    # row compaction: each wave histograms only rows in pending leaves via a
-    # prefix-compacted index gather (the analog of the reference's
-    # smaller-leaf histogramming, serial_tree_learner.cpp:354-362)
+    # row compaction: a wave may histogram only the rows of its pending
+    # leaves, gathered through an index that one sort of the rows by pending
+    # slot builds in that wave (the analog of the reference's smaller-leaf
+    # histogramming, serial_tree_learner.cpp:354-362); tpu_compact_frac says
+    # which waves do. false: every wave streams all rows (the streamed
+    # grower's bit-identity reference, tests/test_stream.py)
     tpu_row_compact: bool = True
     # a wave's histograms come from a COMPACTED pass (one sort, then only the
     # pending rows, each fetched by a row gather) when fewer than this share
@@ -351,16 +353,6 @@ class Config:
     # early waves still stream (PERF.md, PR 31). An explicit value in
     # (0, 1] forces it: 1.0 compacts every wave but a full root.
     tpu_compact_frac: float = 0.0             # 0 = auto
-    # how a compacted pass gets its slot-grouped row index (grower.py, phase
-    # wave.partition). false (default since PR 28): one stable sort of the
-    # rows by pending slot, inside the compacted arm of the wave's cond;
-    # streamed waves build nothing. true: the permutation carried ACROSS
-    # waves (GrowState.perm — the reference's DataPartition,
-    # data_partition.hpp:94), re-partitioned every wave by gather + cumsums
-    # + scatter: bit-identical trees, the parity oracle of
-    # tests/test_incremental_partition.py, and 3.4 s of a 6.6 s tree at
-    # 14.7M rows on the v5e (PERF.md, PR 28)
-    tpu_incremental_partition: bool = False
     # LEGACY EFB scan arm: unpack bundle-space histograms into full
     # [T, F, B, 3] feature space before split finding and route rows
     # through the per-row bundle-decode gather — the pre-redesign layout

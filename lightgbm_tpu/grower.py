@@ -4,13 +4,11 @@ TPU re-architecture of SerialTreeLearner::Train
 (reference: src/treelearner/serial_tree_learner.cpp:152-231):
 
 - The reference's per-leaf DataPartition (permuted row indices,
-  data_partition.hpp) appears TWICE: a flat `leaf_id[num_rows]` vector
-  drives routing/score updates, and (row_compact) a leaf-contiguous row
-  permutation is carried across waves (GrowState.perm + per-leaf segment
-  tables) exactly like the reference's — after a wave's splits only the
-  split leaves' segments move, via a stable cumsum counting-sort, never a
-  sort op; compacted histogram passes gather pending segments through a
-  per-chunk position remap (ops/histogram.py slot_position_base).
+  data_partition.hpp) becomes a flat `leaf_id[num_rows]` vector that drives
+  routing/score updates; nothing row-sized is partitioned across waves. A
+  wave whose pending leaves hold few enough rows (row_compact) builds the
+  rows' order by pending slot with ONE sort, there and then, and its
+  histogram pass gathers only those rows (`_rows_by_slot`).
 - The reference's one-split-per-iteration loop with histogram pool becomes a
   `lax.while_loop` over *waves*: each wave builds histograms for all pending
   leaves in ONE masked matmul pass (ops/histogram.py), finds their best splits
@@ -224,30 +222,11 @@ class GrowState(NamedTuple):
     parent_cache: jnp.ndarray     # i32 [L+1] cache row holding the parent hist
     num_leaves_cur: jnp.ndarray   # i32
     done: jnp.ndarray             # bool
-    # The CARRIED leaf partition (tpu_incremental_partition=true; the
-    # reference's DataPartition, data_partition.hpp:94, maintained ACROSS
-    # waves): rows of leaf l occupy positions [seg_start[l], seg_start[l] +
-    # seg_rows[l]) of `perm`, in ascending original row order — stable
-    # splits preserve that order, so the compacted gather sequence is
-    # BIT-identical to the default's per-compacted-wave sort. Not what runs
-    # by default since PR 28: re-partitioning `perm` cost 3.19 s of a 6.64 s
-    # tree at 14.7M rows on the v5e (0.126 s of gathers + 0.072 s of scatter
-    # + 0.028 s of the sort XLA puts inside that scatter, every wave; my
-    # chip run, PR 27) — kept as the parity oracle until the next
-    # `simplicity` PR deletes it. seg_rows are RAW row counts (OOB/padding
-    # rows included; they route but carry zero weights), distinct from the
-    # bagging-weighted `cnt`. All three are None unless the carried
-    # partition is on (row_compact and tpu_incremental_partition=true) —
-    # None is a static empty pytree leaf, so the while_loop carry stays
-    # structurally consistent.
-    perm: Optional[jnp.ndarray] = None       # i32 [N] leaf-contiguous rows
-    seg_start: Optional[jnp.ndarray] = None  # i32 [L+1]
-    seg_rows: Optional[jnp.ndarray] = None   # i32 [L+1]
-    # The default's only per-row carry besides leaf_id: the histogram slot
-    # of the row's leaf in the NEXT wave (-1: its leaf is not pending),
-    # written by the routing pass that moved the row (step 8), so no wave
-    # pays a second per-row table_lookup. None with the carried partition
-    # or row_compact off.
+    # The only per-row carry besides leaf_id: the histogram slot of the
+    # row's leaf in the NEXT wave (-1: its leaf is not pending), written by
+    # the routing pass that moved the row (step 8), so no wave pays a second
+    # per-row table_lookup. None with row_compact off (a static empty pytree
+    # leaf, so the while_loop carry stays structurally consistent).
     slot_row: Optional[jnp.ndarray] = None   # i32 [N]
     # the loop's own counters (resident loop only; None under streaming,
     # where the host drives the waves)
@@ -270,22 +249,12 @@ class GrowerSpec:
     min_data_in_leaf: float
     min_sum_hessian_in_leaf: float
     min_gain_to_split: float
-    row_compact: bool = True      # histogram only pending-leaf rows per wave
-    incremental_partition: bool = False
-                                  # how a COMPACTED pass gets its
-                                  # slot-grouped row index. False (default):
-                                  # nothing is carried; the compacted arm of
-                                  # the wave's cond builds the index with
-                                  # one stable sort of the rows by pending
-                                  # slot (phase wave.partition), a streamed
-                                  # wave builds nothing. True: the carried
-                                  # permutation (GrowState.perm), re-
-                                  # partitioned EVERY wave by gather +
-                                  # cumsums + scatter — bit-identical, the
-                                  # parity oracle of
-                                  # tests/test_incremental_partition.py,
-                                  # 1.8x slower end to end at 14.7M rows
-                                  # (PERF.md, PR 28)
+    row_compact: bool = True      # a wave may COMPACT its histogram pass:
+                                  # read only the pending leaves' rows,
+                                  # through a slot-grouped index built by
+                                  # one sort in that arm (phase
+                                  # wave.partition); a streamed wave builds
+                                  # nothing. False: every wave streams
     compact_frac: float = 1.0     # a wave compacts its histogram pass when
                                   # n_active < int(N * compact_frac), else
                                   # it streams all N rows. RESOLVED where
@@ -311,9 +280,10 @@ class GrowerSpec:
                                   # None = plain byte layout by X dtype
     hist_kernel: str = "xla"      # "xla" (one-hot matmul) | "pallas" (fused
                                   # VMEM-accumulator kernel, ops/pallas_histogram.py)
-    hist_hilo: bool = True        # bf16 hi/lo channel pairs (~f32 sums) vs
-                                  # single bf16 (GPU-reference-style tradeoff)
-    hist_f64: bool = False        # Kahan-compensated chunk accumulation:
+    hist_f64: bool = False        # the weight mode, one of two. False: bf16
+                                  # hi/lo channel pairs (~f32 sums). True:
+                                  # f32 channels at Precision.HIGHEST with a
+                                  # Kahan-compensated chunk accumulation,
                                   # ~f64-accurate bin sums like the
                                   # reference's double HistogramBinEntry
                                   # (bin.h:29-31); xla kernel only
@@ -679,6 +649,18 @@ def _rows_by_slot(slot_row: jnp.ndarray, num_slots: int) -> jnp.ndarray:
     return out[0] & ((1 << 24) - 1) if one_word else out[1]
 
 
+def _slot_grouped_rows(slot_row: jnp.ndarray, num_slots: int
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """What a compacted pass reads besides the packed rows: (the row index
+    grouped by pending slot, the rows of each slot), from every row's slot
+    (-1: its leaf is not pending). The counts sum to the pending rows."""
+    row_idx = _rows_by_slot(slot_row, num_slots)
+    counts = jnp.sum((slot_row[:, None]
+                      == jnp.arange(num_slots, dtype=jnp.int32)[None, :])
+                     .astype(jnp.int32), axis=0)
+    return row_idx, counts
+
+
 @trace_entry("grower.wave_body")
 def grow_tree(
     X: jnp.ndarray,               # [N, F] bin codes ([N, G] bundled under EFB)
@@ -758,31 +740,21 @@ def grow_tree(
 
     # one packed u8 row array per TREE (bin-code bytes + bf16 g/h channel
     # bytes): the compacted waves gather rows from it with a single random
-    # access each; building it is an O(N) sequential write paid once here
-    # instead of per wave
+    # access each (and the Pallas kernel reads every pass from it); building
+    # it is an O(N) sequential write paid once here instead of per wave
     # weight-channel mode: hist_f64 carries full f32 channels (exact
     # products at Precision.HIGHEST + Kahan chunk carry in build_histograms).
     # Guard at the mechanism: the pallas kernel unpacks packed weights as
     # bf16 unconditionally, so f32-mode rows would silently decode garbage
     assert not (spec.hist_f64 and spec.hist_kernel in ("pallas", "mixed")), \
         "tpu_hist_f64 requires the xla histogram kernel"
-    wmode = "f32" if spec.hist_f64 else spec.hist_hilo
-    if spec.row_compact:
+    if spec.row_compact or spec.hist_kernel == "pallas":
         from .ops.histogram import pack_rows
         with jax.named_scope("tree.pack_rows"):
             packed_rows, _ = pack_rows(X_hist, grad, hess, included,
-                                       wmode, spec.code_mode)
+                                       spec.hist_f64, spec.code_mode)
     else:
         packed_rows = None
-
-    # how a compacted pass gets its slot-grouped row index: by one sort in
-    # its own arm (the default), or from the permutation carried across
-    # waves (the parity oracle), where rows start as ONE root segment in
-    # original order — the identity permutation, rebuilt per tree (iota is
-    # free; a cross-tree carry would violate the ascending-within-segment
-    # invariant the root segment needs)
-    use_inc = spec.row_compact and spec.incremental_partition
-    use_sort = spec.row_compact and not use_inc
 
     tree = _empty_tree(L, B)
     state = GrowState(
@@ -800,12 +772,8 @@ def grow_tree(
         parent_cache=jnp.full(L + 1, L, jnp.int32),
         num_leaves_cur=jnp.asarray(1, jnp.int32),
         done=jnp.asarray(False),
-        perm=jnp.arange(N, dtype=jnp.int32) if use_inc else None,
-        seg_start=jnp.zeros(L + 1, jnp.int32) if use_inc else None,
-        seg_rows=(jnp.zeros(L + 1, jnp.int32).at[0].set(N)
-                  if use_inc else None),
         # the root is pending in slot 0, and every row is in it
-        slot_row=jnp.zeros(N, jnp.int32) if use_sort else None,
+        slot_row=jnp.zeros(N, jnp.int32) if spec.row_compact else None,
         stats=_empty_stats(L),
     )
 
@@ -830,7 +798,7 @@ def grow_tree(
         # then the distributed reduction: psum_scatter for data-parallel
         # (reference data_parallel_tree_learner.cpp:148-163), identity
         # otherwise; output covers this device's feature block only.
-        def hist_pass(row_idx, n_active, slot_counts=None, slot_starts=None):
+        def hist_pass(row_idx=None, n_active=None, slot_counts=None):
             # "mixed": the XLA one-hot matmul for FULL streaming passes and
             # the Pallas VMEM-accumulator kernel for COMPACTED passes (which
             # kernel wins which pass type on today's chip: not measured,
@@ -847,8 +815,7 @@ def grow_tree(
                     # streaming chunk; the pallas grid step is its own knob
                     chunk_rows=min(spec.chunk_rows, 512),
                     row_idx=row_idx,
-                    n_active=n_active, hilo=spec.hist_hilo,
-                    slot_counts=slot_counts, slot_starts=slot_starts,
+                    n_active=n_active, slot_counts=slot_counts,
                     packed=packed_rows,
                     # the adaptive cond only takes this path when
                     # n_active*4 < N — grid + buffers shrink to match
@@ -856,9 +823,8 @@ def grow_tree(
             return build_histograms(
                 X_hist, grad, hess, included, state.leaf_id, slot_of_leaf,
                 num_slots=S, num_bins_padded=B_hist, chunk_rows=spec.chunk_rows,
-                row_idx=row_idx, n_active=n_active, hilo=wmode,
-                slot_counts=slot_counts, slot_starts=slot_starts,
-                packed=packed_rows,
+                row_idx=row_idx, n_active=n_active, exact=spec.hist_f64,
+                slot_counts=slot_counts, packed=packed_rows,
                 code_mode=spec.code_mode, compensated=spec.hist_f64)
 
         if spec.row_compact:
@@ -877,39 +843,18 @@ def grow_tree(
             # compacts unless the table is narrow. Under tree_learner=data
             # N is a shard's rows and each shard decides for itself, inside
             # its own cond; the reduction is outside it.
-            if use_inc:
-                # slot bookkeeping straight from the carried partition:
-                # counts/starts are [S]-sized gathers from the per-leaf
-                # segment tables, n_active a [S] reduction.
-                # leaf_of_slot == L for empty slots and seg_rows[L] stays 0,
-                # so invalid slots contribute nothing.
-                with jax.named_scope("wave.slots"):
-                    slot_counts_inc = state.seg_rows[leaf_of_slot]    # [S]
-                    slot_starts_inc = state.seg_start[leaf_of_slot]   # [S]
-                    n_active = jnp.sum(slot_counts_inc)
-            else:
-                # the slot of every row's leaf came with the routing pass
-                # that moved the row (step 8): one reduction decides the arm
-                with jax.named_scope("wave.slots"):
-                    slot_row = state.slot_row                         # [N] i32
-                    n_active = jnp.sum((slot_row >= 0).astype(jnp.int32))
+            # the slot of every row's leaf came with the routing pass that
+            # moved the row (step 8): one reduction decides the arm
+            with jax.named_scope("wave.slots"):
+                slot_row = state.slot_row                             # [N] i32
+                n_active = jnp.sum((slot_row >= 0).astype(jnp.int32))
 
             def compact_pass():
-                if use_inc:
-                    # rows already slot-grouped inside the carried
-                    # permutation; the kernels map compacted positions into
-                    # the pending segments via slot_starts
-                    return hist_pass(state.perm, n_active, slot_counts_inc,
-                                     slot_starts_inc)
-                # the default: rows grouped by slot, original order within
-                # a slot, built here and now by one sort — only the waves
-                # that take this arm pay for it, and nothing is carried
+                # rows grouped by slot, original order within a slot, built
+                # here and now by one sort — only the waves that take this
+                # arm pay for it, and nothing is carried
                 with jax.named_scope("wave.partition"):
-                    row_idx = _rows_by_slot(slot_row, S)
-                    counts = jnp.sum(
-                        (slot_row[:, None]
-                         == jnp.arange(S, dtype=jnp.int32)[None, :])
-                        .astype(jnp.int32), axis=0)
+                    row_idx, counts = _slot_grouped_rows(slot_row, S)
                 return hist_pass(row_idx, n_active, counts)
 
             # the threshold is a static Python int, so the predicate cannot
@@ -927,14 +872,14 @@ def grow_tree(
 
             def stream_arm():
                 with jax.named_scope("wave.hist.stream"):
-                    return hist_pass(None, None)
+                    return hist_pass()
 
             new_hist = jax.lax.cond(compacted, compact_arm, stream_arm)
         else:
             n_active = jnp.asarray(-1, jnp.int32)   # not counted in this arm
             compacted = jnp.asarray(False)
             with jax.named_scope("wave.hist.stream"):
-                new_hist = hist_pass(None, None)
+                new_hist = hist_pass()
         with jax.named_scope("wave.hist.reduce"):
             if unbundle_early:
                 # this shard's leaf totals: any bundled column's bins partition
@@ -962,64 +907,8 @@ def grow_tree(
             X, state.leaf_id, table, map_mask, spec, bundle, default_bin)
 
         # ---- 8. per-row bookkeeping for the next wave ----------------------
-        # Carried-partition arm (the parity oracle): the reference's
-        # DataPartition::Split (data_partition.hpp:94). Only the split
-        # leaves' segments re-partition — STABLY, by prefix sums and a
-        # monotonic scatter over ALL positions (which the TPU's compiler
-        # expands into a sort of its own: 3.19 s of a 6.64 s tree at 14.7M
-        # rows, my chip run, PR 27). Leaf p keeps the front of its old
-        # segment (its go-left rows, original order), new leaf q takes the
-        # back — so within-segment ascending row order survives and the
-        # next wave's compacted gather sequence is bit-identical to the
-        # default's stable order by (slot, row). The split ordinal of a
-        # row's leaf is recovered from the routing pass's own table_lookup
-        # output (q = num_leaves_cur + srank).
-        if use_inc:
-            with jax.named_scope("wave.partition"):
-                k_row = jnp.where(f_row >= 0,
-                                  right_row - state.num_leaves_cur, -1)   # [N]
-                code_row = jnp.where(f_row >= 0,
-                                     2 * k_row + jnp.where(go_left, 0, 1), -1)
-                code_pos = jnp.take(code_row, state.perm)      # row -> position
-                in_split = code_pos >= 0
-                left_pos = in_split & ((code_pos & 1) == 0)
-                right_pos = in_split & ((code_pos & 1) == 1)
-                k_pos = code_pos >> 1                          # -1 stays -1
-                cl = jnp.cumsum(left_pos.astype(jnp.int32))    # inclusive
-                cr = jnp.cumsum(right_pos.astype(jnp.int32))
-                # cl0[j] = lefts strictly before position j (length N+1 so the
-                # one-past-the-end segment boundary reads the segment total)
-                cl0 = jnp.concatenate([jnp.zeros(1, jnp.int32), cl])
-                cr0 = jnp.concatenate([jnp.zeros(1, jnp.int32), cr])
-                start_k = state.seg_start[p]                   # [S]; p==L inert
-                n_k = state.seg_rows[p]
-                clb = jnp.take(cl0, start_k)
-                crb = jnp.take(cr0, start_k)
-                nL = jnp.take(cl0, start_k + n_k) - clb        # raw left rows
-                # per-slot additive bases resolved per position by an INTEGER
-                # one-hot multiply-sum (exact at any N — no f32 2^24 ceiling)
-                k_onehot = (k_pos[:, None]
-                            == jnp.arange(S, dtype=jnp.int32)[None, :])
-                base_l = jnp.sum(k_onehot * (start_k - clb)[None, :], axis=1)
-                base_r = jnp.sum(k_onehot * (start_k + nL - crb)[None, :], axis=1)
-                newpos = jnp.where(left_pos,
-                                   (cl - left_pos.astype(jnp.int32)) + base_l,
-                                   (cr - right_pos.astype(jnp.int32)) + base_r)
-                perm = state.perm.at[jnp.where(in_split, newpos, N)].set(
-                    state.perm, mode="drop")
-                seg_start = state.seg_start.at[q].set(start_k + nL)
-                seg_rows = state.seg_rows.at[p].set(nL).at[q].set(n_k - nL)
-                # scratch leaf L must stay an empty segment (slot_counts reads
-                # seg_rows[leaf_of_slot] with leaf_of_slot==L for empty slots);
-                # masked-split writes above land there and are reset like the
-                # tree table's scratch row
-                seg_start = seg_start.at[L].set(0)
-                seg_rows = seg_rows.at[L].set(0)
-        else:
-            perm, seg_start, seg_rows = (state.perm, state.seg_start,
-                                         state.seg_rows)
         slot_row_next = None
-        if use_sort:
+        if spec.row_compact:
             # The next wave's pending leaves are children of THIS wave's
             # splits (needs_hist is reset and set for the smaller child
             # only), so the slot of a row's new leaf is a function of its
@@ -1040,11 +929,9 @@ def grow_tree(
 
         # ---- 9. the loop's own counters, one entry per wave ----------------
         with jax.named_scope("wave.stats"):
-            # rows of the leaves split this wave: their segments' sizes
-            # (seg_rows[L] == 0, so p == L is inert), or without the
-            # carried partition a count over the routing pass's output
-            rows_split = (jnp.sum(n_k) if use_inc else
-                          jnp.sum((f_row >= 0).astype(jnp.int32)))
+            # rows of the leaves split this wave: a count over the routing
+            # pass's output
+            rows_split = jnp.sum((f_row >= 0).astype(jnp.int32))
             # slots of this wave's split scan that held a leaf: the pending
             # leaves, and those of their siblings that exist (the root has
             # none)
@@ -1059,9 +946,8 @@ def grow_tree(
                 rows_split=st.rows_split.at[st.waves].set(rows_split),
                 scan_pending=st.scan_pending.at[st.waves].set(scan_pending))
 
-        return state2._replace(leaf_id=leaf_id, perm=perm,
-                               seg_start=seg_start, seg_rows=seg_rows,
-                               slot_row=slot_row_next, stats=stats)
+        return state2._replace(leaf_id=leaf_id, slot_row=slot_row_next,
+                               stats=stats)
 
     def cond(state: GrowState):
         return ~state.done
@@ -1150,7 +1036,6 @@ class StreamedGrower:
         self.missing_code = missing_code
         self.default_bin = default_bin
         self.is_cat = is_cat
-        self.wmode = "f32" if spec.hist_f64 else spec.hist_hilo
         # serial comm when none supplied (mirrors grow_tree)
         if comm is None:
             from .parallel.comm import SerialComm
@@ -1168,7 +1053,7 @@ class StreamedGrower:
         self._mesh = pctx.mesh if pctx is not None else None
         self._n_dev = pctx.num_devices if self._mesh is not None else 1
         from .ops.histogram import num_channels
-        self._ch = num_channels(self.wmode)
+        self._ch = num_channels(spec.hist_f64)
         self._B_hist = spec.hist_bins or spec.num_bins_padded
         self._build_fns()
 
@@ -1297,7 +1182,7 @@ class StreamedGrower:
             acc_l, comp_l = build_histograms(
                 codes, g_sh, h_sh, m_sh, new_lid, slot_of_leaf,
                 num_slots=S, num_bins_padded=B_hist,
-                chunk_rows=spec.chunk_rows, hilo=self.wmode,
+                chunk_rows=spec.chunk_rows, exact=spec.hist_f64,
                 compensated=spec.hist_f64, acc_init=acc_l,
                 comp_init=comp[0] if spec.hist_f64 else None,
                 raw_output=True)
@@ -1316,7 +1201,7 @@ class StreamedGrower:
             bm = comm.block_meta(feature_ok, self.num_bins,
                                  self.missing_code, self.default_bin,
                                  self.is_cat)
-            new_hist = finalize_histograms(acc[0], S, self.wmode)
+            new_hist = finalize_histograms(acc[0], S, spec.hist_f64)
             if self.unbundle_early:
                 lpg = jnp.sum(new_hist[:, 0, :, 0], axis=-1)
                 lph = jnp.sum(new_hist[:, 0, :, 1], axis=-1)

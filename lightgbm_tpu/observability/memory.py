@@ -111,8 +111,7 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
                             num_bins_padded: int, slots: int,
                             chunk_rows: int, channels: int,
                             channel_bytes: int, packed_row_bytes: int = 0,
-                            row_compact: bool = True,
-                            incremental: bool = False, bagging: bool = False,
+                            row_compact: bool = True, bagging: bool = False,
                             has_weight: bool = False, tree_batch: int = 1,
                             compensated: bool = False,
                             valid_bytes: int = 0,
@@ -133,9 +132,7 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
     - scores:     the [K, N] carried score (donation keeps ONE copy live)
     - gradients:  g and h, [K, N] f32 each
     - partition:  leaf_id, and with row_compact one more int32 a row (the
-                  row's slot in the next wave; with
-                  tpu_incremental_partition=true the carried permutation
-                  instead, and its two segment tables)
+                  row's slot in the next wave)
     - packed:     the per-tree packed gather rows (code bytes + weight
                   channel bytes per row)
     - hist_cache: the [L+1, F_cache, B_cache, 3] f32 per-leaf cache
@@ -160,8 +157,7 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
     comp["metadata"] = rows * f32 * (2 + int(bagging) + int(has_weight))
     comp["scores"] = num_models * rows * f32
     comp["gradients"] = 2 * num_models * rows * f32
-    comp["partition"] = rows * f32 * (2 if row_compact else 1) \
-        + (2 * (num_leaves + 1) * f32 if incremental else 0)
+    comp["partition"] = rows * f32 * (2 if row_compact else 1)
     comp["packed"] = rows * packed_row_bytes if row_compact else 0
     comp["hist_cache"] = (num_leaves + 1) * cache_cols * cache_bins * 3 * f32
     acc = hist_cols * hist_bins * slots * channels * f32
@@ -227,12 +223,7 @@ def hbm_preflight(gbdt) -> Dict:
         from ..utils.log import Log
         Log.debug("hbm_preflight: reduced_hist_features unavailable "
                   "(using %d): %s: %s", cache_cols, type(e).__name__, e)
-    if spec.hist_f64:
-        channels, channel_bytes = 3, 4
-    elif spec.hist_hilo:
-        channels, channel_bytes = 5, 2
-    else:
-        channels, channel_bytes = 3, 2
+    channels, channel_bytes = (3, 4) if spec.hist_f64 else (5, 2)
     packed_row_bytes = 0
     if spec.row_compact:
         from ..ops.histogram import code_bytes_total, default_code_mode
@@ -256,7 +247,6 @@ def hbm_preflight(gbdt) -> Dict:
                 channel_bytes=channel_bytes,
                 packed_row_bytes=packed_row_bytes,
                 row_compact=spec.row_compact,
-                incremental=spec.row_compact and spec.incremental_partition,
                 bagging=bool(getattr(gbdt, "bagging_on", False)),
                 has_weight=gbdt.weight is not None,
                 tree_batch=int(getattr(gbdt, "tree_batch", 1)),

@@ -29,19 +29,17 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-# Weight-channel modes (the `hilo` parameter throughout):
-#   True  — g_hi, g_lo, h_hi, h_lo, count bf16 hi/lo pairs (~f32 sums)
-#   False — g, h, count single bf16 (the reference GPU path's
-#           f32-and-accept-tiny-deltas tradeoff at 40% fewer columns)
-#   "f32" — g, h, count full f32 columns contracted at Precision.HIGHEST
+# Weight-channel modes (the `exact` parameter throughout):
+#   False — g_hi, g_lo, h_hi, h_lo, count bf16 hi/lo pairs (~f32 sums)
+#   True  — g, h, count full f32 columns contracted at Precision.HIGHEST
 #           (exact per-element products; tpu_hist_f64's exactness half —
 #           the Kahan carry in build_histograms is the other)
 NUM_CHANNELS = 5
-NUM_CHANNELS_FAST = 3
+NUM_CHANNELS_EXACT = 3
 
 
-def num_channels(hilo) -> int:
-    return NUM_CHANNELS if hilo is True else NUM_CHANNELS_FAST
+def num_channels(exact: bool) -> int:
+    return NUM_CHANNELS_EXACT if exact else NUM_CHANNELS
 
 
 # ---- the pass, sized by the table's width ----------------------------------
@@ -86,9 +84,8 @@ def hist_pass_shape(rows: int, features: int, bins_padded: int,
 #   matmul        _MATMUL_NS_A_CELL x F x B, the chunk matmul of either arm
 #                 with bf16 hi/lo weights (1.36 ps a cell in the trees of
 #                 400,000 x 2,000, 1.40 in passes alone from 6 to 512
-#                 columns), times _MATMUL_SCALE in the other weight modes
-#                 (f32 columns at Precision.HIGHEST: 5.1x; three plain bf16
-#                 columns a slot: 3.8x, not 0.6x)
+#                 columns), times _MATMUL_SCALE_EXACT with f32 columns at
+#                 Precision.HIGHEST
 #   stream_fixed  what a streamed row pays whatever the width: its slot
 #                 lookup, slices and weight channels
 #   gather        one packed row, BY ITS BYTES and not smoothly: rows of 28
@@ -100,7 +97,7 @@ def hist_pass_shape(rows: int, features: int, bins_padded: int,
 # smaller children only, under half of the rows: a value above 0.5 means
 # "stream a full root, compact the rest".
 _MATMUL_NS_A_CELL = 1.38e-3
-_MATMUL_SCALE = {True: 1.0, False: 3.8, "f32": 5.1}    # by weight mode (hilo)
+_MATMUL_SCALE_EXACT = 5.1
 _STREAM_FIXED_NS = 1.8
 # (row bytes up to, ns a gathered row): one gather alone at each width, times
 # the 0.9 that whole trees read of it at 20 and at 38 bytes
@@ -127,14 +124,15 @@ def _gather_ns(row_bytes: int) -> float:
 
 
 def compact_break_even(rows: int, features: int, bins_padded: int,
-                       row_bytes: int, num_slots: int, hilo=True) -> float:
+                       row_bytes: int, num_slots: int,
+                       exact: bool = False) -> float:
     """The share of a device's rows below which a wave's compacted pass is
     cheaper than a streamed one: a static function of the shapes, in
     (0, 1]. ``features`` x ``bins_padded`` is the histogram BUILD's width
     (bundle space under EFB), ``row_bytes`` the packed row the gather
-    fetches, ``rows`` the rows a device, ``hilo`` the weight mode."""
+    fetches, ``rows`` the rows a device, ``exact`` the weight mode."""
     matmul = (_MATMUL_NS_A_CELL * int(features) * int(bins_padded)
-              * _MATMUL_SCALE[hilo])
+              * (_MATMUL_SCALE_EXACT if exact else 1.0))
     sort = _SORT_NS_A_ROW[sort_is_one_word(int(rows), int(num_slots))]
     frac = ((matmul + _STREAM_FIXED_NS - sort)
             / (matmul + _gather_ns(int(row_bytes))))
@@ -151,32 +149,29 @@ def resolve_compact_frac(requested: float, hist_kernel: str, **shape) -> float:
     return frac
 
 
-def weight_channels(grad, hess, included, hilo):
+def weight_channels(grad, hess, included, exact: bool):
     """[N, ch] weight channels for the one-hot matmul (dtype by mode)."""
-    if hilo is True:
-        g_hi, g_lo = _split_hi_lo(grad)
-        h_hi, h_lo = _split_hi_lo(hess)
-        # every input cast explicitly (R003): a dtype change upstream in
-        # _split_hi_lo must not silently widen the packed channel matrix
-        return jnp.stack([g_hi.astype(jnp.bfloat16),
-                          g_lo.astype(jnp.bfloat16),
-                          h_hi.astype(jnp.bfloat16),
-                          h_lo.astype(jnp.bfloat16),
-                          included.astype(jnp.bfloat16)], axis=-1)
-    if hilo == "f32":
+    if exact:
         return jnp.stack([grad.astype(jnp.float32),
                           hess.astype(jnp.float32),
                           included.astype(jnp.float32)], axis=-1)
-    return jnp.stack([grad.astype(jnp.bfloat16), hess.astype(jnp.bfloat16),
+    g_hi, g_lo = _split_hi_lo(grad)
+    h_hi, h_lo = _split_hi_lo(hess)
+    # every input cast explicitly (R003): a dtype change upstream in
+    # _split_hi_lo must not silently widen the packed channel matrix
+    return jnp.stack([g_hi.astype(jnp.bfloat16),
+                      g_lo.astype(jnp.bfloat16),
+                      h_hi.astype(jnp.bfloat16),
+                      h_lo.astype(jnp.bfloat16),
                       included.astype(jnp.bfloat16)], axis=-1)
 
 
-def combine_channels(acc, hilo):
+def combine_channels(acc, exact: bool):
     """[..., ch] f32 accumulated channels -> [..., 3] (sum_g, sum_h, cnt)."""
-    if hilo is True:
-        return jnp.stack([acc[..., 0] + acc[..., 1],
-                          acc[..., 2] + acc[..., 3], acc[..., 4]], axis=-1)
-    return acc[..., :3]
+    if exact:
+        return acc[..., :3]
+    return jnp.stack([acc[..., 0] + acc[..., 1],
+                      acc[..., 2] + acc[..., 3], acc[..., 4]], axis=-1)
 
 
 def _split_hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -240,11 +235,11 @@ def code_bytes_total(F: int, code_mode: str) -> int:
             "u6": ((F + 3) // 4) * 3}[code_mode]
 
 
-def packed_row_bytes(F: int, code_mode: str, hilo) -> int:
+def packed_row_bytes(F: int, code_mode: str, exact: bool) -> int:
     """Bytes of one row of ``pack_rows``: the code bytes, then the weight
-    channels (f32 in the "f32" mode, bf16 otherwise)."""
+    channels (three f32 in the exact mode, five bf16 otherwise)."""
     return (code_bytes_total(F, code_mode)
-            + num_channels(hilo) * (4 if hilo == "f32" else 2))
+            + num_channels(exact) * (4 if exact else 2))
 
 
 def _pack_codes(X: jnp.ndarray, code_mode: str) -> jnp.ndarray:
@@ -271,14 +266,14 @@ def _pack_codes(X: jnp.ndarray, code_mode: str) -> jnp.ndarray:
     return jnp.stack([b0, b1, b2], axis=-1).reshape(N, -1)
 
 
-def pack_rows(X, grad, hess, included, hilo,
+def pack_rows(X, grad, hess, included, exact: bool,
               code_mode: str = None) -> Tuple[jnp.ndarray, int]:
     """Returns (packed [N, ncb + weight bytes] u8, code byte count ncb)."""
     N, F = X.shape
     if code_mode is None:
         code_mode = default_code_mode(X.dtype)
     codes = _pack_codes(X, code_mode)
-    w = weight_channels(grad, hess, included, hilo)     # [N, ch] bf16 or f32
+    w = weight_channels(grad, hess, included, exact)    # [N, ch] bf16 or f32
     wb = jax.lax.bitcast_convert_type(w, jnp.uint8).reshape(N, -1)
     return jnp.concatenate([codes, wb], axis=1), codes.shape[1]
 
@@ -320,26 +315,6 @@ def slot_from_position(pos: jnp.ndarray, slot_cum: jnp.ndarray) -> jnp.ndarray:
     spans positions [cum[s-1], cum[s]) — a VPU compare-sum, no row gather."""
     return jnp.sum((pos[:, None] >= slot_cum[None, :]).astype(jnp.int32),
                    axis=1)
-
-
-def slot_position_base(raw_slot: jnp.ndarray, slot_cum: jnp.ndarray,
-                       slot_starts: jnp.ndarray) -> jnp.ndarray:
-    """Additive base mapping a slot-grouped virtual position into a
-    leaf-contiguous permutation: ``src = pos + base[raw_slot]``.
-
-    The grower's incremental partition (grower.py GrowState.perm) keeps each
-    pending leaf's rows contiguous at ``slot_starts[s]`` instead of
-    materializing a compacted prefix; compacted histogram chunks translate
-    their positions on the fly, so only ACTIVE chunks ever touch the
-    permutation. Integer one-hot multiply-sum: exact at any N (no f32 2^24
-    ceiling) and no per-row table gather. Positions past the last slot
-    (raw_slot == S, garbage masked downstream) get base 0."""
-    S = slot_cum.shape[0]
-    cum_before = jnp.concatenate(
-        [jnp.zeros(1, slot_cum.dtype), slot_cum[:-1]])
-    base = slot_starts - cum_before                                 # [S]
-    onehot = raw_slot[:, None] == jnp.arange(S, dtype=jnp.int32)[None, :]
-    return jnp.sum(onehot * base[None, :], axis=1)
 
 
 def table_lookup(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
@@ -391,28 +366,6 @@ def table_lookup(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     return out[:, 0] if squeeze else out
 
 
-def compact_rows(leaf_id: jnp.ndarray, slot_of_leaf: jnp.ndarray
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Prefix-compact the indices of rows whose leaf is pending a histogram.
-
-    Returns (row_idx [N] i32, n_active i32): the first `n_active` entries of
-    `row_idx` are the indices of rows in pending leaves (original order); the
-    rest are garbage and masked out downstream. The TPU analog of the
-    reference's leaf-contiguous DataPartition (data_partition.hpp:94):
-    instead of maintaining a permutation across splits, we rebuild the
-    pending-rows prefix each wave with one cumsum + one monotonic scatter —
-    both cheap VPU streams next to the histogram matmul they gate.
-    """
-    n = leaf_id.shape[0]
-    pending = slot_of_leaf[leaf_id] >= 0                          # [N] bool
-    pos = jnp.cumsum(pending.astype(jnp.int32)) - 1               # [N]
-    n_active = jnp.where(n > 0, pos[-1] + 1, 0)
-    row_idx = jnp.zeros(n, jnp.int32).at[
-        jnp.where(pending, pos, n)                                # invalid -> dropped
-    ].set(jnp.arange(n, dtype=jnp.int32), mode="drop")
-    return row_idx, n_active
-
-
 def build_histograms(
     X: jnp.ndarray,          # [N, F] uint8/uint16 bin codes (N padded to chunk multiple)
     grad: jnp.ndarray,       # [N] f32 (bagging-masked)
@@ -423,21 +376,19 @@ def build_histograms(
     num_slots: int,
     num_bins_padded: int,
     chunk_rows: int,
-    row_idx: jnp.ndarray = None,   # [N] i32 from compact_rows (optional)
+    row_idx: jnp.ndarray = None,   # [N] i32: a COMPACTED pass. The rows
+                                   # grouped by pending slot, ascending
+                                   # within a slot (grower._rows_by_slot);
+                                   # needs n_active, slot_counts and packed
     n_active: jnp.ndarray = None,  # i32 count of valid row_idx entries
-    hilo: bool = True,             # hi/lo bf16 channel pairs (~f32 sums)
-    slot_counts: jnp.ndarray = None,  # [S] i32: rows per slot when row_idx is
-                                   # SLOT-GROUPED — slots derive from position
-                                   # (2 fewer random gathers per active row)
-    slot_starts: jnp.ndarray = None,  # [S] i32: row_idx is a LEAF-CONTIGUOUS
-                                   # permutation (grower incremental
-                                   # partition) — slot s's rows live at
-                                   # row_idx[slot_starts[s]:...+counts[s]];
-                                   # chunks remap positions via
-                                   # slot_position_base. Requires slot_counts
-    packed: jnp.ndarray = None,    # pre-built pack_rows(X, grad, hess,
-                                   # included) — pass to amortize the O(N)
-                                   # pack across waves of one tree
+    exact: bool = False,           # weight mode: bf16 hi/lo channel pairs
+                                   # (~f32 sums) | f32 columns at HIGHEST
+    slot_counts: jnp.ndarray = None,  # [S] i32 rows per slot of row_idx: a
+                                   # position's slot comes from the counts'
+                                   # running sum, no per-row gather
+    packed: jnp.ndarray = None,    # pack_rows(X, grad, hess, included): the
+                                   # one array a compacted pass gathers its
+                                   # rows from, built once a tree
     code_mode: str = None,         # packed-row code layout; None = by dtype
     compensated: bool = False,     # Kahan-compensate the chunk accumulation:
                                    # ~f64-accurate bin sums (the reference
@@ -458,9 +409,10 @@ def build_histograms(
 ) -> jnp.ndarray:
     """Returns hist [num_slots, F, num_bins_padded, 3] f32 (sum_g, sum_h, count).
 
-    With (row_idx, n_active) the pass is *row-compacted*: only
-    ceil(n_active/chunk_rows) chunks run (a dynamic-trip-count while_loop),
-    each gathering its rows through row_idx — the analog of the reference
+    With (row_idx, n_active, slot_counts, packed) the pass is
+    *row-compacted*: only ceil(n_active/chunk_rows) chunks run (a
+    dynamic-trip-count while_loop), each gathering its packed rows through
+    a slice of row_idx — the analog of the reference
     histogramming only the smaller leaf's rows
     (serial_tree_learner.cpp:354-362) instead of a full-data pass per wave.
 
@@ -473,19 +425,17 @@ def build_histograms(
     n_rows, num_features = X.shape
     assert n_rows % chunk_rows == 0, (n_rows, chunk_rows)
     n_chunks = n_rows // chunk_rows
-    ch = num_channels(hilo)
+    ch = num_channels(exact)
     compact = row_idx is not None
-    assert slot_starts is None or slot_counts is not None, \
-        "slot_starts (leaf-contiguous row_idx) needs slot_counts"
     iota_bins = jnp.arange(num_bins_padded, dtype=jnp.int32)[None, None, :]
     iota_slots = jnp.arange(num_slots, dtype=jnp.int32)[None, :]
     iota_chunk = jnp.arange(chunk_rows, dtype=jnp.int32)
-    slot_cum = (jnp.cumsum(slot_counts) if slot_counts is not None else None)
     if compact:
+        assert slot_counts is not None and packed is not None, \
+            "a compacted pass reads a slot-grouped row_idx: slot_counts, packed"
+        slot_cum = jnp.cumsum(slot_counts)
         if code_mode is None:
             code_mode = default_code_mode(X.dtype)
-        if packed is None:
-            packed, _ = pack_rows(X, grad, hess, included, hilo, code_mode)
         ncb = code_bytes_total(num_features, code_mode)
 
     def chunk_part(i):
@@ -496,22 +446,11 @@ def build_histograms(
             with jax.named_scope("wave.hist.compact.gather"):
                 pos = i * chunk_rows + iota_chunk
                 valid = pos < n_active
-                if slot_starts is not None:
-                    # leaf-contiguous permutation: translate compacted positions
-                    # into the pending segments (incremental partition) — the
-                    # slot is position-derived exactly as in the prefix layout
-                    raw = slot_from_position(pos, slot_cum)
-                    src = pos + slot_position_base(raw, slot_cum, slot_starts)
-                    idx = jnp.take(row_idx, jnp.clip(src, 0, n_rows - 1))
-                else:
-                    idx = sl(row_idx, i * chunk_rows, chunk_rows)
-                    if slot_cum is not None:
-                        raw = slot_from_position(pos, slot_cum)
-                    else:
-                        raw = table_lookup(jnp.take(leaf_id, idx), slot_of_leaf)
+                idx = sl(row_idx, i * chunk_rows, chunk_rows)
+                raw = slot_from_position(pos, slot_cum)
                 pk = jnp.take(packed, idx, axis=0)                    # [R, Wb] u8
                 xc = unpack_codes(pk[:, :ncb], num_features, code_mode)
-                w = unpack_weights(pk[:, ncb:], ch, f32=(hilo == "f32"))  # [R, ch]
+                w = unpack_weights(pk[:, ncb:], ch, f32=exact)         # [R, ch]
                 slot = jnp.where(valid, raw, -1)                       # [R]
         else:
             xc = sl(X, i * chunk_rows, chunk_rows)
@@ -520,7 +459,7 @@ def build_histograms(
             mc = sl(included, i * chunk_rows, chunk_rows)
             lc = sl(leaf_id, i * chunk_rows, chunk_rows)
             slot = table_lookup(lc, slot_of_leaf)                  # [R]
-            w = weight_channels(gc, hc, mc, hilo)                  # [R, ch]
+            w = weight_channels(gc, hc, mc, exact)                 # [R, ch]
 
         slot_onehot = (slot[:, None] == iota_slots)               # [R, S] bool
         rhs = (slot_onehot[:, :, None].astype(w.dtype) * w[:, None, :]
@@ -532,10 +471,10 @@ def build_histograms(
             onehot, rhs,
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-            # f32 mode: HIGHEST decomposes each f32 operand into bf16
+            # exact mode: HIGHEST decomposes each f32 operand into bf16
             # triples, so every one-hot x weight product is EXACT (the
-            # one-hot side is 0/1); bf16 modes use the default fast path
-            precision=(jax.lax.Precision.HIGHEST if hilo == "f32" else None),
+            # one-hot side is 0/1); bf16 hi/lo uses the default fast path
+            precision=(jax.lax.Precision.HIGHEST if exact else None),
         )                                                         # [F, B, S*ch]
         return part
 
@@ -584,10 +523,10 @@ def build_histograms(
 
     if raw_output:
         return acc, comp
-    return finalize_histograms(acc, num_slots, hilo)
+    return finalize_histograms(acc, num_slots, exact)
 
 
-def finalize_histograms(acc: jnp.ndarray, num_slots: int, hilo
+def finalize_histograms(acc: jnp.ndarray, num_slots: int, exact: bool
                         ) -> jnp.ndarray:
     """[F, B, S*ch] f32 fold state -> [S, F, B, 3] (sum_g, sum_h, count).
 
@@ -598,12 +537,12 @@ def finalize_histograms(acc: jnp.ndarray, num_slots: int, hilo
     ch = acc.shape[-1] // num_slots
     acc = acc.reshape(num_features, num_bins_padded, num_slots, ch)
     acc = jnp.transpose(acc, (2, 0, 1, 3))                        # [S, F, B, ch]
-    return combine_channels(acc, hilo)                            # [S, F, B, 3]
+    return combine_channels(acc, exact)                           # [S, F, B, 3]
 
 
 def histogram_cost_report(n_rows: int, num_features: int,
                           num_bins_padded: int, num_slots: int,
-                          chunk_rows: int, hilo=True, dtype=None,
+                          chunk_rows: int, exact: bool = False, dtype=None,
                           site: str = None) -> dict:
     """Compile-time cost probe of the streaming histogram kernel at one
     shape class: lower+compile a standalone jitted ``build_histograms`` on
@@ -624,7 +563,7 @@ def histogram_cost_report(n_rows: int, num_features: int,
     def run(X, g, h, inc, lid, sol):
         return build_histograms(X, g, h, inc, lid, sol, num_slots=num_slots,
                                 num_bins_padded=num_bins_padded,
-                                chunk_rows=chunk_rows, hilo=hilo)
+                                chunk_rows=chunk_rows, exact=exact)
 
     site = site or f"histogram.stream.s{num_slots}"
     dims = dict(rows=int(n_rows), features=int(num_features),

@@ -41,9 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram import (NUM_CHANNELS, NUM_CHANNELS_FAST, code_bytes,
-                        combine_channels, pack_rows, slot_from_position,
-                        slot_position_base, table_lookup, unpack_weights)
+from .histogram import (NUM_CHANNELS, code_bytes, combine_channels,
+                        slot_from_position, table_lookup, unpack_weights)
 
 _INTERPRET = False   # flipped by tests on CPU
 
@@ -114,7 +113,7 @@ def _hist_kernel(n_active_ref,        # SMEM scalar prefetch: [1] i32
 def hist_pallas(
     Xb8: jnp.ndarray,          # [N, F*cb] u8 bin-code bytes
     slot: jnp.ndarray,         # [N] i32 histogram slot per row, -1 = skip
-    w: jnp.ndarray,            # [N, ch] bf16 weight channels
+    w: jnp.ndarray,            # [N, 5] bf16 hi/lo weight channels
     num_slots: int,
     num_bins: int,
     num_features: int,
@@ -129,7 +128,7 @@ def hist_pallas(
     """
     N, ncb = Xb8.shape
     ch = w.shape[1]
-    hilo = ch == NUM_CHANNELS
+    assert ch == NUM_CHANNELS, ch
     SC = num_slots * ch
     # f32 sublane-tile alignment for the accumulator block (see the
     # kernel's rhs comment): 125 -> 128 at the default S=25 x ch=5
@@ -168,7 +167,7 @@ def hist_pallas(
 
     acc = out[:SC].reshape(num_slots, ch, num_features, num_bins)
     acc = jnp.transpose(acc, (0, 2, 3, 1))                        # [S, F, B, ch]
-    return combine_channels(acc, hilo)                            # [S, F, B, 3]
+    return combine_channels(acc, exact=False)                     # [S, F, B, 3]
 
 
 def build_histograms_pallas(
@@ -181,18 +180,17 @@ def build_histograms_pallas(
     num_slots: int,
     num_bins_padded: int,
     chunk_rows: int,
-    row_idx: jnp.ndarray = None,
+    row_idx: jnp.ndarray = None,       # [N] i32: a COMPACTED pass, the rows
+                                       # grouped by pending slot; needs
+                                       # n_active and slot_counts
     n_active: jnp.ndarray = None,
-    hilo: bool = True,
-    slot_counts: jnp.ndarray = None,   # [S] i32: row_idx is slot-grouped —
+    slot_counts: jnp.ndarray = None,   # [S] i32 rows per slot of row_idx:
                                        # slots derive from position (no
                                        # leaf_id/slot_of_leaf row gathers)
-    slot_starts: jnp.ndarray = None,   # [S] i32: row_idx is a LEAF-CONTIGUOUS
-                                       # permutation (grower incremental
-                                       # partition) — positions remap through
-                                       # slot_position_base before the gather
-    packed: jnp.ndarray = None,        # pre-built pack_rows output (amortize
-                                       # the O(N) pack across a tree's waves)
+    packed: jnp.ndarray = None,        # REQUIRED: pack_rows(X, grad, hess,
+                                       # included, exact=False), built once a
+                                       # tree; the kernel reads the code bytes
+                                       # and the bf16 hi/lo weights from it
     max_rows: int = 0,                 # STATIC cap on n_active (0 = N). The
                                        # grower's adaptive cond guarantees
                                        # n_active < N/4 on this path, so the
@@ -200,20 +198,22 @@ def build_histograms_pallas(
                                        # shrink 4x — skipped grid steps are
                                        # not free at a 10.5M-row full grid.
 ) -> jnp.ndarray:
-    """Drop-in replacement for ops.histogram.build_histograms backed by the
-    Pallas kernel (same signature/semantics — the GPU_DEBUG_COMPARE analog
-    lives in tests/test_pallas_hist.py).
+    """ops.histogram.build_histograms backed by the Pallas kernel, bf16 hi/lo
+    weights only (same semantics — the GPU_DEBUG_COMPARE analog lives in
+    tests/test_pallas_hist.py). The two are called alike: ``grad``, ``hess``
+    and ``included`` stay in the signature, but this kernel reads them from
+    ``packed`` in every pass.
 
     With ``max_rows`` set, active rows beyond it are silently dropped — the
     caller must guarantee n_active <= max_rows."""
     N, F = X.shape
     cb = code_bytes(X.dtype)
-    ch = NUM_CHANNELS if hilo else NUM_CHANNELS_FAST
-    if packed is None:
-        packed, _ = pack_rows(X, grad, hess, included, hilo)  # [N, ncb+2ch] u8
+    assert packed is not None, "the Pallas kernel reads the packed rows"
     ncb = F * cb
     if row_idx is not None:
-        # pending-prefix gather, bounded to active chunks only — ONE random
+        assert slot_counts is not None, \
+            "a compacted pass reads a slot-grouped row_idx: slot_counts"
+        # pending-rows gather, bounded to active chunks only — ONE random
         # row gather from the packed array per active row (vs four separate
         # X/g/h/inc gathers; a random HBM row access costs the same ~30 ns
         # regardless of row width). Gather granularity (32k rows) is
@@ -228,26 +228,14 @@ def build_histograms_pallas(
             Rg //= 2
         n_chunks_active = jnp.minimum((n_active + Rg - 1) // Rg, cap // Rg)
         iota_r = jnp.arange(Rg, dtype=jnp.int32)
-        slot_cum = (jnp.cumsum(slot_counts) if slot_counts is not None
-                    else None)
+        slot_cum = jnp.cumsum(slot_counts)
 
         def gather_chunk(c, bufs):
             pb, sb = bufs
             sl = c * Rg
             pos = sl + iota_r
-            if slot_cum is not None:
-                raw = slot_from_position(pos, slot_cum)
-                if slot_starts is not None:
-                    # leaf-contiguous permutation (incremental partition):
-                    # positions translate into the pending segments
-                    src = pos + slot_position_base(raw, slot_cum, slot_starts)
-                    idx = jnp.take(row_idx,
-                                   jnp.clip(src, 0, row_idx.shape[0] - 1))
-                else:
-                    idx = jax.lax.dynamic_slice_in_dim(row_idx, sl, Rg)
-            else:
-                idx = jax.lax.dynamic_slice_in_dim(row_idx, sl, Rg)
-                raw = table_lookup(jnp.take(leaf_id, idx), slot_of_leaf)
+            raw = slot_from_position(pos, slot_cum)
+            idx = jax.lax.dynamic_slice_in_dim(row_idx, sl, Rg)
             chunk_slot = jnp.where(pos < n_active, raw, -1)
             upd = jax.lax.dynamic_update_slice_in_dim
             return (upd(pb, jnp.take(packed, idx, axis=0), sl, 0),
@@ -266,7 +254,7 @@ def build_histograms_pallas(
         n_active = None
         n_rows = N
     Xb8 = packed[:, :ncb]
-    w = unpack_weights(packed[:, ncb:], ch)
+    w = unpack_weights(packed[:, ncb:], NUM_CHANNELS)
     return hist_pallas(Xb8, slot, w, num_slots, num_bins_padded,
                        num_features=F, cb=cb,
                        chunk_rows=min(chunk_rows, n_rows),

@@ -53,13 +53,12 @@ class SplitCandidates(NamedTuple):
     leaf-id order (grower.py wave step 1, a cumsum over `needs_hist`), and
     three consumers rely on that one ordering staying consistent — the
     grower's `leaf_of_slot` scatter/gather pair, the compacted histogram
-    pass's position->slot derivation (`slot_from_position` /
-    `slot_position_base`, which index the SAME per-leaf segment tables the
-    incremental partition maintains), and the scan here, whose outputs are
-    written back through `scan_leaves = leaf_of_slot ++ siblings`. The scan
-    itself is row-order-independent (it reads finished histograms), so the
-    incremental partition changes nothing below this line — but a re-order
-    of slot assignment would silently mis-route all three.
+    pass's position->slot derivation (`slot_from_position` over the running
+    sum of the per-slot row counts of `_rows_by_slot`'s order), and the scan
+    here, whose outputs are written back through `scan_leaves = leaf_of_slot
+    ++ siblings`. The scan itself is row-order-independent (it reads
+    finished histograms) — but a re-order of slot assignment would silently
+    mis-route all three.
     """
     gain: jnp.ndarray          # f32, improvement over parent (-inf if none)
     feature: jnp.ndarray       # i32 inner feature index (GLOBAL)

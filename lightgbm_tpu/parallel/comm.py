@@ -24,15 +24,14 @@ instead of socket/MPI calls — SURVEY.md §2.6):
 Each Comm object is a *static* bundle of callables closed over the mesh axis
 name; `grow_tree` (grower.py) calls them at trace time inside `shard_map`.
 
-Incremental partition under row-sharded strategies (data/voting): the
-grower's leaf-contiguous row permutation (GrowState.perm/seg_start/
-seg_rows) is SHARD-LOCAL state over this device's row block — exactly like
-`leaf_id`. No collective ever touches it: segment counts, the counting-sort
-update, and the compacted gather all run on local rows, while the reference
-keeps one DataPartition per machine over its local partition the same way
-(data_parallel_tree_learner.cpp uses the local data_partition_ for
-histogram construction). Split decisions arrive replicated (the all-gather
-argmax below), so every shard re-partitions consistently.
+Per-row state under row-sharded strategies (data/voting): `leaf_id` and the
+row's next-wave slot (GrowState.slot_row) are SHARD-LOCAL over this device's
+row block. No collective ever touches them: a compacted wave's sort, slot
+counts and packed-row gather all run on local rows, and each shard decides
+stream-or-compact for itself, as the reference keeps one DataPartition per
+machine over its local partition (data_parallel_tree_learner.cpp uses the
+local data_partition_ for histogram construction). Split decisions arrive
+replicated (the all-gather argmax below), so every shard routes consistently.
 """
 from __future__ import annotations
 

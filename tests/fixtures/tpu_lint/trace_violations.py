@@ -1,8 +1,8 @@
 """Planted trace-contract violations, exec'd via ``--trace --load``.
 
 One deliberately-broken (entry, shape_class) cell per check kind —
-forbidden-primitive, required-collective, dtype, donation — proving the
-trace tier FAILS when a contract is violated (the shipped tree passes
+forbidden-primitive, required-collective, dtype, donation, row-pass — proving
+the trace tier FAILS when a contract is violated (the shipped tree passes
 clean, so without these the tier's teeth would be untested). Contract ids
 use the TX9x range so they can never collide with shipped T0xx ids.
 """
@@ -65,3 +65,28 @@ def _dropped_donation():
 
 contract("TX93", "planted donation violation", ENTRY,
          checks=[C.DonationEffective()], targets=[Target("dropped_donation")])
+
+
+WAVE_ROWS = 64
+
+
+@program_builder(ENTRY, "scattered_wave")
+def _scattered_wave():
+    # a wave loop that re-partitions a row permutation through a row-sized
+    # scatter in EVERY iteration: what contract T001 (the same check, on
+    # grower.wave_body) forbids, and what the carried leaf partition did
+    # until PR 32 deleted it. On the TPU such a scatter hides a sort.
+    def wave(carry):
+        i, perm = carry
+        dest = (perm + i) % WAVE_ROWS
+        return i + 1, jnp.zeros_like(perm).at[dest].set(perm)
+
+    jx = jax.make_jaxpr(lambda perm: jax.lax.while_loop(
+        lambda c: c[0] < 3, wave, (jnp.asarray(0, jnp.int32), perm)))(
+        jnp.arange(WAVE_ROWS, dtype=jnp.int32))
+    return TracedProgram(ENTRY, "scattered_wave", jx, rows=WAVE_ROWS)
+
+
+contract("TX94", "planted row-pass violation (T001's check)", ENTRY,
+         checks=[C.RowPassesInLoops(max_sorts=1)],
+         targets=[Target("scattered_wave")])

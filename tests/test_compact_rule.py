@@ -21,28 +21,28 @@ from lightgbm_tpu.ops.histogram import (PALLAS_COMPACT_FRAC_CAP,
 ROWS, SLOTS = 1 << 20, 25
 
 
-def _rule(features, bins=256, rows=ROWS, code_mode="u8", hilo=True,
+def _rule(features, bins=256, rows=ROWS, code_mode="u8", exact=False,
           row_bytes=None):
     if row_bytes is None:
-        row_bytes = packed_row_bytes(features, code_mode, hilo)
+        row_bytes = packed_row_bytes(features, code_mode, exact)
     return compact_break_even(rows=rows, features=features, bins_padded=bins,
-                              row_bytes=row_bytes, num_slots=SLOTS, hilo=hilo)
+                              row_bytes=row_bytes, num_slots=SLOTS,
+                              exact=exact)
 
 
 WIDTHS = (1, 2, 4, 10, 28, 67, 137, 256, 700, 2000, 8000)
 
 
 @pytest.mark.parametrize("bins", [16, 64, 256])
-@pytest.mark.parametrize("hilo", [True, False, "f32"],
-                         ids=["hilo", "bf16", "f32"])
-def test_rule_rises_with_the_width_inside_the_unit_interval(bins, hilo):
+@pytest.mark.parametrize("exact", [False, True], ids=["hilo", "f32"])
+def test_rule_rises_with_the_width_inside_the_unit_interval(bins, exact):
     """At one packed row the threshold only rises with the build's width:
     a wasted streamed row is dearer the wider the table."""
-    got = [_rule(f, bins, hilo=hilo, row_bytes=77) for f in WIDTHS]
+    got = [_rule(f, bins, exact=exact, row_bytes=77) for f in WIDTHS]
     assert all(0.0 < v <= 1.0 for v in got)
     assert got == sorted(got) and got[-1] > got[0]
     # and with the row that goes with each width it stays in (0, 1]
-    assert all(0.0 < _rule(f, bins, hilo=hilo) <= 1.0 for f in WIDTHS)
+    assert all(0.0 < _rule(f, bins, exact=exact) <= 1.0 for f in WIDTHS)
 
 
 def test_rule_follows_the_gathers_steps_not_a_line():
@@ -84,11 +84,9 @@ def test_rule_reads_the_sort_and_the_weight_mode():
         (1 << 24) + 1, 25) and not sort_is_one_word(1 << 20, 128)
     # pairs cost three one-word sorts: a compacted pass has to save more
     assert _rule(10, rows=(1 << 24) + 256) < _rule(10, rows=1 << 24)
-    # f32 columns at Precision.HIGHEST, or three plain bf16 columns a slot:
-    # a dearer matmul in both arms over the same gather, so compaction
-    # pays earlier
-    assert (_rule(10, hilo="f32", row_bytes=20)
-            > _rule(10, hilo=False, row_bytes=20) > _rule(10, row_bytes=20))
+    # f32 columns at Precision.HIGHEST: a dearer matmul in both arms over
+    # the same gather, so compaction pays earlier
+    assert _rule(10, exact=True, row_bytes=20) > _rule(10, row_bytes=20)
     # a narrower packed row (two codes a byte) is a cheaper gather
     assert _rule(28, bins=16, code_mode="u4") > _rule(28, bins=16)
 
@@ -102,7 +100,7 @@ def test_rule_reads_the_sort_and_the_weight_mode():
          "under-the-cap"])
 def test_resolve_auto_explicit_and_the_pallas_cap(requested, kernel, want):
     shape = dict(rows=ROWS, features=67, bins_padded=256, row_bytes=77,
-                 num_slots=SLOTS, hilo=True)
+                 num_slots=SLOTS, exact=False)
     got = resolve_compact_frac(requested, kernel, **shape)
     assert got == (compact_break_even(**shape) if want == "rule" else want)
 
@@ -130,21 +128,20 @@ def clean_registry():
     (dict(max_bin=255), 12),
     (dict(max_bin=15), 6),                          # u4: two codes a byte
     (dict(max_bin=63), 9),                          # u6: four codes, 3 bytes
-    (dict(max_bin=255, tpu_hist_f64=True), 12),
-    (dict(max_bin=255, tpu_hist_hilo=False), 12)],
-    ids=["u8", "u4", "u6", "f64", "bf16"])
+    (dict(max_bin=255, tpu_hist_f64=True), 12)],
+    ids=["u8", "u4", "u6", "f64"])
 def test_booster_resolves_auto_from_its_own_shapes(clean_registry, params,
                                                    code_bytes):
     g = _booster(params)._gbdt
     spec = g.spec
     f64 = bool(params.get("tpu_hist_f64"))
-    channels = 5 if (spec.hist_hilo and not f64) else 3
+    assert spec.hist_f64 == f64
+    channels = 3 if f64 else 5
     want = compact_break_even(
         rows=g.num_data_padded, features=spec.num_features,
         bins_padded=spec.num_bins_padded,
         row_bytes=code_bytes + channels * (4 if f64 else 2),
-        num_slots=spec.hist_slots,
-        hilo="f32" if f64 else spec.hist_hilo)
+        num_slots=spec.hist_slots, exact=f64)
     assert spec.compact_frac == want and 0.0 < want <= 1.0
     # published where hist_pass_shape's readings are
     assert obs.snapshot()["gauges"]["hist.compact_frac"] == want
@@ -183,6 +180,6 @@ def test_bundled_table_is_sized_in_bundle_space(clean_registry):
     assert cols < f
     want = compact_break_even(
         rows=g.num_data_padded, features=cols, bins_padded=g.spec.hist_bins,
-        row_bytes=packed_row_bytes(cols, g.spec.code_mode, True),
+        row_bytes=packed_row_bytes(cols, g.spec.code_mode, False),
         num_slots=g.spec.hist_slots)
     assert g.spec.compact_frac == want

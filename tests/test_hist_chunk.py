@@ -13,7 +13,7 @@ import pytest
 
 from lightgbm_tpu.ops import histogram
 from lightgbm_tpu.ops.histogram import (build_histograms, hist_pass_shape,
-                                        num_channels)
+                                        num_channels, pack_rows)
 
 N, F, B, S = 4096, 96, 64, 6
 
@@ -33,9 +33,9 @@ def _table(seed=5, integer=False):
     return X, g * inc, h * inc, inc, leaf_id, slot_of_leaf
 
 
-def _compact_args(leaf_id):
-    """A slot-grouped row index over the pending rows, as the grower's
-    compacted arm builds it."""
+def _compact_args(X, g, h, inc, leaf_id, exact):
+    """A slot-grouped row index over the pending rows and the packed rows,
+    as the grower's compacted arm gets them."""
     lid = np.asarray(leaf_id)
     pending = np.flatnonzero(lid < S)
     order = pending[np.argsort(lid[pending], kind="stable")].astype(np.int32)
@@ -44,23 +44,24 @@ def _compact_args(leaf_id):
     counts = np.bincount(lid[pending], minlength=S).astype(np.int32)
     return dict(row_idx=jnp.asarray(row_idx),
                 n_active=jnp.asarray(len(order), jnp.int32),
-                slot_counts=jnp.asarray(counts))
+                slot_counts=jnp.asarray(counts),
+                packed=pack_rows(X, g, h, inc, exact)[0])
 
 
-@pytest.mark.parametrize("hilo,compensated", [(True, False), (False, False),
-                                              ("f32", False), ("f32", True)],
-                         ids=["hilo", "bf16", "f32", "f32-kahan"])
+@pytest.mark.parametrize("exact,compensated", [(False, False), (True, False),
+                                               (True, True)],
+                         ids=["hilo", "f32", "f32-kahan"])
 @pytest.mark.parametrize("arm", ["stream", "compact"])
-def test_a_narrower_chunk_sums_the_same_histogram(arm, hilo, compensated):
+def test_a_narrower_chunk_sums_the_same_histogram(arm, exact, compensated):
     """256 rows a chunk against 4,096 (one chunk): the counts to the unit,
     the f32 mode's sums of integer weights to the bit, g and h of real
-    weights to the rounding of their mode."""
-    integer = hilo == "f32"
+    weights to the rounding of the hi/lo mode."""
+    integer = exact
     X, g, h, inc, leaf_id, slot_of_leaf = _table(integer=integer)
-    kw = dict(num_slots=S, num_bins_padded=B, hilo=hilo,
+    kw = dict(num_slots=S, num_bins_padded=B, exact=exact,
               compensated=compensated)
     if arm == "compact":
-        kw.update(_compact_args(leaf_id))
+        kw.update(_compact_args(X, g, h, inc, leaf_id, exact))
     args = (X, g, h, inc, leaf_id, slot_of_leaf)
     one = np.asarray(build_histograms(*args, chunk_rows=N, **kw))
     narrow = np.asarray(build_histograms(*args, chunk_rows=256, **kw))
@@ -69,18 +70,17 @@ def test_a_narrower_chunk_sums_the_same_histogram(arm, hilo, compensated):
     if integer:
         np.testing.assert_array_equal(one, narrow)
     else:
-        np.testing.assert_allclose(one, narrow, rtol=0,
-                                   atol=2e-2 if hilo is False else 2e-4)
+        np.testing.assert_allclose(one, narrow, rtol=0, atol=2e-4)
 
 
-@pytest.mark.parametrize("hilo,compensated", [(True, False), ("f32", True)],
+@pytest.mark.parametrize("exact,compensated", [(False, False), (True, True)],
                          ids=["hilo", "f32-kahan"])
-def test_shard_legs_carry_the_accumulator_at_a_narrow_chunk(hilo, compensated):
+def test_shard_legs_carry_the_accumulator_at_a_narrow_chunk(exact, compensated):
     """Two streamed shard legs (``acc_init``/``raw_output``, ops/stream.py)
     against one resident pass over the same rows at the same chunk: the
     same chunk partials folded in the same order, so equal to the bit."""
     X, g, h, inc, leaf_id, slot_of_leaf = _table()
-    kw = dict(num_slots=S, num_bins_padded=B, chunk_rows=256, hilo=hilo,
+    kw = dict(num_slots=S, num_bins_padded=B, chunk_rows=256, exact=exact,
               compensated=compensated)
     whole, whole_comp = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf,
                                          raw_output=True, **kw)
@@ -92,7 +92,7 @@ def test_shard_legs_carry_the_accumulator_at_a_narrow_chunk(hilo, compensated):
             X[sl], g[sl], h[sl], inc[sl], leaf_id[sl], slot_of_leaf,
             acc_init=acc, comp_init=comp if compensated else None,
             raw_output=True, **kw)
-        assert acc.shape == (F, B, S * num_channels(hilo))
+        assert acc.shape == (F, B, S * num_channels(exact))
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(acc))
     if compensated:
         np.testing.assert_array_equal(np.asarray(whole_comp),

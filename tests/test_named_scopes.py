@@ -80,26 +80,18 @@ _IN_COMPACT_ARM = re.compile(r'op_name="[^"]*/while/body/[^"]*cond/[^"]*'
                              r'wave\.hist\.compact/wave\.partition/')
 
 
-@pytest.mark.parametrize("carried", [False, True])
-def test_row_sized_sorts_of_the_compiled_step(carried):
-    """The default step as compiled: exactly ONE row-sized sort, under
+def test_row_sized_sorts_of_the_compiled_step():
+    """The step as compiled: exactly ONE row-sized sort, under
     ``wave.partition`` inside the compacted arm of the wave's ``cond``, and
-    no row-sized reduce-window (a cumsum) or scatter anywhere. The carried
-    arm (``tpu_incremental_partition=true``) has no sort of its own here,
-    and the row-sized scatter and cumsums that the TPU's compiler turns
-    into a sort and reduce-windows in every wave (the v5e case below)."""
-    fn, args = _step_and_args(tpu_incremental_partition=carried)
+    no row-sized reduce-window (a cumsum) or scatter anywhere: those are
+    what the TPU's compiler turns into a sort and reduce-windows in every
+    wave (the v5e case below)."""
+    fn, args = _step_and_args()
     hlo = fn.lower(*args).compile().as_text()
     sorts = _row_sized(hlo, 2560, "sort")            # 2500 rows, padded
-    if carried:
-        assert not sorts
-        scatters = _row_sized(hlo, 2560, "scatter")
-        assert scatters and all("/wave.partition/" in ln for ln in scatters)
-        assert not any(_IN_COMPACT_ARM.search(ln) for ln in scatters)
-    else:
-        assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
-        assert not _row_sized(hlo, 2560, "reduce-window")
-        assert not _row_sized(hlo, 2560, "scatter")
+    assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
+    assert not _row_sized(hlo, 2560, "reduce-window")
+    assert not _row_sized(hlo, 2560, "scatter")
 
 
 def test_streamed_legs_share_the_split_and_route_scopes():
@@ -179,8 +171,8 @@ def test_v5e_fusions_carry_the_scopes(v5e_hlo):
 def test_v5e_step_sorts_rows_once_in_the_compacted_arm(v5e_hlo):
     """What no jaxpr walk can see: the sorts the TPU's compiler itself puts
     in (it expands a row-sized scatter into a sort of (index, value) pairs:
-    the carried arm paid one in every wave, PERF.md PR 27). The default
-    step compiled for the v5e holds exactly one row-sized sort, the
+    the carried partition, deleted in PR 32, paid one in every wave,
+    PERF.md PR 27). The step compiled for the v5e holds exactly one row-sized sort, the
     compacted arm's own, and no row-sized reduce-window or scatter."""
     sorts = _row_sized(v5e_hlo, 2560, "sort")
     assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts

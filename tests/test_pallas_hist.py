@@ -1,13 +1,18 @@
 """Pallas histogram kernel vs the XLA one-hot matmul — the analog of the
 reference's GPU_DEBUG_COMPARE cross-check (gpu_tree_learner.cpp:1018-1043),
-run in Pallas interpret mode on the CPU test backend."""
+run in Pallas interpret mode on the CPU test backend.
+
+The reference of every COMPACTED pass here is the STREAMED xla pass over
+the same pending leaves: the independent path (no row index, no packed
+rows, no gather), not a second compacted layout."""
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
+from lightgbm_tpu.grower import _slot_grouped_rows
 from lightgbm_tpu.ops import pallas_histogram as ph
-from lightgbm_tpu.ops.histogram import build_histograms, compact_rows
+from lightgbm_tpu.ops.histogram import build_histograms, pack_rows
 
 
 @pytest.fixture(autouse=True)
@@ -26,16 +31,17 @@ def _data(n=4096, f=6, bins=32, leaves=8, seed=0):
             jnp.asarray(leaf_id))
 
 
-def test_pallas_matches_xla_full_pass():
-    X, g, h, inc, leaf_id = _data()
-    S, B = 4, 32
-    slot_of_leaf = jnp.full(9, -1, jnp.int32).at[jnp.arange(4)].set(
-        jnp.arange(4))
-    ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                           num_bins_padded=B, chunk_rows=1024)
-    out = ph.build_histograms_pallas(X, g, h, inc, leaf_id, slot_of_leaf,
-                                     num_slots=S, num_bins_padded=B,
-                                     chunk_rows=1024)
+def _compacted(X, g, h, inc, leaf_id, slot_of_leaf, num_slots):
+    """What the grower hands a compacted pass: the rows grouped by pending
+    slot by its own one sort, the pending count, the rows a slot, and the
+    packed rows (bf16 hi/lo weights, plain byte codes)."""
+    row_idx, counts = _slot_grouped_rows(slot_of_leaf[leaf_id], num_slots)
+    packed, _ = pack_rows(X, g, h, inc, exact=False)
+    return dict(row_idx=row_idx, n_active=jnp.sum(counts),
+                slot_counts=counts, packed=packed)
+
+
+def _assert_same_histograms(out, ref):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-4)
     # count channel must be exact
@@ -43,52 +49,51 @@ def test_pallas_matches_xla_full_pass():
                                   np.asarray(ref[..., 2]))
 
 
+def test_pallas_matches_xla_full_pass():
+    X, g, h, inc, leaf_id = _data()
+    S, B = 4, 32
+    slot_of_leaf = jnp.full(9, -1, jnp.int32).at[jnp.arange(4)].set(
+        jnp.arange(4))
+    ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
+                           num_bins_padded=B, chunk_rows=1024)
+    packed, _ = pack_rows(X, g, h, inc, exact=False)
+    out = ph.build_histograms_pallas(X, g, h, inc, leaf_id, slot_of_leaf,
+                                     num_slots=S, num_bins_padded=B,
+                                     chunk_rows=1024, packed=packed)
+    _assert_same_histograms(out, ref)
+
+
 def test_pallas_matches_xla_compacted():
     X, g, h, inc, leaf_id = _data(seed=2)
     S, B = 4, 32
     # only leaves 1 and 3 pending -> ~1/4 of rows active
     slot_of_leaf = jnp.full(9, -1, jnp.int32).at[1].set(0).at[3].set(1)
-    row_idx, n_active = compact_rows(leaf_id, slot_of_leaf)
     ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                           num_bins_padded=B, chunk_rows=1024,
-                           row_idx=row_idx, n_active=n_active)
-    out = ph.build_histograms_pallas(X, g, h, inc, leaf_id, slot_of_leaf,
-                                     num_slots=S, num_bins_padded=B,
-                                     chunk_rows=1024, row_idx=row_idx,
-                                     n_active=n_active)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(out[..., 2]),
-                                  np.asarray(ref[..., 2]))
+                           num_bins_padded=B, chunk_rows=1024)
+    out = ph.build_histograms_pallas(
+        X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S, num_bins_padded=B,
+        chunk_rows=1024, **_compacted(X, g, h, inc, leaf_id, slot_of_leaf, S))
+    _assert_same_histograms(out, ref)
 
 
 def test_slot_grouped_position_slots_match():
-    """slot_counts path: rows pre-sorted by slot, slots derived from position
-    — must equal the per-row slot-gather path in BOTH kernels."""
+    """The compacted layout: rows grouped by slot, a position's slot from the
+    running sum of the rows a slot — must equal the streamed pass, whose
+    slots come from a per-row lookup of the leaf, in BOTH kernels."""
     X, g, h, inc, leaf_id = _data(seed=7)
     S, B = 4, 32
     slot_of_leaf = jnp.full(9, -1, jnp.int32).at[1].set(0).at[3].set(1).at[5].set(2)
-    slot_row = slot_of_leaf[leaf_id]
-    n_active = jnp.sum((slot_row >= 0).astype(jnp.int32))
-    key = jnp.where(slot_row >= 0, slot_row, jnp.int32(2 ** 30))
-    row_idx = jnp.argsort(key, stable=True).astype(jnp.int32)
-    counts = jnp.sum((slot_row[:, None] == jnp.arange(S)[None, :])
-                     .astype(jnp.int32), axis=0)
+    compacted = _compacted(X, g, h, inc, leaf_id, slot_of_leaf, S)
     ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                           num_bins_padded=B, chunk_rows=1024,
-                           row_idx=row_idx, n_active=n_active)
+                           num_bins_padded=B, chunk_rows=1024)
     grouped = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf,
                                num_slots=S, num_bins_padded=B,
-                               chunk_rows=1024, row_idx=row_idx,
-                               n_active=n_active, slot_counts=counts)
-    np.testing.assert_allclose(np.asarray(grouped), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
+                               chunk_rows=1024, **compacted)
+    _assert_same_histograms(grouped, ref)
     grouped_pl = ph.build_histograms_pallas(
         X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S, num_bins_padded=B,
-        chunk_rows=1024, row_idx=row_idx, n_active=n_active,
-        slot_counts=counts)
-    np.testing.assert_allclose(np.asarray(grouped_pl), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
+        chunk_rows=1024, **compacted)
+    _assert_same_histograms(grouped_pl, ref)
 
 
 @pytest.mark.slow
@@ -112,37 +117,14 @@ def test_train_with_pallas_kernel_matches_xla():
     np.testing.assert_allclose(m_mx.predict(X), p_x, rtol=1e-4, atol=1e-5)
 
 
-def test_fast_channels_close_to_hilo():
-    """tpu_hist_hilo=false (3 bf16 channels) stays close to the hi/lo sums —
-    the GPU reference's accepted-precision-tradeoff mode."""
-    X, g, h, inc, leaf_id = _data(seed=5)
-    S, B = 4, 32
-    slot_of_leaf = jnp.full(9, -1, jnp.int32).at[jnp.arange(4)].set(
-        jnp.arange(4))
-    full = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                            num_bins_padded=B, chunk_rows=1024)
-    fast = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                            num_bins_padded=B, chunk_rows=1024, hilo=False)
-    # counts exact; g/h within bf16 rounding of the summands
-    np.testing.assert_array_equal(np.asarray(fast[..., 2]),
-                                  np.asarray(full[..., 2]))
-    denom = np.abs(np.asarray(full[..., :2])) + 1.0
-    rel = np.abs(np.asarray(fast[..., :2]) - np.asarray(full[..., :2])) / denom
-    assert rel.max() < 0.05, rel.max()
-    fast_pl = ph.build_histograms_pallas(
-        X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S, num_bins_padded=B,
-        chunk_rows=1024, hilo=False)
-    np.testing.assert_allclose(np.asarray(fast_pl), np.asarray(fast),
-                               rtol=1e-5, atol=1e-4)
-
-
 def test_pallas_f32_precision_vs_f64():
     """hi/lo bf16 channels keep ~f32 accuracy on large sums."""
     X, g, h, inc, leaf_id = _data(n=8192, f=2, bins=8, leaves=1, seed=3)
     slot_of_leaf = jnp.zeros(2, jnp.int32)
+    packed, _ = pack_rows(X, g, h, inc, exact=False)
     out = ph.build_histograms_pallas(X, g, h, inc, leaf_id, slot_of_leaf,
                                      num_slots=1, num_bins_padded=8,
-                                     chunk_rows=2048)
+                                     chunk_rows=2048, packed=packed)
     Xn, gn, hn = np.asarray(X), np.asarray(g, np.float64), np.asarray(h, np.float64)
     incn = np.asarray(inc, np.float64)
     for f in range(2):
@@ -159,8 +141,7 @@ def test_uint16_codes_pack_roundtrip():
     """max_bin > 255 stores uint16 codes (2 little-endian bytes per code in
     the packed u8 rows) — both kernels and the pack/unpack helpers must
     agree with the uint8 semantics."""
-    from lightgbm_tpu.ops.histogram import (code_bytes, pack_rows,
-                                            unpack_codes)
+    from lightgbm_tpu.ops.histogram import code_bytes, unpack_codes
     rng = np.random.RandomState(11)
     n, f, bins = 2048, 5, 500
     X = jnp.asarray(rng.randint(0, bins, size=(n, f)).astype(np.uint16))
@@ -168,26 +149,27 @@ def test_uint16_codes_pack_roundtrip():
     h = jnp.asarray(np.abs(rng.randn(n)).astype(np.float32))
     inc = jnp.ones(n, jnp.float32)
     assert code_bytes(X.dtype) == 2
-    packed, ncb = pack_rows(X, g, h, inc, hilo=True)
+    packed, ncb = pack_rows(X, g, h, inc, exact=False)
     codes = unpack_codes(packed[:, :ncb], f, "u16")
     np.testing.assert_array_equal(np.asarray(codes), np.asarray(X, np.int32))
 
     leaf_id = jnp.asarray(rng.randint(0, 4, size=n).astype(np.int32))
     slot_of_leaf = jnp.full(5, -1, jnp.int32).at[1].set(0).at[3].set(1)
     B = 512
-    row_idx, n_active = compact_rows(leaf_id, slot_of_leaf)
+    compacted = _compacted(X, g, h, inc, leaf_id, slot_of_leaf, 2)
     ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=2,
                            num_bins_padded=B, chunk_rows=512)
     cmp = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=2,
-                           num_bins_padded=B, chunk_rows=512,
-                           row_idx=row_idx, n_active=n_active)
-    np.testing.assert_allclose(np.asarray(cmp), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
+                           num_bins_padded=B, chunk_rows=512, **compacted)
+    _assert_same_histograms(cmp, ref)
     out = ph.build_histograms_pallas(X, g, h, inc, leaf_id, slot_of_leaf,
                                      num_slots=2, num_bins_padded=B,
-                                     chunk_rows=512)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
+                                     chunk_rows=512, packed=packed)
+    _assert_same_histograms(out, ref)
+    out = ph.build_histograms_pallas(X, g, h, inc, leaf_id, slot_of_leaf,
+                                     num_slots=2, num_bins_padded=B,
+                                     chunk_rows=512, **compacted)
+    _assert_same_histograms(out, ref)
 
 
 def test_uint16_end_to_end_train():
@@ -210,62 +192,13 @@ def test_max_rows_capped_buffers_match():
     S, B = 4, 32
     # one small leaf pending -> well under n/4 active
     slot_of_leaf = jnp.full(9, -1, jnp.int32).at[2].set(0)
-    row_idx, n_active = compact_rows(leaf_id, slot_of_leaf)
     ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                           num_bins_padded=B, chunk_rows=512,
-                           row_idx=row_idx, n_active=n_active)
+                           num_bins_padded=B, chunk_rows=512)
     capped = ph.build_histograms_pallas(
         X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S, num_bins_padded=B,
-        chunk_rows=512, row_idx=row_idx, n_active=n_active,
-        max_rows=X.shape[0] // 4)
-    np.testing.assert_allclose(np.asarray(capped), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(capped[..., 2]),
-                                  np.asarray(ref[..., 2]))
-
-
-def test_slot_starts_permutation_matches_prefix_layout():
-    """Leaf-contiguous permutation + slot_starts (the grower's incremental
-    partition layout) must produce the same histograms as the legacy
-    slot-grouped prefix, through BOTH kernels."""
-    X, g, h, inc, leaf_id = _data(seed=5)
-    S, B = 4, 32
-    slot_of_leaf = jnp.full(9, -1, jnp.int32).at[jnp.arange(1, 5)].set(
-        jnp.arange(4))
-    # legacy: stable argsort prefix + per-slot counts
-    sr = slot_of_leaf[leaf_id]
-    key = jnp.where(sr >= 0, sr, jnp.int32(2 ** 30))
-    row_idx = jnp.argsort(key, stable=True).astype(jnp.int32)
-    counts = jnp.sum((sr[:, None] == jnp.arange(S)[None, :]).astype(
-        jnp.int32), axis=0)
-    n_active = jnp.sum((sr >= 0).astype(jnp.int32))
-    ref = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S,
-                           num_bins_padded=B, chunk_rows=1024,
-                           row_idx=row_idx, n_active=n_active,
-                           slot_counts=counts)
-    # incremental layout: rows grouped by leaf id (a valid leaf-contiguous
-    # permutation); pending leaves 1..4 serve slots 0..3
-    perm = jnp.argsort(leaf_id, stable=True).astype(jnp.int32)
-    cnts_leaf = np.bincount(np.asarray(leaf_id), minlength=9)
-    starts_leaf = np.zeros(9, np.int64)
-    starts_leaf[1:] = np.cumsum(cnts_leaf)[:-1]
-    slot_starts = jnp.asarray(starts_leaf[1:5].astype(np.int32))
-    slot_counts = jnp.asarray(cnts_leaf[1:5].astype(np.int32))
-    out_xla = build_histograms(X, g, h, inc, leaf_id, slot_of_leaf,
-                               num_slots=S, num_bins_padded=B,
-                               chunk_rows=1024, row_idx=perm,
-                               n_active=n_active, slot_counts=slot_counts,
-                               slot_starts=slot_starts)
-    np.testing.assert_array_equal(np.asarray(out_xla), np.asarray(ref))
-    out_pl = ph.build_histograms_pallas(
-        X, g, h, inc, leaf_id, slot_of_leaf, num_slots=S, num_bins_padded=B,
-        chunk_rows=1024, row_idx=perm, n_active=n_active,
-        slot_counts=slot_counts, slot_starts=slot_starts,
-        max_rows=X.shape[0])
-    np.testing.assert_allclose(np.asarray(out_pl), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(out_pl[..., 2]),
-                                  np.asarray(ref[..., 2]))
+        chunk_rows=512, max_rows=X.shape[0] // 4,
+        **_compacted(X, g, h, inc, leaf_id, slot_of_leaf, S))
+    _assert_same_histograms(capped, ref)
 
 
 def test_kernel_choice_is_a_function_of_config_only():

@@ -67,12 +67,11 @@ def test_r007_ignores_sorts_outside_while_loops(tmp_path):
 
 
 def test_r007_grower_compacted_arm_site_is_baseline_exempt():
-    """The grower's ONE sort — the compacted arm's slot-grouped row index,
-    the default since PR 28 — is the audited site: R007 sees it, the
-    committed baseline absorbs it, and nothing else in the wave loop sorts
-    (the carried arm, tpu_incremental_partition=true, calls no sort; the
-    one the TPU's compiler hides in its scatter is counted on the compiled
-    program in test_named_scopes.py)."""
+    """The grower's ONE sort — the compacted arm's slot-grouped row index —
+    is the audited site: R007 sees it, the committed baseline absorbs it,
+    and nothing else in the wave loop sorts (a sort the TPU's compiler
+    hides in a row-sized scatter is counted on the compiled program in
+    test_named_scopes.py)."""
     findings, err = lint_file(
         os.path.join(REPO, "lightgbm_tpu", "grower.py"),
         rel=os.path.join("lightgbm_tpu", "grower.py"))

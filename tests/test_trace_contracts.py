@@ -3,8 +3,8 @@
 The registry is the ONE implementation of the repo's jaxpr/HLO pins:
 contracts T001-T010 over the shipped entry points, with
 expect="violates" targets keeping every predicate demonstrably sensitive.
-The migrated wave-loop / EFB-routing pins live in their original test
-files (test_incremental_partition.py, test_efb_bundlespace.py) and
+The migrated wave-loop / EFB-routing pins live in their own test
+files (test_compacted_row_index.py, test_efb_bundlespace.py) and
 assert through this registry; here we cover the linear-fit pins added for
 the piecewise-linear leaves PR, the donation/collective/host-transfer
 contracts, the sensitivity machinery, and the CLI (--trace, --load,
@@ -91,8 +91,7 @@ def test_violates_targets_actually_violate():
     """The sensitivity arms really fail a check — otherwise evaluate()
     would have reported 'sensitivity lost' above, but assert the raw
     failures directly too."""
-    for cid, shape_class in [("T001", "serial_carried"),
-                             ("T002", "bundled_unpack")]:
+    for cid, shape_class in [("T002", "bundled_unpack")]:
         c, t, program = _cell(cid, shape_class)
         assert t.expect == "violates"
         assert evaluate_target(c, program), \
@@ -138,11 +137,17 @@ def test_hlo_alias_count_parses_nested_braces():
 # -------------------------------------------------- planted violations
 
 def test_planted_fixture_violations_fire():
-    """--load fixture: one violating cell per check kind, all four fire."""
+    """--load fixture: one violating cell per check kind, all five fire.
+    TX94 (a loop body with a row-sized scatter) is what keeps T001's check,
+    RowPassesInLoops, demonstrably sensitive: no shipped arm violates it
+    since the carried leaf partition was deleted."""
     import runpy
     runpy.run_path(FIXTURE, run_name="trace_fixture_test")
     expected = {"TX90": "forbidden-primitive", "TX91": "required-collective",
-                "TX92": "dtype", "TX93": "donation"}
+                "TX92": "dtype", "TX93": "donation", "TX94": "row-pass"}
+    assert any(isinstance(chk, C.RowPassesInLoops)
+               for chk in CONTRACTS["T001"].checks) and all(
+        t.expect == "clean" for t in CONTRACTS["T001"].targets)
     for cid, kind in expected.items():
         c = CONTRACTS[cid]
         t = c.targets[0]
@@ -162,13 +167,13 @@ def _run_trace_cli(*argv):
 
 def test_cli_planted_violations_gate_exit(tmp_path):
     r = _run_trace_cli("--load", FIXTURE,
-                       "--select", "TX90,TX91,TX92,TX93",
+                       "--select", "TX90,TX91,TX92,TX93,TX94",
                        "--format", "json")
     assert r.returncode == 1, r.stderr
     data = json.loads(r.stdout)
     kinds = {f["snippet"].rsplit(":", 1)[1] for f in data["findings"]}
     assert kinds == {"forbidden-primitive", "required-collective",
-                     "dtype", "donation"}
+                     "dtype", "donation", "row-pass"}
 
 
 def test_cli_update_baseline_and_stale_detection(tmp_path):
