@@ -111,7 +111,8 @@ def _wave_data8():
 # ----------------------------------------------------- routing.bundle_space
 
 def _routing_program(shape_class: str, efb_unpack: bool) -> TracedProgram:
-    from lightgbm_tpu.grower import BundleDecode
+    from lightgbm_tpu.grower import (BundleDecode, RouteTable,
+                                     empty_route_table)
     route = get_entry("routing.bundle_space")
     N, G, F, B, Bb = 64, 3, 8, 8, 16
     spec = _wave_spec(num_leaves=7, num_features=F, num_bins_padded=B,
@@ -123,12 +124,11 @@ def _routing_program(shape_class: str, efb_unpack: bool) -> TracedProgram:
         hi=jnp.full(F, 2, jnp.int32), off=jnp.zeros(F, jnp.int32),
         unpack_bin=jnp.zeros((F, B), jnp.int32),
         code_feat=jnp.zeros((G, Bb), jnp.int32))
-    n_cols = 6 if efb_unpack else 11
     jx = jax.make_jaxpr(
-        lambda X, lid, table, db: route(X, lid, table, None, spec,
-                                        bundle, db))(
+        lambda X, lid, keys, rows, db: route(
+            X, lid, RouteTable(keys, rows), None, spec, bundle, db))(
         jnp.zeros((N, G), jnp.uint8), jnp.zeros(N, jnp.int32),
-        jnp.zeros((8, n_cols), jnp.int32), jnp.zeros(F, jnp.int32))
+        *empty_route_table(spec, bundle), jnp.zeros(F, jnp.int32))
     return TracedProgram("routing.bundle_space", shape_class, jx)
 
 

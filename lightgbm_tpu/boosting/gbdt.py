@@ -32,7 +32,7 @@ from ..observability import costs as obs_costs
 from ..config import Config
 from ..dataset import ConstructedDataset, Metadata, MetadataDuckTyping
 from ..grower import (GrowerSpec, TreeArrays, WaveStats, grow_tree,
-                      wave_totals)
+                      route_table_cols, wave_totals)
 from ..ops.histogram import (hist_pass_shape, num_channels,
                              resolve_compact_frac, table_lookup)
 from ..parallel.comm import make_parallel_context
@@ -416,7 +416,7 @@ class GBDT:
             # a function of committed code only: auto is the XLA one-hot
             # matmul on every platform. Whether the mixed dispatch (Pallas
             # for compacted passes) should become auto's TPU choice is a
-            # measured decision (ROADMAP Queue 1 item 5); chip_smoke.py's
+            # measured decision (ROADMAP Queue 1 item 7); chip_smoke.py's
             # Pallas leg proves on every run that the kernel still compiles.
             hist_kernel = "xla"
             Log.debug("tpu_hist_kernel=auto resolved to %s", hist_kernel)
@@ -883,6 +883,10 @@ class GBDT:
             * (4 if self.spec.hist_f64 else 2))
         reg.gauge("hist.acc_bytes").set(self._hist_acc_bytes)
         reg.gauge("hist.compact_frac").set(self.spec.compact_frac)
+        # the routing pass's one-hot is booster.hist_slots wide, and every
+        # row reads this many table columns through it
+        reg.gauge("route.table_cols").set(
+            route_table_cols(self.spec, self.bundle))
         obs.event("hist_pass_shape", rule=_shape_rule,
                   chunk_rows=int(self.spec.chunk_rows),
                   rows=int(per_target), features=int(_hist_cols),

@@ -43,7 +43,7 @@ import numpy as np
 
 from .analysis.contracts.registry import trace_entry
 from .ops.histogram import (PALLAS_COMPACT_FRAC_CAP, build_histograms,
-                            root_sums, sort_is_one_word, table_lookup)
+                            keyed_lookup, root_sums, sort_is_one_word)
 from .ops.split_finder import SplitCandidates, leaf_output
 from .robustness import allowed_host_sync
 
@@ -104,6 +104,45 @@ class BundleDecode(NamedTuple):
     off: jnp.ndarray          # i32 [F]
     unpack_bin: jnp.ndarray   # i32 [F, B]
     code_feat: jnp.ndarray    # i32 [G, Bb]
+
+
+class RouteTable(NamedTuple):
+    """One wave's routing table, keyed by the wave's split ordinal
+    (``_apply_wave_splits`` has the columns; ``_route_rows`` applies it)."""
+    keys: jnp.ndarray             # i32 [S] leaf split at ordinal k (-1: none)
+    rows: jnp.ndarray             # i32 [S, C] the split, one row an ordinal
+
+
+def _route_bundle(spec: "GrowerSpec", bundle: Optional[BundleDecode]
+                  ) -> Optional[BundleDecode]:
+    """The bundle tables routing reads: native bundle-space routing only
+    (the legacy ``tpu_efb_unpack`` arm decodes per row instead)."""
+    return bundle if (bundle is not None and not spec.efb_unpack) else None
+
+
+def route_table_cols(spec: "GrowerSpec",
+                     bundle: Optional[BundleDecode]) -> int:
+    """Columns C of the routing table: the split's six, five bundle
+    coordinates under native bundle-space routing, the two children's next
+    slots under ``row_compact`` (gauge ``route.table_cols``)."""
+    return (6 + (5 if _route_bundle(spec, bundle) is not None else 0)
+            + (2 if spec.row_compact else 0))
+
+
+def empty_route_table(spec: "GrowerSpec",
+                      bundle: Optional[BundleDecode]) -> RouteTable:
+    """The table of a wave that splits nothing: no ordinal carries a key,
+    so every row routes to itself (the streamed grower's first wave)."""
+    return RouteTable(
+        keys=jnp.full(spec.hist_slots, -1, jnp.int32),
+        rows=jnp.zeros((spec.hist_slots, route_table_cols(spec, bundle)),
+                       jnp.int32))
+
+
+def _slots_of(pending: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """leaf -> histogram slot (-1: not pending), and the slots' ranks."""
+    slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
+    return jnp.where(pending, slot_rank, -1).astype(jnp.int32), slot_rank
 
 
 def decode_bundled_bin(Xb: jnp.ndarray, f: jnp.ndarray,
@@ -375,9 +414,9 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
                        default_bin: jnp.ndarray,
                        route_bundle: Optional[BundleDecode] = None):
     """Steps 3-6 of one wave — cache write + sibling subtraction, split
-    scan, split choice, tree/leaf-state apply — plus the [L+1, 6|11]
-    routing table and categorical left-set mask the per-row routing pass
-    consumes.
+    scan, split choice, tree/leaf-state apply — plus the wave's
+    ``RouteTable`` (one row a split ordinal) and categorical left-set mask
+    the per-row routing pass consumes.
 
     Shared VERBATIM by the resident wave body (``grow_tree``) and the
     streamed ``wave_update`` (``StreamedGrower``): residency is a transport
@@ -397,11 +436,10 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     routing pass compares bundled codes directly instead of gathering a
     per-row decode.
 
-    Returns ``(state', table, map_mask, p, q, n_apply)`` with ``state'``
-    carrying every field EXCEPT the per-row ones (leaf_id, the next
-    wave's slot of each row, or the carried partition), which the caller
-    owns; ``p``/``q`` are the per-slot split/new-right leaves the resident
-    loop's per-row bookkeeping (step 8) keys on.
+    Returns ``(state', table, map_mask, n_apply)`` with ``state'``
+    carrying every field EXCEPT the per-row ones (leaf_id and the next
+    wave's slot of each row), which the caller owns and the routing pass
+    writes.
     """
     L = spec.num_leaves
     M = L - 1
@@ -520,13 +558,19 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     parent_cache = state.parent_cache.at[smaller].set(jnp.where(apply, p, L))
 
     # ---- routing table (applied per row by _route_rows) ----------------
-    # One [L+1, 6] split table resolved per row by table_lookup's one-hot
-    # MXU matmul (each separate [N] table-gather costs ~10-25 ms at 2M
-    # rows; the old 7-gather routing dominated the wave). Columns:
-    #   0: split feature (-1 = leaf not split this wave)
+    # Keyed by the wave's split ORDINAL, not by leaf id: row k holds the
+    # k-th chosen split and keys[k] the leaf it splits (-1, a key no row
+    # carries, where ordinal k applies nothing), so the routing pass
+    # resolves a row by ONE [N, S] match against the keys (keyed_lookup)
+    # where a table over all L + 1 leaves cost a 256-wide one-hot a row for
+    # a wave that splits at most S = 25 of them: 16.5 ms a wave at
+    # 14,680,064 rows against 0.88 (my chip run; PERF.md, PR 33). A row whose
+    # leaf is not split matches nothing and reads zeros, so the two columns
+    # whose "nothing" is -1 are stored offset by one. Columns:
+    #   0: split feature + 1 (0 = leaf not split this wave)
     #   1: threshold bin
-    #   2: missing bin code (-1 = feature has no missing bin) folded from
-    #      (missing_code, num_bins, default_bin) at split time — the
+    #   2: missing bin code + 1 (0 = feature has no missing bin) folded
+    #      from (missing_code, num_bins, default_bin) at split time — the
     #      reference's NumericalDecision missing handling (tree.h:218)
     #   3: right-child leaf   4: default_left   5: is_cat
     # Native bundle-space routing (route_bundle set) appends the winning
@@ -534,27 +578,29 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     # chosen splits only, never per row (the reference translates a
     # FeatureGroup threshold back the same way):
     #   6: bundled column   7: lo   8: hi   9: off   10: default bin
+    # Under row_compact the last two columns carry the NEXT wave's
+    # histogram slot + 1 of the left and of the right child (0 = not
+    # pending: needs_hist is final above), so the slot of every moved row
+    # rides on the same pass.
     sf = cand.feature[p]
     sf_safe = jnp.maximum(sf, 0)
     mc_s, nb_s, db_s = (missing_code[sf_safe], num_bins[sf_safe],
                         default_bin[sf_safe])
     miss_bin = jnp.where(mc_s == 2, nb_s - 1,
                          jnp.where(mc_s == 1, db_s, -1))
-    cols = [sf.astype(jnp.int32), cand.threshold[p],
-            miss_bin.astype(jnp.int32), q.astype(jnp.int32),
+    cols = [sf.astype(jnp.int32) + 1, cand.threshold[p],
+            miss_bin.astype(jnp.int32) + 1, q.astype(jnp.int32),
             cand.default_left[p].astype(jnp.int32),
             cand.is_cat[p].astype(jnp.int32)]
-    scratch = [-1, 0, -1, 0, 0, 0]
     if route_bundle is not None:
         cols += [route_bundle.col[sf_safe], route_bundle.lo[sf_safe],
                  route_bundle.hi[sf_safe], route_bundle.off[sf_safe],
                  db_s.astype(jnp.int32)]
-        scratch += [0, 0, 0, 0, 0]
-    table = jnp.zeros((L + 1, len(cols)), jnp.int32) \
-        .at[:, 0].set(-1).at[:, 2].set(-1)
-    rows = jnp.stack(cols, axis=-1)
-    table = table.at[p].set(rows, mode="drop").at[L].set(
-        jnp.array(scratch, jnp.int32))
+    if spec.row_compact:
+        slot_next = _slots_of(needs_hist)[0]
+        cols += [slot_next[p] + 1, slot_next[q] + 1]
+    table = RouteTable(keys=jnp.where(apply, p, -1),
+                       rows=jnp.stack(cols, axis=-1))
     map_mask = None
     if spec.use_categorical:
         map_mask = jnp.zeros((L + 1, B), bool).at[p].set(cand.cat_mask[p],
@@ -566,12 +612,12 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
         leaf_depth=leaf_depth, leaf_is_right=leaf_is_right, cand=cand,
         needs_hist=needs_hist, sib_leaf=sib_leaf, parent_cache=parent_cache,
         num_leaves_cur=state.num_leaves_cur + n_apply, done=done)
-    return state2, table, map_mask, p, q, n_apply
+    return state2, table, map_mask, n_apply
 
 
 @trace_entry("routing.bundle_space")
 @jax.named_scope("wave.route")
-def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: jnp.ndarray,
+def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: RouteTable,
                 map_mask: Optional[jnp.ndarray], spec: "GrowerSpec",
                 bundle: Optional[BundleDecode], default_bin: jnp.ndarray):
     """Step 7: apply one wave's routing table to the rows of ``X``.
@@ -579,12 +625,14 @@ def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: jnp.ndarray,
     The only wave computation that touches the code matrix besides the
     histogram build — under streaming it runs per shard (fused ahead of the
     shard's histogram leg) on exactly these ops. Returns
-    ``(leaf_id, f_row, go_left, right_row)``; the trailing three feed the
-    resident loop's per-row bookkeeping (step 8)."""
-    packed = table_lookup(lid, table)                         # [N, 6|11]
-    f_row = packed[:, 0]
+    ``(leaf_id, f_row, slot_row)``: every row's leaf after the wave, its
+    split feature (-1: its leaf was not split) and, under
+    ``spec.row_compact``, the histogram slot of its leaf in the next wave
+    (-1: not pending; else None)."""
+    packed = keyed_lookup(lid, table.keys, table.rows)        # [N, C]
+    f_row = packed[:, 0] - 1
     thr_row = packed[:, 1]
-    miss_row = packed[:, 2]
+    miss_row = packed[:, 2] - 1
     right_row = packed[:, 3]
     dl_row = packed[:, 4] != 0
     f_safe = jnp.maximum(f_row, 0)
@@ -625,7 +673,11 @@ def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: jnp.ndarray,
                                           axis=1)[:, 0]
         go_left = jnp.where(cat_row, go_left_cat, go_left)
     leaf_id = jnp.where((f_row >= 0), jnp.where(go_left, lid, right_row), lid)
-    return leaf_id, f_row, go_left, right_row
+    slot_row = None
+    if spec.row_compact:
+        # a row of an unsplit leaf read zeros: -1, not pending
+        slot_row = jnp.where(go_left, packed[:, -2], packed[:, -1]) - 1
+    return leaf_id, f_row, slot_row
 
 
 def _rows_by_slot(slot_row: jnp.ndarray, num_slots: int) -> jnp.ndarray:
@@ -779,16 +831,11 @@ def grow_tree(
 
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
 
-    def slots_of(pending):
-        """leaf -> histogram slot (-1: not pending), and the slots' ranks."""
-        slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
-        return jnp.where(pending, slot_rank, -1).astype(jnp.int32), slot_rank
-
     def wave(state: GrowState) -> GrowState:
         # ---- 1. slot assignment for leaves needing histograms --------------
         with jax.named_scope("wave.slots"):
             pending = state.needs_hist
-            slot_of_leaf, slot_rank = slots_of(pending)               # [L+1]
+            slot_of_leaf, slot_rank = _slots_of(pending)              # [L+1]
             # leaf served by each slot (or L = scratch)
             leaf_of_slot = jnp.full(S, L, jnp.int32).at[
                 jnp.where(pending, slot_rank, S)  # invalid -> dropped (S OOB)
@@ -802,7 +849,7 @@ def grow_tree(
             # "mixed": the XLA one-hot matmul for FULL streaming passes and
             # the Pallas VMEM-accumulator kernel for COMPACTED passes (which
             # kernel wins which pass type on today's chip: not measured,
-            # ROADMAP Queue 1 item 5)
+            # ROADMAP Queue 1 item 7)
             use_pallas = (spec.hist_kernel == "pallas"
                           or (spec.hist_kernel == "mixed"
                               and row_idx is not None))
@@ -895,37 +942,18 @@ def grow_tree(
             new_hist = comm.reduce_hist(new_hist)
 
         # ---- 3-6 + routing table: the shared wave tail ---------------------
-        state2, table, map_mask, p, q, _n_apply = _apply_wave_splits(
+        state2, table, map_mask, _n_apply = _apply_wave_splits(
             state, new_hist, leaf_of_slot, bm, spec, comm,
             scan_bundle if (bundle is not None and not unbundle_early)
             else None, num_bins, missing_code, default_bin,
-            route_bundle=(bundle if (bundle is not None
-                                     and not spec.efb_unpack) else None))
+            route_bundle=_route_bundle(spec, bundle))
 
-        # ---- 7. route rows of split leaves ---------------------------------
-        leaf_id, f_row, go_left, right_row = _route_rows(
+        # ---- 7-8. route rows of split leaves; the same pass hands every row
+        # the histogram slot of its leaf in the next wave (the pending
+        # leaves are children of THIS wave's splits, so the slot rides in
+        # the routing table beside the split: one lookup a row, a wave)
+        leaf_id, f_row, slot_row_next = _route_rows(
             X, state.leaf_id, table, map_mask, spec, bundle, default_bin)
-
-        # ---- 8. per-row bookkeeping for the next wave ----------------------
-        slot_row_next = None
-        if spec.row_compact:
-            # The next wave's pending leaves are children of THIS wave's
-            # splits (needs_hist is reset and set for the smaller child
-            # only), so the slot of a row's new leaf is a function of its
-            # split's ordinal k and its side, both already in the routing
-            # pass's output: an integer one-hot multiply-sum over the S
-            # ordinals, as exact and as cheap as the routing's own, where a
-            # table_lookup(leaf_id, slot_of_leaf) would be a second pass
-            # over a 256-wide one-hot. Rows of unsplit leaves get -1.
-            with jax.named_scope("wave.slots"):
-                slot_next, _ = slots_of(state2.needs_hist)            # [L+1]
-                k_row = jnp.where(f_row >= 0,
-                                  right_row - state.num_leaves_cur, -1)
-                k_onehot = (k_row[:, None]
-                            == jnp.arange(S, dtype=jnp.int32)[None, :])
-                slot_l = jnp.sum(k_onehot * (slot_next[p] + 1)[None, :], axis=1)
-                slot_r = jnp.sum(k_onehot * (slot_next[q] + 1)[None, :], axis=1)
-                slot_row_next = jnp.where(go_left, slot_l, slot_r) - 1
 
         # ---- 9. the loop's own counters, one entry per wave ----------------
         with jax.named_scope("wave.stats"):
@@ -1121,16 +1149,13 @@ class StreamedGrower:
                 done=jnp.asarray(False),
             )
             leaf_id = jnp.zeros(n_local, jnp.int32)
-            # wave-1 routing table: every leaf "not split" -> identity
+            # wave-1 routing table: no ordinal carries a key -> identity
             # route. Width must match what _apply_wave_splits emits for
-            # THIS arm (11 columns with native bundle-space routing) —
-            # a narrower wave-1 table would both re-trace shard_fn/
-            # route_fn against the streamed shape-stability contract and
-            # lean on JAX's silent out-of-bounds clamp for columns 6-10
-            n_route_cols = 11 if (bundle is not None
-                                  and not spec.efb_unpack) else 6
-            table0 = jnp.zeros((L + 1, n_route_cols), jnp.int32) \
-                .at[:, 0].set(-1).at[:, 2].set(-1)
+            # THIS arm (route_table_cols) — a narrower wave-1 table would
+            # both re-trace shard_fn/route_fn against the streamed
+            # shape-stability contract and lean on JAX's silent
+            # out-of-bounds clamp for the bundle columns
+            table0 = empty_route_table(spec, bundle)
             map_mask0 = (jnp.zeros((L + 1, B), bool)
                          if spec.use_categorical else None)
             return state, leaf_id, table0, map_mask0
@@ -1143,8 +1168,7 @@ class StreamedGrower:
             # step 1 of the resident wave, verbatim
             leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
             pending = needs_hist
-            slot_rank = jnp.cumsum(pending.astype(jnp.int32)) - 1
-            slot_of_leaf = jnp.where(pending, slot_rank, -1).astype(jnp.int32)
+            slot_of_leaf, slot_rank = _slots_of(pending)
             leaf_of_slot = jnp.full(S, L, jnp.int32).at[
                 jnp.where(pending, slot_rank, S)
             ].set(leaf_iota, mode="drop")
@@ -1171,8 +1195,8 @@ class StreamedGrower:
             codes = unpack_codes(codes_sh, F_cols, self.code_mode)
             # route by the PREVIOUS wave's table first (wave 1 arrives with
             # the inert table): one shard transfer serves both legs
-            new_lid, _, _, _ = _route_rows(codes, lid_sh, table, map_mask,
-                                           spec, bundle, self.default_bin)
+            new_lid, _, _ = _route_rows(codes, lid_sh, table, map_mask,
+                                        spec, bundle, self.default_bin)
             leaf_id = jax.lax.dynamic_update_slice_in_dim(
                 leaf_id, new_lid, start, 0)
             g_sh = jax.lax.dynamic_slice_in_dim(g, start, Rd)
@@ -1214,11 +1238,10 @@ class StreamedGrower:
                 scan_bundle = (comm.localize_bundle(bundle)
                                if getattr(comm, "bundled_blocks", False)
                                else bundle)
-            state2, table, map_mask, _p, _q, n_apply = _apply_wave_splits(
+            state2, table, map_mask, n_apply = _apply_wave_splits(
                 state, new_hist, leaf_of_slot, bm, spec, comm, scan_bundle,
                 self.num_bins, self.missing_code, self.default_bin,
-                route_bundle=(bundle if (bundle is not None
-                                         and not spec.efb_unpack) else None))
+                route_bundle=_route_bundle(spec, bundle))
             return state2, table, map_mask, state2.done, n_apply
 
         self.wave_fn = self._wrap(
@@ -1231,8 +1254,8 @@ class StreamedGrower:
             start = i * Rd
             lid_sh = jax.lax.dynamic_slice_in_dim(leaf_id, start, Rd)
             codes = unpack_codes(codes_sh, F_cols, self.code_mode)
-            new_lid, _, _, _ = _route_rows(codes, lid_sh, table, map_mask,
-                                           spec, bundle, self.default_bin)
+            new_lid, _, _ = _route_rows(codes, lid_sh, table, map_mask,
+                                        spec, bundle, self.default_bin)
             return jax.lax.dynamic_update_slice_in_dim(
                 leaf_id, new_lid, start, 0)
 

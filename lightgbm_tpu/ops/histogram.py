@@ -321,10 +321,15 @@ def table_lookup(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
     """table[idx] for a SMALL table ([T<=1024, C]) as a one-hot f32 matmul.
 
     XLA's TPU gather prices a per-row dynamic lookup at the random-access
-    tax (an earlier on-chip session measured ~15-25 ms for 2M rows) even when
-    the table is tiny; the one-hot [N, T] x [T, C] contraction is ~0.1 ms
-    on the MXU. Exact for values with |v| < 2^24 (f32 integer range) —
-    callers keep table entries inside that. Returns table.dtype.
+    tax (8.7 ns a row and more on the v5e: 0.127 s for one element gather
+    over 14,680,064 rows; PERF.md, PR 28) even when the table is tiny. The
+    one-hot [N, T] x [T, C] contraction costs by the WIDTH of the one-hot,
+    T lanes produced and converted a row: at T = 256 and C = 6 it reads
+    66 us a 65,536-row block, 16.5 ms over 14,680,064 rows with its convert
+    = 1.12 ns a row, where a 25-wide one reads 0.88 ms (my chip runs;
+    PERF.md, PR 31 and PR 33), so a lookup whose live keys are few belongs
+    in :func:`keyed_lookup`. Exact for values with |v| < 2^24 (f32 integer
+    range) — callers keep table entries inside that. Returns table.dtype.
 
     CAVEAT: rows of the table that are never selected still flow through
     the contraction with weight 0 — a non-finite entry there would poison
@@ -364,6 +369,32 @@ def table_lookup(idx: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
         out = jnp.round(out)
     out = out.astype(table.dtype)
     return out[:, 0] if squeeze else out
+
+
+def keyed_lookup(idx: jnp.ndarray, keys: jnp.ndarray,
+                 table: jnp.ndarray) -> jnp.ndarray:
+    """``table[k]`` for the one ``k`` with ``keys[k] == idx[n]``, zeros for
+    an ``idx`` no key carries: :func:`table_lookup` for a table of which
+    only ``S = len(keys)`` rows are live, at the cost of an S-wide one-hot
+    in place of a T-wide one.
+
+    ``keys`` [S] are distinct where they can match (an unused ordinal
+    carries a key no ``idx`` holds, e.g. -1), so a row matches at most one
+    ordinal and the f32 ``Precision.HIGHEST`` contraction returns that
+    table row bit-exact for |v| < 2^24, as ``table_lookup`` argues. Not
+    blocked: the [N, S] match is never materialised, the TPU's compiler
+    fuses the compare into the dot's operand and the round and convert
+    into its output (one fusion over [N, C]: 0.88 ms at 14,680,064 rows,
+    S = 25 and C <= 8 on the v5e, 1.58 at C = 13, 1.40 in 65,536-row
+    blocks; PERF.md, PR 33). Integer tables only; returns ``[N, C]`` of
+    ``table.dtype``."""
+    match = (idx[:, None] == keys[None, :]).astype(jnp.float32)      # [N, S]
+    out = jax.lax.dot_general(
+        match, table.astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+    return jnp.round(out).astype(table.dtype)
 
 
 def build_histograms(

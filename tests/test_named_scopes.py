@@ -98,14 +98,15 @@ def test_streamed_legs_share_the_split_and_route_scopes():
     """``_apply_wave_splits`` and ``_route_rows`` carry their scope inside,
     so the host-driven streamed grower's legs are named like the resident
     loop's."""
-    from lightgbm_tpu.grower import _route_rows
+    from lightgbm_tpu.grower import _route_rows, empty_route_table
     from lightgbm_tpu.analysis.contracts.entries import _wave_spec
     import jax.numpy as jnp
     spec = _wave_spec()
     route = jax.jit(lambda X, lid, table: _route_rows(
         X, lid, table, None, spec, None, jnp.zeros(6, jnp.int32)))
     text = route.lower(jnp.zeros((64, 6), jnp.uint8), jnp.zeros(64, jnp.int32),
-                       jnp.zeros((16, 6), jnp.int32)).as_text(debug_info=True)
+                       empty_route_table(spec, None)
+                       ).as_text(debug_info=True)
     assert _named(text, "wave.route")
 
 
@@ -124,24 +125,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def v5e_hlo(one_chip):
-    """The default step compiled for the described chip, as text."""
+def _compiled_text(lowered) -> str:
+    """``lowered`` compiled (for a described chip), as text. Such a program
+    cannot be read back from the persistent cache: keep it out, and the run
+    silent."""
     from jax.experimental.compilation_cache import compilation_cache as cc
-    fn, args = _step_and_args()
-    shapes = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                       sharding=one_chip), args)
-    # a program compiled for a described chip cannot be read back from the
-    # persistent cache: keep it out, and the run silent
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
-        return fn.lower(*shapes).compile().as_text()
+        return lowered.compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e_hlo(one_chip):
+    """The default step compiled for the described chip, as text."""
+    fn, args = _step_and_args()
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip), args)
+    return _compiled_text(fn.lower(*shapes))
 
 
 def test_v5e_fusions_carry_the_scopes(v5e_hlo):
@@ -178,3 +184,42 @@ def test_v5e_step_sorts_rows_once_in_the_compacted_arm(v5e_hlo):
     assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
     assert not _row_sized(v5e_hlo, 2560, "reduce-window")
     assert not _row_sized(v5e_hlo, 2560, "scatter")
+
+
+def _route_widths(hlo: str, rows: int):
+    """Minor widths ``w`` of the row-sized 2-D values ``[rows, w]`` the
+    compiled program computes under ``wave.route``: a one-hot over T keys is
+    a compare of that width there, whatever consumes it."""
+    widths = set()
+    for ln in hlo.splitlines():
+        if not re.search(r'op_name="[^"]*/wave\.route/', ln):
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[(\d+),(\d+)\]", ln)
+        if m and int(m.group(1)) == rows:
+            widths.add(int(m.group(2)))
+    return widths
+
+
+def test_v5e_routing_matches_no_wider_than_the_waves_splits(v5e_hlo, one_chip):
+    """Routing resolves a row against the wave's ``hist_slots`` split
+    leaves, never against all ``L + 1`` (PERF.md, PR 33: the 256-wide
+    one-hot lookup was two thirds of ``wave.route``). In the step compiled
+    for the v5e nothing row-sized under ``wave.route`` is wider than the
+    slots (14 here; the feature one-hot and the table's columns are 8), and
+    no value there has an ``L + 1`` = 16 axis at all; the same reading
+    flags the old form, ``table_lookup`` over a ``[L + 1, 6]`` table."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import table_lookup
+    slots, leaves = 14, 15            # PARAMS: min(25, num_leaves - 1)
+    widths = _route_widths(v5e_hlo, 2560)
+    assert slots in widths and max(widths) == slots, widths
+    route_shapes = re.findall(
+        r'= \w+\[([\d,]+)\][^\n]*op_name="[^"]*/wave\.route/', v5e_hlo)
+    assert route_shapes
+    assert not [s for s in route_shapes
+                if str(leaves + 1) in s.split(",")], route_shapes
+    # the control: what the reading says of the lookup this PR removed
+    old = jax.jit(jax.named_scope("wave.route")(table_lookup)).lower(
+        jax.ShapeDtypeStruct((2560,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((leaves + 1, 6), jnp.int32, sharding=one_chip))
+    assert leaves + 1 in _route_widths(_compiled_text(old), 2560)
