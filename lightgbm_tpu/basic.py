@@ -49,9 +49,15 @@ def _to_2d_float(data):
         # without ever materializing the float matrix (reference accepts
         # CSR/CSC via LGBM_DatasetCreateFromCSR/CSC, c_api.cpp:471+)
         return data.tocsr(), None
-    from . import observability as obs
-    with obs.setup_span("dataset.to_float"):      # a float64 COPY of float32
-        arr = np.asarray(data, dtype=np.float64)
+    arr = np.asarray(data)
+    if arr.dtype != np.float32:
+        # float32 rows stay as they came (no copy): every reader widens the
+        # values it looks at (the bin-finding sample, a column, a chunk),
+        # and f32 -> f64 is exact, so bins, codes and trees are the float64
+        # copy's. Everything else becomes float64, as the reference reads it
+        from . import observability as obs
+        with obs.setup_span("dataset.to_float"):
+            arr = np.asarray(arr, dtype=np.float64)
     if arr.ndim == 1:
         arr = arr.reshape(1, -1)
     return arr, None

@@ -25,17 +25,18 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from .. import observability as obs
 from ..observability import costs as obs_costs
 from ..config import Config
 from ..dataset import ConstructedDataset, Metadata, MetadataDuckTyping
-from ..grower import (GrowerSpec, TreeArrays, WaveStats, grow_tree,
-                      route_table_cols, wave_totals)
+from ..grower import (GrowerSpec, TreeArrays, WaveStats, counts_past_f32,
+                      grow_tree, route_table_cols, wave_totals)
 from ..ops.histogram import (hist_pass_shape, num_channels,
                              resolve_compact_frac, table_lookup)
-from ..parallel.comm import make_parallel_context
+from ..parallel.comm import make_parallel_context, tree_collective_bytes
 from ..metrics import Metric, create_metrics
 from ..robustness import allowed_host_sync
 from ..utils.timer import TIMERS
@@ -516,6 +517,7 @@ class GBDT:
         self._streamed_grower = None
         self._stream_fns = None
         self._ingest_report = None
+        self._comm_bytes_per_wave = {}      # comm.collective_bytes, by name
         if self.residency == "stream":
             # out-of-core: the padded (possibly bundled) code matrix is cut
             # into fixed-size host shards, packed to the tightest byte
@@ -926,6 +928,12 @@ class GBDT:
             hist_bins=(self._hist_bins
                        if (self.bundle is not None and not self._efb_unpack)
                        else None))
+        if comm_bytes and counts_past_f32(
+                self.num_data_padded // self.pctx.pad_rows_multiple(),
+                self.comm):
+            # past 2^24 rows the tree's counts are summed again as integers
+            comm_bytes["psum_leaf_counts"] = (num_leaves + 1) * 4
+        self._comm_bytes_per_wave = comm_bytes
         for cname, nbytes in comm_bytes.items():
             reg.gauge(f"comm.bytes_per_wave.{cname}").set(nbytes)
         if comm_bytes:
@@ -1133,6 +1141,12 @@ class GBDT:
             reports.append(rep)
         report = merge_ingest_reports(reports)
         self._ingest_report = report
+        if len(reports) > 1:
+            # the ingest span ran once a device: its gauge held the last one's
+            reg = obs.get_registry()
+            reg.gauge("setup.ingest_s").set(report["seconds"])
+            for d, rep in enumerate(reports):
+                reg.gauge(f"setup.ingest_device_s.{d}").set(rep["seconds"])
         Log.info("device ingest: %d rows binned+packed on %d device(s) "
                  "(%.2f Mrow/s, %d chunks, stall fraction %.2f)",
                  N, len(blocks), (report["rows_per_s"] or 0.0) / 1e6,
@@ -1247,6 +1261,20 @@ class GBDT:
                 internal_value=clip_nonfinite(tree.internal_value))
         return tree, bl
 
+    def _leaf_contrib(self, leaf_ids, leaf_value):
+        """Each training row's leaf value. Under a row-sharded mesh every
+        shard looks up its own rows (``shard_map``): left to the
+        partitioner, the blocked lookup's reshape and its loop over row
+        blocks all-gather the leaf ids across devices, the one row-sized
+        exchange the step ever made (the step compiled for four described
+        v5e chips; PERF.md, PR 34)."""
+        rows = self.pctx.row_sharding()
+        if rows is None:
+            return table_lookup(leaf_ids, leaf_value)
+        return jax.shard_map(table_lookup, mesh=self.pctx.mesh,
+                             in_specs=(rows.spec, P()), out_specs=rows.spec,
+                             check_vma=False)(leaf_ids, leaf_value)
+
     def _tree_score_updates(self, score_k, valid_k, valid_Xb, tree,
                             leaf_ids, it):
         """Apply one (shrunk) tree to the train score and every valid
@@ -1259,7 +1287,7 @@ class GBDT:
                 contrib = linear_leaf_scores(tree, leaf_ids, self.Xraw,
                                              self.Xmiss)
             else:
-                contrib = table_lookup(leaf_ids, tree.leaf_value)
+                contrib = self._leaf_contrib(leaf_ids, tree.leaf_value)
             new_score_k = self._score_update(score_k, contrib, it)
         new_valid_k = []
         for vi in range(len(valid_Xb)):
@@ -1484,16 +1512,24 @@ class GBDT:
                 ((2, 3, 4) if self.bagging_on else (2, 3))
         return jax.jit(step, donate_argnums=donate)
 
+    def _place_step_scalars(self, shrinkage: float) -> None:
+        """The step's device counter (first step / post-rollback resync) and
+        its shrinkage, PLACED like the step's own outputs: an uncommitted
+        scalar on the first call and the step's committed output on the
+        second are two signatures, and the step compiled twice for them
+        (333.7 + 327.4 s at 14.7M rows; PERF.md, PR 27)."""
+        if self._iter_dev is None:
+            self._iter_dev = self._put(np.asarray(self.iter_, np.int32))
+        if self._shrink_cache[0] != shrinkage:
+            self._shrink_cache = (shrinkage,
+                                  self._put(np.asarray(shrinkage, np.float32)))
+
     def _dispatch_prep(self, shrinkage: float):
         """Shared pre-dispatch protocol of the K=1 and fused-batch paths:
         device-counter resync, on-device shrinkage cache, valid-score /
         step-constant assembly. ONE copy so the two dispatchers cannot
         drift."""
-        if self._iter_dev is None:    # first step / post-rollback resync
-            self._iter_dev = jnp.asarray(self.iter_, jnp.int32)
-        if self._shrink_cache[0] != shrinkage:
-            self._shrink_cache = (shrinkage,
-                                  jnp.asarray(shrinkage, jnp.float32))
+        self._place_step_scalars(shrinkage)
         valid_scores = tuple(tuple(vs.score[k] for k in range(self.num_models))
                              for vs in self.valid_sets)
         consts, valid_Xb = self._step_consts()
@@ -1772,11 +1808,7 @@ class GBDT:
         if self._stream_fns is None:
             self._stream_fns = self._make_stream_fns()
         fns = self._stream_fns
-        if self._iter_dev is None:    # first step / post-rollback resync
-            self._iter_dev = jnp.asarray(self.iter_, jnp.int32)
-        if self._shrink_cache[0] != shrinkage:
-            self._shrink_cache = (shrinkage,
-                                  jnp.asarray(shrinkage, jnp.float32))
+        self._place_step_scalars(shrinkage)
         valid_scores = tuple(tuple(vs.score[k] for k in range(self.num_models))
                              for vs in self.valid_sets)
         valid_Xb = tuple(vs.Xb for vs in self.valid_sets)
@@ -2476,6 +2508,16 @@ class GBDT:
                     reg.summary("grow.hist_chunks").observe(t["hist_chunks"])
                     reg.summary("grow.hist_acc_bytes").observe(
                         t["hist_chunks"] * self._hist_acc_bytes * 2)
+                if self._comm_bytes_per_wave:
+                    moved = tree_collective_bytes(self._comm_bytes_per_wave,
+                                                  t["waves"])
+                    for cname, nbytes in moved["bytes"].items():
+                        reg.summary("comm.bytes." + cname).observe(nbytes)
+                    reg.summary("comm.collectives_per_tree").observe(
+                        moved["collectives"])
+                for d, (streamed, compacted) in enumerate(t["shard_passes"]):
+                    reg.summary(f"grow.stream_passes.{d}").observe(streamed)
+                    reg.summary(f"grow.compact_passes.{d}").observe(compacted)
                 reg.counter("rows.routed").inc(t["rows_routed"])
                 reg.counter("hist.mxu_flops").inc(
                     2 * t["hist_rows_touched"] * cells * spec.hist_slots * ch)

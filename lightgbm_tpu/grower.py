@@ -66,9 +66,9 @@ class TreeArrays(NamedTuple):
     right_child: jnp.ndarray      # i32 [M+1]
     split_gain: jnp.ndarray       # f32 [M+1]
     internal_value: jnp.ndarray   # f32 [M+1] would-be output of internal node
-    internal_count: jnp.ndarray   # f32 [M+1]
+    internal_count: jnp.ndarray   # f32 [M+1] (i32 past 2^24 rows: _exact_counts)
     leaf_value: jnp.ndarray       # f32 [L+1]
-    leaf_count: jnp.ndarray       # f32 [L+1]
+    leaf_count: jnp.ndarray       # f32 [L+1] (i32 past 2^24 rows: _exact_counts)
     leaf_parent: jnp.ndarray      # i32 [L+1]
     num_leaves: jnp.ndarray       # i32 scalar: leaves actually grown
     # piecewise-linear leaves (linear_tree=true, ops/linear.py): populated
@@ -228,6 +228,11 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int,
     # the wave's pace
     streamed = int((~compacted).any(axis=0).sum())
     return {"waves": waves,
+            # (streamed, compacted) passes of each shard, where there are
+            # several: shards that took different arms in a wave are seen
+            "shard_passes": ([(int(w), waves - int(w))
+                              for w in (~compacted).sum(axis=1)]
+                             if compacted.shape[0] > 1 else []),
             "stream_passes": streamed,
             "compact_passes": waves - streamed,
             "hist_rows_touched": int(touched.max(axis=0).sum()),
@@ -993,9 +998,58 @@ def grow_tree(
     tr = tr._replace(
         leaf_value=tr.leaf_value.at[L].set(0.0),
         internal_value=tr.internal_value.at[M].set(0.0))
+    if counts_past_f32(N, comm):
+        tr = _exact_counts(tr, final.leaf_id, included, comm, L)
     # the counters leave with a leading device axis: under shard_map each
     # device's own record is one row of the global array (comm.shard_grow)
     return tr, final.leaf_id, jax.tree.map(lambda a: a[None], final.stats)
+
+
+# the histograms' count channel, the leaves' running counts and the split
+# finder's prefix sums are float32: whole numbers up to here, and no further
+_F32_EXACT_ROWS = 1 << 24
+
+
+def counts_past_f32(rows_per_device: int, comm) -> bool:
+    """Whether a tree over this many rows a device can hold a node of more
+    rows than float32 counts: the table's rows are the devices' together."""
+    return rows_per_device * getattr(comm, "num_devices", 1) > _F32_EXACT_ROWS
+
+
+@jax.named_scope("tree.exact_counts")
+def _exact_counts(tree: TreeArrays, leaf_id, included, comm, L: int) -> TreeArrays:
+    """The tree's row counts taken again, in int32, from where the rows
+    ended up. A table of more than 2^24 rows (four workers' shares of the
+    Criteo cell: 44,040,192) has nodes whose float32 counts are no longer
+    whole: a bin that holds 17.6M rows rounds to an even number in the
+    cross-device sum, the sibling's histogram inherits the error through
+    the subtraction, and ``cnt - left`` hands it down to every larger child
+    (119 nodes of four trees off by a row or two on the chip; PERF.md,
+    PR 34). Each shard counts its in-bag rows per leaf (one compare-sum
+    over its rows), the shards' counts are summed as integers, and a node's
+    count is its children's: children are made after their parent, so one
+    pass down the node numbers finds them counted. Growth itself still
+    gates ``min_data_in_leaf`` on the float32 counts."""
+    leaves = jnp.arange(L + 1, dtype=leaf_id.dtype)
+    mine = jnp.sum(((leaf_id[:, None] == leaves[None, :])
+                    & (included[:, None] > 0)).astype(jnp.int32), axis=0)
+    (leaf_count,) = comm.reduce_scalars(mine)                     # [L+1] i32
+    leaf_count = leaf_count.at[L].set(0)
+
+    def count_of(child, internal_count):
+        return jnp.where(child < 0, leaf_count[jnp.clip(-child - 1, 0, L)],
+                         internal_count[jnp.clip(child, 0, L - 1)])
+
+    def node(k, internal_count):
+        nid = L - 2 - k
+        both = (count_of(tree.left_child[nid], internal_count)
+                + count_of(tree.right_child[nid], internal_count))
+        return internal_count.at[nid].set(
+            jnp.where(nid < tree.num_leaves - 1, both, 0))
+
+    internal_count = jax.lax.fori_loop(
+        0, L - 1, node, jnp.zeros(L, jnp.int32))
+    return tree._replace(leaf_count=leaf_count, internal_count=internal_count)
 
 
 # ======================================================================

@@ -133,12 +133,13 @@ def device_ingest_blocker(data, mappers: Sequence[BinMapper]) -> Optional[str]:
         if cats and max(cats) >= _CAT_EXACT_LIMIT:
             return (f"categorical value {max(cats)} >= 2^24 "
                     f"(not exactly representable in f32)")
-    from .. import observability as obs
-    with obs.setup_span("dataset.lossless_check"):
-        lossless = f32_lossless(data)
-    if not lossless:
-        return ("float64 values not losslessly f32-representable "
-                "(device binning compares in f32)")
+    if data.dtype == np.float64:      # float32 is lossless by definition
+        from .. import observability as obs
+        with obs.setup_span("dataset.lossless_check"):
+            lossless = f32_lossless(data)
+        if not lossless:
+            return ("float64 values not losslessly f32-representable "
+                    "(device binning compares in f32)")
     return None
 
 
@@ -516,6 +517,10 @@ def merge_ingest_reports(reports: Sequence[Dict]) -> Dict:
     compiles = [r["compiles"] for r in reports]
     out.update({
         "devices": len(reports),
+        # one device after another (boosting/gbdt._ingest_device): their
+        # seconds add up to the wall clock's
+        "device_seconds": [r["seconds"] for r in reports],
+        "in_turn": True,
         "seconds": round(seconds, 6),
         "stall_seconds": round(stall, 6),
         "rows_per_s": (out["rows"] / seconds) if seconds > 0 else None,

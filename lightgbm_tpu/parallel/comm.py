@@ -151,7 +151,8 @@ def _gather_argmax(cand: SplitCandidates, axis_name: str) -> SplitCandidates:
     Ties resolve to the lowest device index; with features block-partitioned
     contiguously this equals the serial learner's lowest-feature-index rule.
     """
-    g = jax.lax.all_gather(cand, axis_name)          # leaves [D, S, ...]
+    with jax.named_scope("wave.split.allgather"):
+        g = jax.lax.all_gather(cand, axis_name)      # leaves [D, S, ...]
     d_idx = jnp.argmax(g.gain, axis=0)               # [S]
 
     def pick(arr):
@@ -202,6 +203,21 @@ class SerialComm:
         space otherwise — charging feature-space widths for a bundled run
         overstated every histogram collective. Serial runs none."""
         return {}
+
+
+# of the ``collective_bytes`` entries, those a tree pays once (its root
+# sums) and not once a wave
+PER_TREE_COLLECTIVES = ("psum_root_scalars", "psum_leaf_counts")
+
+
+def tree_collective_bytes(per_wave: dict, waves: int) -> dict:
+    """Bytes one tree of ``waves`` waves moved through each collective of a
+    ``collective_bytes`` estimate, and how many collectives it ran."""
+    moved = {name: nbytes * (1 if name in PER_TREE_COLLECTIVES else waves)
+             for name, nbytes in per_wave.items()}
+    calls = sum(1 if name in PER_TREE_COLLECTIVES else waves
+                for name in per_wave)
+    return {"bytes": moved, "collectives": calls}
 
 
 def _block_slice(arr, axis_index, block: int):

@@ -113,16 +113,22 @@ def test_streamed_legs_share_the_split_and_route_scopes():
 # --------------------------------------- compiled for a described v5e chip
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_chips():
+    """The four chips of a described v5e 2x2 host."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:          # noqa: BLE001 — whatever keeps it away
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_chips):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_chips[0])
 
 
 def _compiled_text(lowered) -> str:
@@ -223,3 +229,141 @@ def test_v5e_routing_matches_no_wider_than_the_waves_splits(v5e_hlo, one_chip):
         jax.ShapeDtypeStruct((2560,), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((leaves + 1, 6), jnp.int32, sharding=one_chip))
     assert leaves + 1 in _route_widths(_compiled_text(old), 2560)
+
+
+# ------------------------- the data-parallel step, for four described chips
+
+# rows a device. The cell runs 11,010,048; the step compiles in a quarter of
+# a minute at this size and in three at that one (by hand: PERF.md, PR 34),
+# and the partitioner treats a row-sized value alike at both
+DP_ROWS = 1_048_576
+COLLECTIVE_SCOPES = ("tree.root_sums", "wave.hist.reduce", "wave.split.allgather")
+COLLECTIVE_PRIMITIVES = ("psum", "psum2", "psum_invariant", "pmax", "pmin",
+                         "reduce_scatter", "all_gather", "all_gather_invariant",
+                         "ppermute", "all_to_all")
+COLLECTIVE_OPCODES = ("all-reduce", "reduce-scatter", "all-gather",
+                      "collective-permute", "all-to-all")
+
+
+@pytest.fixture(scope="module")
+def dp_step(v5e_chips):
+    """``tree_learner=data`` over four devices, as the benchmark's four-chip
+    cell runs it: (the step traced, as a jaxpr; the step compiled for the four
+    described chips at ``DP_ROWS`` rows a device, as text)."""
+    from jax.sharding import NamedSharding
+    from lightgbm_tpu.parallel.comm import ParallelContext
+    rng = np.random.RandomState(3)
+    X = rng.rand(4 * 4096, 16).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] * X[:, 2] > 0.8).astype(np.float32)
+    params = dict(PARAMS, max_bin=255, tree_learner="data", num_machines=4,
+                  bagging_fraction=1.0, bagging_freq=0)
+    bst = lgb.Booster(params=params,
+                      train_set=lgb.Dataset(X, label=y, params=params))
+    g = bst._gbdt
+    consts, valid_Xb, valid_scores = g._dispatch_prep(g._step_shrinkage())
+    args = (consts, valid_Xb, g.score, valid_scores, g.bag_mask, g._rng_key,
+            g._iter_dev, g._shrink_cache[1])
+    # the same booster over the described chips: the step closes over the mesh
+    g.pctx = ParallelContext("data", v5e_chips)
+    small = int(g.num_data_padded)
+
+    def described(x):
+        shape = tuple(4 * DP_ROWS if d == small else d for d in x.shape)
+        return jax.ShapeDtypeStruct(
+            shape, x.dtype, sharding=NamedSharding(g.pctx.mesh, x.sharding.spec))
+
+    traced = g._make_step().trace(*jax.tree.map(described, args))
+    return traced.jaxpr, _compiled_text(traced.lower())
+
+
+def _walk(jaxpr, inside_cond=False):
+    """(equation, whether a ``cond`` encloses it) over a jaxpr and every
+    jaxpr its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_cond
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (tuple, list)) else (value,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub, inside_cond
+                                     or eqn.primitive.name == "cond")
+
+
+def test_dp_every_collective_sits_under_a_named_scope(dp_step):
+    """A device trace files collective time under a scope: the root's psum,
+    the histograms' reduce-scatter, the candidates' all-gather. None of them
+    inside an arm of the wave's ``cond``: shards may take different arms."""
+    jaxpr, _ = dp_step
+    found = [(eqn.primitive.name, str(eqn.source_info.name_stack), in_cond)
+             for eqn, in_cond in _walk(jaxpr.jaxpr)
+             if eqn.primitive.name in COLLECTIVE_PRIMITIVES]
+    assert found
+    for scope in COLLECTIVE_SCOPES:
+        assert any(scope in stack.split("/") for _, stack, _ in found), scope
+    for name, stack, in_cond in found:
+        # past 2^24 rows a table also sums its leaves' counts as integers
+        assert set(COLLECTIVE_SCOPES + ("tree.exact_counts",)) \
+            & set(stack.split("/")), (name, stack)
+        assert not in_cond, (name, stack)
+    conds = [eqn for eqn, _ in _walk(jaxpr.jaxpr) if eqn.primitive.name == "cond"]
+    assert any("wave.hist.compact" in str(e) for eqn in conds
+               for e, _ in _walk(eqn.params["branches"][1].jaxpr)
+               for e in [e.source_info.name_stack])
+
+
+def _computations(hlo: str) -> dict:
+    """{computation name: its lines} of a compiled module's text."""
+    comps, name = {}, None
+    for ln in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", ln)
+        if head and not ln.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(ln)
+    return comps
+
+
+def _collective_lines(lines):
+    return [ln for ln in lines if re.search(
+        r"= [^=]*?\b(?:" + "|".join(COLLECTIVE_OPCODES) + r")(?:-start|-done)?\(", ln)]
+
+
+def test_dp_compiled_step_exchanges_nothing_row_sized(dp_step):
+    """The step compiled for four v5e chips: what crosses devices is
+    histograms, candidates and scalars, never a value with a row's worth of
+    elements (the score update looked leaf values up through the partitioner
+    and all-gathered the leaf ids until PR 34); the compacted arm of the
+    wave's ``cond`` and the streamed one hold no collective; one row-sized
+    sort a wave a shard, the compacted arm's own."""
+    _, hlo = dp_step
+    collectives = _collective_lines(hlo.splitlines())
+    assert len(collectives) >= 3
+    for ln in collectives:
+        result = ln.split(" = ", 1)[1].split("(%")[0]
+        sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                 for dims in re.findall(r"\w+\[([\d,]*)\]", result)]
+        assert max(sizes) < DP_ROWS // 4, ln[:200]
+    # the wave's cond: neither arm, nor anything an arm calls
+    comps = _computations(hlo)
+    waves = [ln for lines in comps.values() for ln in lines
+             if " conditional(" in ln and "/while/body/cond" in ln]
+    assert len(waves) == 1, waves
+    todo = re.findall(r"%([\w.\-]+)", waves[0].split("branch_computations={")[1]
+                      .split("}")[0])
+    assert len(todo) == 2
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        assert not _collective_lines(comps[name]), name
+        for ln in comps[name]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", ln)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", ln):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    assert len(seen) > 2
+    sorts = _row_sized(hlo, DP_ROWS, "sort")
+    assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
+    assert not _row_sized(hlo, DP_ROWS, "scatter")

@@ -194,6 +194,8 @@ def test_wave_totals_takes_the_pace_setting_shard():
                    scan_pending=np.array([[1, 2, 0], [1, 2, 0]]))
     t = wave_totals(st, rows_per_device=100, chunk_rows=20, hist_slots=4)
     assert t == {"waves": 2, "stream_passes": 2, "compact_passes": 0,
+                 # shard 0 compacted the wave that shard 1 streamed
+                 "shard_passes": [(1, 1), (2, 0)],
                  "hist_rows_touched": 200, "hist_chunks": 10,
                  "hist_rows_active": 130,
                  "rows_routed": 200, "rows_split": 170,
@@ -368,7 +370,13 @@ def test_spans_record_the_span_that_caused_them(clean_registry):
 def test_setup_spans_always_set_their_gauge(clean_registry):
     """Set-up boundaries are timed with the tracer off: the benchmark reads
     them from the registry."""
-    bst = _booster(dict(BASE, tpu_ingest="device"), rounds=1)
+    # float64 rows: the copy and the round-trip check run for them alone
+    # (float32 rows are kept as they are, tests/test_float32_input.py)
+    X, y = _data()
+    params = dict(BASE, tpu_ingest="device")
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(
+        X.astype(np.float64), label=y, params=params))
+    bst.update()
     bst._ensure_finalized()
     gauges = obs.snapshot()["gauges"]
     for name in ("setup.dataset_to_float_s", "setup.dataset_lossless_check_s",
