@@ -33,7 +33,8 @@ from ..observability import costs as obs_costs
 from ..config import Config
 from ..dataset import ConstructedDataset, Metadata, MetadataDuckTyping
 from ..grower import (GrowerSpec, TreeArrays, WaveStats, counts_past_f32,
-                      grow_tree, route_table_cols, wave_totals)
+                      grow_tree, route_table_cols, scan_block_pairs,
+                      scan_hist_shape, wave_totals)
 from ..ops.histogram import (hist_pass_shape, num_channels,
                              resolve_compact_frac, table_lookup)
 from ..parallel.comm import make_parallel_context, tree_collective_bytes
@@ -885,6 +886,11 @@ class GBDT:
             * (4 if self.spec.hist_f64 else 2))
         reg.gauge("hist.acc_bytes").set(self._hist_acc_bytes)
         reg.gauge("hist.compact_frac").set(self.spec.compact_frac)
+        # slot pairs a block of the wave's tail covers (the cache's
+        # write-back and the split scan): hist_slots = the static form
+        reg.gauge("scan.block_slots").set(scan_block_pairs(
+            self.spec.hist_slots, *scan_hist_shape(
+                self.spec, self.comm, _hist_cols, self.bundle is not None)))
         # the routing pass's one-hot is booster.hist_slots wide, and every
         # row reads this many table columns through it
         reg.gauge("route.table_cols").set(

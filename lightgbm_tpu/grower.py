@@ -180,19 +180,28 @@ class WaveStats(NamedTuple):
     rows_split: jnp.ndarray     # i32 [W] rows of the leaves split this
                                 # wave: what routing and the partition
                                 # usefully move
-    scan_pending: jnp.ndarray   # i32 [W] of the 2 x hist_slots slots the
-                                # split scan runs over every wave, those
-                                # that held a leaf (a pending leaf or its
-                                # sibling by subtraction)
+    scan_pending: jnp.ndarray   # i32 [W] of the slots the wave's tail
+                                # covered, those that held a leaf (a
+                                # pending leaf or its sibling by
+                                # subtraction)
+    scan_slots: Optional[jnp.ndarray] = None  # i32 [W] slots the cache's
+                                # write-back and the split scan covered:
+                                # 2 x b x the blocks the loop ran. None
+                                # where the tail keeps its static form
+                                # (scan_block_pairs: a narrow table), 2 x
+                                # hist_slots every wave and nothing to
+                                # count: that program carries no counter
 
 
-def _empty_stats(L: int) -> WaveStats:
+def _empty_stats(L: int, blocked_tail: bool) -> WaveStats:
     W = max(L - 1, 1)
     return WaveStats(waves=jnp.asarray(0, jnp.int32),
                      rows_active=jnp.zeros(W, jnp.int32),
                      compacted=jnp.zeros(W, bool),
                      rows_split=jnp.zeros(W, jnp.int32),
-                     scan_pending=jnp.zeros(W, jnp.int32))
+                     scan_pending=jnp.zeros(W, jnp.int32),
+                     scan_slots=(jnp.zeros(W, jnp.int32) if blocked_tail
+                                 else None))
 
 
 def wave_totals(stats, rows_per_device: int, chunk_rows: int,
@@ -209,9 +218,9 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int,
     not count it). Routing and the partition pass over every row every
     wave (``rows_routed``); ``rows_split`` is the useful part of that.
     ``hist_chunks`` is the chunks the passes ran (each folds into the
-    accumulator once). The split scan runs over 2 x ``hist_slots`` slots
-    every wave (``scan_slots``); ``scan_slots_pending`` of them held a
-    leaf."""
+    accumulator once). ``scan_slots`` is the slots the waves' tails covered
+    (the loop's own count: the blocks it ran, or 2 x ``hist_slots`` a wave
+    in the static form); ``scan_slots_pending`` of them held a leaf."""
     waves = int(np.max(stats.waves))
 
     def per_wave(a, dtype):                              # -> [D, waves]
@@ -221,6 +230,10 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int,
     compacted = per_wave(stats.compacted, bool)
     split = per_wave(stats.rows_split, np.int64)
     scanned = per_wave(stats.scan_pending, np.int64)
+    # the static tail (no counter) covers every slot pair every wave
+    covered = (per_wave(stats.scan_slots, np.int64)
+               if stats.scan_slots is not None
+               else np.full_like(scanned, 2 * hist_slots))
     chunks = np.minimum(-(-np.maximum(active, 0) // chunk_rows),
                         rows_per_device // chunk_rows)
     touched = np.where(compacted, chunks * chunk_rows, rows_per_device)
@@ -237,7 +250,7 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int,
             "compact_passes": waves - streamed,
             "hist_rows_touched": int(touched.max(axis=0).sum()),
             "hist_chunks": int(touched.max(axis=0).sum()) // chunk_rows,
-            "scan_slots": waves * 2 * hist_slots,
+            "scan_slots": int(covered.max(axis=0).sum()),
             "scan_slots_pending": int(scanned.max(axis=0).sum()),
             "hist_rows_active": (int(active.max(axis=0).sum())
                                  if (active >= 0).all() else None),
@@ -411,6 +424,164 @@ def _empty_cand(L: int, B: int) -> SplitCandidates:
     )
 
 
+# ---- the wave's tail, over the slots the wave holds -------------------------
+# The pending leaves take slots 0 .. k-1 (_slots_of: the rank of a cumsum) and
+# k is 1, 1, 2, 4, 8, 16 and then S for a 255-leaf tree, while the cache's
+# subtract-and-write-back and the split scan are independent slot by slot. On
+# a WIDE table their arrays are large ([2S, F, B, 3] f32: 307 MB at 2,000
+# columns) and all S slot pairs at once cost 15-18 + 6.4 ms a wave on the v5e
+# whatever the wave holds, so they run over blocks of b slot pairs under one
+# loop of ceil(k / b) trips: 1.37 + 0.19 ms a block of 4 pairs there, a full
+# wave 9.4 + 1.3 ms and the first four waves 1.6 ms each (whole trees at
+# 400,000 x 2,000, seconds a tree: b = 1 3.544, 2 1.387, 3 1.391, 4 1.363,
+# 5 1.409, 7 1.425, 9 1.444, 13 1.502, all 25 at once 1.637; my chip run,
+# PR 35: a pair costs 0.39 ms in a block of 4 and 1.08 in one of 25). On a
+# narrow table the same arrays are a few MB, the tail's ~100 fusions are
+# launch-bound and every block would cost what the whole does: there b = S and
+# the tail keeps its static form, with no loop. b follows from the shapes: as
+# many slot pairs as bring one block's [2b, F, B, 3] f32 to
+# _SCAN_BLOCK_BYTES, in blocks of equal size.
+_SCAN_BLOCK_BYTES = 40 << 20
+
+
+def scan_block_pairs(hist_slots: int, features: int, bins: int) -> int:
+    """Slot pairs (a pending leaf and its sibling) a block of the wave's tail
+    covers, from the width of the histograms it scans (``features`` x
+    ``bins`` AFTER the reduce: a device's block): a static function of the
+    shapes. ``hist_slots`` = the static form, no loop (gauge
+    ``scan.block_slots``)."""
+    pair_bytes = 2 * int(features) * int(bins) * 3 * 4
+    want = -(-_SCAN_BLOCK_BYTES // pair_bytes)
+    if want >= hist_slots:
+        return int(hist_slots)
+    blocks = -(-hist_slots // want)
+    return -(-hist_slots // blocks)
+
+
+def _unbundles_early(spec: "GrowerSpec", comm, bundled: bool) -> bool:
+    """The LEGACY EFB arm (``tpu_efb_unpack``) under a row-sharded strategy
+    unpacks bundle-space histograms to feature space BEFORE the collective,
+    with the shard's own leaf totals; serial and bundled-block layouts
+    unpack at scan time."""
+    return (bundled and spec.efb_unpack
+            and getattr(comm, "axis", None) is not None
+            and not getattr(comm, "bundled_blocks", False))
+
+
+def scan_hist_shape(spec: "GrowerSpec", comm, hist_cols: int,
+                    bundled: bool) -> Tuple[int, int]:
+    """(features, bins) of the histograms a device caches and scans: what
+    ``comm.reduce_hist`` leaves of the ``hist_cols`` columns it histograms.
+    Bundle space under EFB, except where the legacy arm unpacks before the
+    collective."""
+    if _unbundles_early(spec, comm, bundled):
+        return (comm.reduced_hist_features(spec.num_features),
+                spec.num_bins_padded)
+    return (comm.reduced_hist_features(hist_cols),
+            spec.hist_bins or spec.num_bins_padded)
+
+
+def _scan_slot_pairs(state: "GrowState", hist, new_hist, leaves, bm, spec,
+                     comm, scan_bundle, default_bin, row_by_row: bool):
+    """Steps 3 and 4 for the slots that serve ``leaves`` [n] (L: none) and
+    built ``new_hist`` [n, F, B, 3]: the siblings by subtraction from the
+    parents' rows of the cache ``hist``, both written back, and this
+    device's scan of the 2n histograms (``comm.scan_block``: no collective).
+    Returns (cache, scanned leaves [2n], their histograms, their leaf sums,
+    the scan).
+
+    ``row_by_row`` reads the parents' rows as n slices of the cache and not
+    as one gather: the v5e's gather first copies the WHOLE cache, in column
+    pieces, whatever it fetches (4.7 ms of the 1.57 GB cache at 2,000
+    columns for 25 rows or for 5: my chip run, PR 35), which a block of a
+    few slots cannot afford and a narrow table's small cache does not
+    notice."""
+    L = spec.num_leaves
+    # "wave.cache": the parent read, the subtraction and the two write-backs
+    # of the [L+1, F, B, 3] cache, [n, F, B, 3] each (154 MB for 25 slots at
+    # 2,000 columns), named apart from the scan they feed
+    slot_valid = leaves < L
+    sibs = state.sib_leaf[leaves]                             # [n]
+    with jax.named_scope("wave.cache"):
+        parent_rows = state.parent_cache[leaves]              # [n]
+        if row_by_row:
+            parent_hist = jnp.stack([
+                jax.lax.dynamic_index_in_dim(hist, parent_rows[j],
+                                             keepdims=False)
+                for j in range(parent_rows.shape[0])])
+        else:
+            parent_hist = hist[parent_rows]                   # [n, F, B, 3]
+        sib_hist = parent_hist - new_hist
+        hist = hist.at[jnp.where(slot_valid, leaves, L)].set(new_hist)
+        hist = hist.at[jnp.where(slot_valid, sibs, L)].set(sib_hist)
+
+    scan_leaves = jnp.concatenate([leaves, jnp.where(slot_valid, sibs, L)])
+    scan_hist = jnp.concatenate([new_hist, sib_hist], axis=0)  # [2n, F, B, 3]
+    find_bundle = None
+    if scan_bundle is not None:
+        if spec.efb_unpack:
+            # legacy arm: materialize the [2n, F, B, 3] feature-space
+            # decode (the gather the native path exists to delete)
+            scan_hist = _unpack_bundled(
+                scan_hist, scan_bundle, state.sum_g[scan_leaves],
+                state.sum_h[scan_leaves], state.cnt[scan_leaves], default_bin)
+        else:
+            find_bundle = scan_bundle
+    sums = (state.sum_g[scan_leaves], state.sum_h[scan_leaves],
+            state.cnt[scan_leaves])
+    local = comm.scan_block(scan_hist, *sums, bm, spec, bundle=find_bundle)
+    return hist, scan_leaves, scan_hist, sums, local
+
+
+def _scan_held_slots(state: "GrowState", new_hist, leaf_of_slot, b: int, bm,
+                     spec, comm, scan_bundle, default_bin):
+    """``_scan_slot_pairs`` over the occupied prefix of the wave's slots, b
+    slot pairs a trip: one copy of the scan in the executable, the cache
+    carried through the loop and written in place. Slots past the last
+    block are not read, subtracted, written or scanned: they serve no leaf,
+    and their rows of the scan stay inert (gain -inf, zeros). Returns
+    (cache, scanned leaves [2S], the scan [2S, ...], slots covered)."""
+    L, S = spec.num_leaves, spec.hist_slots
+    held = jnp.sum((leaf_of_slot < L).astype(jnp.int32))
+    trips = (held + (b - 1)) // b
+
+    def block(i, hist):
+        # the last block of an S that b does not divide starts early
+        # (dynamic_slice clamps): the slots the block before it covered
+        # are served as slots of no leaf
+        start = jnp.minimum(i * b, S - b)
+        slot = start + jnp.arange(b, dtype=jnp.int32)
+        fresh = slot >= i * b
+        leaves = jnp.where(
+            fresh, jax.lax.dynamic_slice_in_dim(leaf_of_slot, start, b), L)
+        hist, _, _, _, local = _scan_slot_pairs(
+            state, hist, jax.lax.dynamic_slice_in_dim(new_hist, start, b),
+            leaves, bm, spec, comm, scan_bundle, default_bin, row_by_row=True)
+        # the block's rows of the [2S] scan: the leaves, then their siblings
+        rows = jnp.where(jnp.concatenate([fresh, fresh]),
+                         jnp.concatenate([slot, slot + S]), 2 * S)
+        return hist, local, rows
+
+    local0 = jax.tree.map(
+        lambda a: jnp.zeros((2 * S,) + a.shape[1:], a.dtype),
+        jax.eval_shape(lambda: block(jnp.int32(0), state.hist)[1]))
+    local0 = local0._replace(gain=jnp.full_like(local0.gain, NEG_INF))
+
+    def next_block(carry):
+        i, hist, local = carry
+        hist, found, rows = block(i, hist)
+        local = jax.tree.map(
+            lambda all_, new: all_.at[rows].set(new, mode="drop"), local, found)
+        return i + 1, hist, local
+
+    _, hist, local = jax.lax.while_loop(
+        lambda carry: carry[0] < trips, next_block,
+        (jnp.int32(0), state.hist, local0))
+    scan_leaves = jnp.concatenate([leaf_of_slot, jnp.where(
+        leaf_of_slot < L, state.sib_leaf[leaf_of_slot], L)])
+    return hist, scan_leaves, local, 2 * b * trips
+
+
 @jax.named_scope("wave.split")
 def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
                        leaf_of_slot: jnp.ndarray, bm, spec: "GrowerSpec",
@@ -433,7 +604,7 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     EFB decode table when the histograms are bundle-space — with
     ``spec.efb_unpack`` the LEGACY arm unpacks them to feature space here
     (serial / bundled-block layouts), otherwise the scan runs natively on
-    bundle space (comm.find_splits -> per_feature_best_bundled) and only
+    bundle space (comm.scan_block -> per_feature_best_bundled) and only
     the winning (bundled column, bundle bin) is translated back to
     (original feature, original bin) — the reference's FeatureGroup
     discipline. ``route_bundle`` (native arm only, GLOBAL tables) extends
@@ -441,10 +612,11 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     routing pass compares bundled codes directly instead of gathering a
     per-row decode.
 
-    Returns ``(state', table, map_mask, n_apply)`` with ``state'``
-    carrying every field EXCEPT the per-row ones (leaf_id and the next
-    wave's slot of each row), which the caller owns and the routing pass
-    writes.
+    Returns ``(state', table, map_mask, n_apply, scan_slots)`` with
+    ``state'`` carrying every field EXCEPT the per-row ones (leaf_id and the
+    next wave's slot of each row), which the caller owns and the routing
+    pass writes; ``scan_slots`` is the slots steps 3-4 covered this wave
+    (None in the static form: all 2S, whatever the wave held).
     """
     L = spec.num_leaves
     M = L - 1
@@ -452,40 +624,31 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
     B = spec.num_bins_padded
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
 
-    # ---- 3. cache write + sibling by subtraction -----------------------
-    # "wave.cache": the parent read, the subtraction and the two write-backs
-    # of the [L+1, F, B, 3] cache, [S, F, B, 3] each (154 MB a wave at
-    # 2,000 columns), named apart from the scan they feed
-    slot_valid = leaf_of_slot < L
-    sibs = state.sib_leaf[leaf_of_slot]                       # [S]
-    with jax.named_scope("wave.cache"):
-        parent_rows = state.parent_cache[leaf_of_slot]        # [S]
-        parent_hist = state.hist[parent_rows]                 # [S, F, B, 3]
-        sib_hist = parent_hist - new_hist
-        hist = state.hist
-        hist = hist.at[jnp.where(slot_valid, leaf_of_slot, L)].set(new_hist)
-        hist = hist.at[jnp.where(slot_valid, sibs, L)].set(sib_hist)
-
-    # ---- 4. split scan for the 2S touched leaves -----------------------
-    scan_leaves = jnp.concatenate([leaf_of_slot, jnp.where(slot_valid, sibs, L)])
-    scan_hist = jnp.concatenate([new_hist, sib_hist], axis=0)  # [2S, F, B, 3]
-    find_bundle = None
-    if scan_bundle is not None:
-        if spec.efb_unpack:
-            # legacy arm: materialize the [2S, F, B, 3] feature-space
-            # decode (the gather the native path exists to delete)
-            scan_hist = _unpack_bundled(
-                scan_hist, scan_bundle, state.sum_g[scan_leaves],
-                state.sum_h[scan_leaves], state.cnt[scan_leaves], default_bin)
-        else:
-            find_bundle = scan_bundle
+    # ---- 3-4. cache write + sibling by subtraction, split scan ----------
+    # over the slots the wave holds, in blocks sized by the table's width
+    # (scan_block_pairs); a narrow table takes all S slot pairs at once
+    b = scan_block_pairs(S, *new_hist.shape[1:3])
+    if b >= S:
+        hist, scan_leaves, scan_hist, sums, local = _scan_slot_pairs(
+            state, state.hist, new_hist, leaf_of_slot, bm, spec, comm,
+            scan_bundle, default_bin, row_by_row=False)
+        pick_cols = lambda cols: jnp.take_along_axis(       # noqa: E731
+            scan_hist, cols[:, :, None, None], axis=1)
+        scan_slots = None
+    else:
+        hist, scan_leaves, local, scan_slots = _scan_held_slots(
+            state, new_hist, leaf_of_slot, b, bm, spec, comm, scan_bundle,
+            default_bin)
+        sums = tuple(a[scan_leaves]
+                     for a in (state.sum_g, state.sum_h, state.cnt))
+        # a slot's histograms are its leaves' rows of the cache by now
+        pick_cols = lambda cols: hist[scan_leaves[:, None], cols]  # noqa: E731
     # candidate features are GLOBAL indices; under feature/data
     # parallelism this ends in an all-gather argmax across devices
     # (reference SyncUpGlobalBestSplit, parallel_tree_learner.h:184-207)
-    cand_new = comm.find_splits(
-        scan_hist,
-        state.sum_g[scan_leaves], state.sum_h[scan_leaves], state.cnt[scan_leaves],
-        bm, spec, bundle=find_bundle)
+    cand_new = comm.sync_splits(
+        local, pick_cols, *sums, bm, spec,
+        bundle=None if spec.efb_unpack else scan_bundle)
     cand = SplitCandidates(*[
         old.at[scan_leaves].set(new) for old, new in zip(state.cand, cand_new)])
     cand = cand._replace(gain=cand.gain.at[L].set(NEG_INF))  # keep scratch row inert
@@ -617,7 +780,7 @@ def _apply_wave_splits(state: GrowState, new_hist: jnp.ndarray,
         leaf_depth=leaf_depth, leaf_is_right=leaf_is_right, cand=cand,
         needs_hist=needs_hist, sib_leaf=sib_leaf, parent_cache=parent_cache,
         num_leaves_cur=state.num_leaves_cur + n_apply, done=done)
-    return state2, table, map_mask, n_apply
+    return state2, table, map_mask, n_apply, scan_slots
 
 
 @trace_entry("routing.bundle_space")
@@ -777,19 +940,12 @@ def grow_tree(
     # to original feature space — serial/bundled-block layouts at scan
     # time, row-sharded strategies BEFORE the collective using this shard's
     # leaf totals (feature blocks stay contiguous through the psum_scatter).
-    unbundle_early = (bundle is not None and spec.efb_unpack
-                      and getattr(comm, "axis", None) is not None
-                      and not getattr(comm, "bundled_blocks", False))
+    unbundle_early = _unbundles_early(spec, comm, bundle is not None)
     scan_bundle = bundle
     if bundle is not None and getattr(comm, "bundled_blocks", False):
         scan_bundle = comm.localize_bundle(bundle)
     B_hist = spec.hist_bins or B  # bundle-space bin axis (build side)
-    if unbundle_early:
-        F_cache = comm.reduced_hist_features(spec.num_features)
-        B_cache = B
-    else:
-        F_cache = comm.reduced_hist_features(F_hist)
-        B_cache = B_hist
+    F_cache, B_cache = scan_hist_shape(spec, comm, F_hist, bundle is not None)
     bm = comm.block_meta(feature_ok, num_bins, missing_code, default_bin, is_cat)
 
     with jax.named_scope("tree.root_sums"):
@@ -831,7 +987,7 @@ def grow_tree(
         done=jnp.asarray(False),
         # the root is pending in slot 0, and every row is in it
         slot_row=jnp.zeros(N, jnp.int32) if spec.row_compact else None,
-        stats=_empty_stats(L),
+        stats=_empty_stats(L, scan_block_pairs(S, F_cache, B_cache) < S),
     )
 
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
@@ -947,7 +1103,7 @@ def grow_tree(
             new_hist = comm.reduce_hist(new_hist)
 
         # ---- 3-6 + routing table: the shared wave tail ---------------------
-        state2, table, map_mask, _n_apply = _apply_wave_splits(
+        state2, table, map_mask, _n_apply, scan_slots = _apply_wave_splits(
             state, new_hist, leaf_of_slot, bm, spec, comm,
             scan_bundle if (bundle is not None and not unbundle_early)
             else None, num_bins, missing_code, default_bin,
@@ -977,7 +1133,9 @@ def grow_tree(
                 rows_active=st.rows_active.at[st.waves].set(n_active),
                 compacted=st.compacted.at[st.waves].set(compacted),
                 rows_split=st.rows_split.at[st.waves].set(rows_split),
-                scan_pending=st.scan_pending.at[st.waves].set(scan_pending))
+                scan_pending=st.scan_pending.at[st.waves].set(scan_pending),
+                scan_slots=(None if scan_slots is None else
+                            st.scan_slots.at[st.waves].set(scan_slots)))
 
         return state2._replace(leaf_id=leaf_id, slot_row=slot_row_next,
                                stats=stats)
@@ -1126,10 +1284,8 @@ class StreamedGrower:
         # space end-to-end (data-parallel reduces bundle-column blocks);
         # the legacy unpack arm (spec.efb_unpack) unpacks BEFORE the
         # collective under row-sharded strategies, at scan time serially
-        self.unbundle_early = (bundle is not None and spec.efb_unpack
-                               and getattr(self.comm, "axis", None) is not None
-                               and not getattr(self.comm, "bundled_blocks",
-                                               False))
+        self.unbundle_early = _unbundles_early(spec, self.comm,
+                                               bundle is not None)
         assert pctx is None or pctx.strategy != "feature", \
             "streamed growth does not run under feature-parallel bundling"
         self._mesh = pctx.mesh if pctx is not None else None
@@ -1174,12 +1330,8 @@ class StreamedGrower:
         from .ops.histogram import (build_histograms, finalize_histograms,
                                     unpack_codes)
 
-        if self.unbundle_early:
-            F_cache = comm.reduced_hist_features(spec.num_features)
-            B_cache = B
-        else:
-            F_cache = comm.reduced_hist_features(F_cols)
-            B_cache = B_hist
+        F_cache, B_cache = scan_hist_shape(spec, comm, F_cols,
+                                           bundle is not None)
 
         def init_body(grad, hess, included):
             rg, rh, rc = comm.reduce_scalars(
@@ -1292,7 +1444,7 @@ class StreamedGrower:
                 scan_bundle = (comm.localize_bundle(bundle)
                                if getattr(comm, "bundled_blocks", False)
                                else bundle)
-            state2, table, map_mask, n_apply = _apply_wave_splits(
+            state2, table, map_mask, n_apply, _ = _apply_wave_splits(
                 state, new_hist, leaf_of_slot, bm, spec, comm, scan_bundle,
                 self.num_bins, self.missing_code, self.default_bin,
                 route_bundle=_route_bundle(spec, bundle))

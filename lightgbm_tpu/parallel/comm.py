@@ -130,6 +130,26 @@ def find_block_splits(hist, pg, ph, pc, bm: BlockMeta, spec,
     return reduce_features(pf, bm.offset, is_cat=bm.is_cat, cat_mask=mask)
 
 
+# ---- a wave's split search in two halves -----------------------------------
+# The grower finds a wave's splits as ``sync_splits(scan_block(...))``:
+#   scan_block   the scan of this device's block, slot by slot, NO collective:
+#                the slot axis is a batch axis, so the grower may run it over
+#                a few slots at a time (grower._apply_wave_splits' blocks)
+#   sync_splits  what crosses devices, always over all 2 x hist_slots slots at
+#                once: the candidates' all-gather argmax, PV-Tree's votes and
+#                the psum of the voted columns (``pick_cols(cols [T, k]) ->
+#                [T, k, B, 3]`` hands it those columns of each slot's local
+#                histogram, from wherever the caller keeps them)
+# Both halves carry a ``gain`` leaf; a slot nobody scanned holds -inf there
+# and zeros elsewhere, and loses every argmax and every vote.
+
+
+class LocalGains(NamedTuple):
+    """VotingParallelComm.scan_block's result: the local scan's best gain per
+    (slot, feature), before any vote."""
+    gain: jnp.ndarray             # f32 [T, F]
+
+
 # serialized size of one slot's SplitCandidates leaves (the all-gather
 # argmax payload): gain/left_g/left_h/left_c f32 + feature/threshold i32 +
 # default_left/is_cat bool + the [B] bool cat_mask — the analog of the
@@ -188,9 +208,14 @@ class SerialComm:
         return BlockMeta(feature_ok, num_bins, missing_code, default_bin,
                          is_cat, jnp.asarray(0, jnp.int32))
 
-    def find_splits(self, hist, pg, ph, pc, bm: BlockMeta, spec,
-                    bundle=None) -> SplitCandidates:
+    def scan_block(self, hist, pg, ph, pc, bm: BlockMeta, spec, bundle=None):
+        """This device's scan of ``hist`` [T, F_block, B, 3], slot by slot."""
         return find_block_splits(hist, pg, ph, pc, bm, spec, bundle)
+
+    def sync_splits(self, local, pick_cols, pg, ph, pc, bm: BlockMeta, spec,
+                    bundle=None) -> SplitCandidates:
+        """The devices' scans -> every slot's global best split."""
+        return local
 
     def collective_bytes(self, num_slots: int, num_bins_padded: int,
                          use_categorical: bool = True,
@@ -267,10 +292,11 @@ class DataParallelComm:
             _block_slice(missing_code, i, b), _block_slice(default_bin, i, b),
             _block_slice(is_cat, i, b), i * b)
 
-    def find_splits(self, hist, pg, ph, pc, bm: BlockMeta, spec,
+    scan_block = SerialComm.scan_block
+
+    def sync_splits(self, local, pick_cols, pg, ph, pc, bm: BlockMeta, spec,
                     bundle=None) -> SplitCandidates:
-        return _gather_argmax(find_block_splits(hist, pg, ph, pc, bm, spec,
-                                                bundle), self.axis)
+        return _gather_argmax(local, self.axis)
 
     def collective_bytes(self, num_slots: int, num_bins_padded: int,
                          use_categorical: bool = True,
@@ -324,7 +350,8 @@ class FeatureParallelComm:
 
     reduced_hist_features = SerialComm.reduced_hist_features
     block_meta = DataParallelComm.block_meta
-    find_splits = DataParallelComm.find_splits
+    scan_block = SerialComm.scan_block
+    sync_splits = DataParallelComm.sync_splits
 
     def collective_bytes(self, num_slots: int, num_bins_padded: int,
                          use_categorical: bool = True,
@@ -401,10 +428,8 @@ class FeatureParallelBundledComm:
             code_feat=jax.lax.dynamic_slice_in_dim(
                 bundle.code_feat, i * self.block, self.block, axis=0))
 
-    def find_splits(self, hist, pg, ph, pc, bm: BlockMeta, spec,
-                    bundle=None) -> SplitCandidates:
-        return _gather_argmax(find_block_splits(hist, pg, ph, pc, bm, spec,
-                                                bundle), self.axis)
+    scan_block = SerialComm.scan_block
+    sync_splits = DataParallelComm.sync_splits
 
     def collective_bytes(self, num_slots: int, num_bins_padded: int,
                          use_categorical: bool = True,
@@ -479,10 +504,8 @@ class DataParallelBundledComm:
 
     localize_bundle = FeatureParallelBundledComm.localize_bundle
 
-    def find_splits(self, hist, pg, ph, pc, bm: BlockMeta, spec,
-                    bundle=None) -> SplitCandidates:
-        return _gather_argmax(find_block_splits(hist, pg, ph, pc, bm, spec,
-                                                bundle), self.axis)
+    scan_block = SerialComm.scan_block
+    sync_splits = DataParallelComm.sync_splits
 
     def collective_bytes(self, num_slots: int, num_bins_padded: int,
                          use_categorical: bool = True,
@@ -527,15 +550,9 @@ class VotingParallelComm:
         return BlockMeta(feature_ok, num_bins, missing_code, default_bin,
                          is_cat, jnp.asarray(0, jnp.int32))
 
-    def find_splits(self, hist, pg, ph, pc, bm: BlockMeta, spec,
-                    bundle=None) -> SplitCandidates:
+    def scan_block(self, hist, pg, ph, pc, bm: BlockMeta, spec,
+                   bundle=None) -> LocalGains:
         import dataclasses
-
-        S = hist.shape[0]
-        F = self.num_features
-        B = hist.shape[2]
-        k = max(1, min(self.top_k, F))
-        k2 = min(2 * k, F)
 
         # Phase 1 — local proposals from LOCAL leaf sums (the histogram here
         # is this device's un-reduced partial, so its bin sums ARE the local
@@ -553,7 +570,16 @@ class VotingParallelComm:
                                      / self.num_devices))
         pf_local, _ = block_per_feature(hist, local_pg, local_ph, local_pc,
                                         bm, local_spec, bundle)
-        local_gain = pf_local.gain
+        return LocalGains(pf_local.gain)
+
+    def sync_splits(self, local: LocalGains, pick_cols, pg, ph, pc,
+                    bm: BlockMeta, spec, bundle=None) -> SplitCandidates:
+        local_gain = local.gain
+        S = local_gain.shape[0]
+        F = self.num_features
+        k = max(1, min(self.top_k, F))
+        k2 = min(2 * k, F)
+
         top_gain, top_feat = jax.lax.top_k(local_gain, k)           # [S, k]
         votes = jnp.zeros((S, F), jnp.float32).at[
             jnp.arange(S)[:, None], top_feat].add(
@@ -582,10 +608,9 @@ class VotingParallelComm:
             # gathered column (a per-slot one-member bundle view; the
             # default-bin hole at off+db stays unowned so the FixHistogram
             # deficit reconstructs it exactly like the global scan)
-            Bb = hist.shape[2]
             sel_col = jnp.asarray(bundle.col)[sel]                  # [S, k2]
-            sel_hist = jnp.take_along_axis(
-                hist, sel_col[:, :, None, None], axis=1)            # [S,k2,Bb,3]
+            sel_hist = pick_cols(sel_col)                           # [S,k2,Bb,3]
+            Bb = sel_hist.shape[2]
             sel_hist = jax.lax.psum(sel_hist, self.axis)
             iota_c = jnp.arange(Bb, dtype=jnp.int32)
             jidx = jnp.arange(k2, dtype=jnp.int32)
@@ -609,8 +634,7 @@ class VotingParallelComm:
                 bm.num_bins[sel], bm.missing_code[sel], bm.default_bin[sel],
                 bm.feature_ok[sel] & ~bm.is_cat[sel], pg, ph, pc)
         else:
-            sel_hist = jnp.take_along_axis(
-                hist, sel[:, :, None, None], axis=1)                # [S, k2, B, 3]
+            sel_hist = pick_cols(sel)                               # [S, k2, B, 3]
             sel_hist = jax.lax.psum(sel_hist, self.axis)
 
             # Per-slot feature metadata: vmap the scan over slots since
@@ -637,7 +661,7 @@ class VotingParallelComm:
         columns reduce (CopyLocalHistogram,
         voting_parallel_tree_learner.cpp:197) — compare psum_selected_hist
         here against DataParallelComm's full psum_scatter_hist. Every one
-        of these runs inside ``find_splits``, whose slot axis is the
+        of these runs inside ``sync_splits``, whose slot axis is the
         2*num_slots slot+sibling scan (grower.py step 4). Under the native
         EFB arm the selected columns are BUNDLE columns, so their psum is
         ``hist_bins`` (Bb) wide — the bundled-run fix for an estimate that
@@ -770,7 +794,7 @@ class ParallelContext:
         partition unit there, both EFB arms), and for data-parallel only on
         the native bundle-space arm (the legacy unpack arm reduces
         feature-space histograms through the plain DataParallelComm).
-        Voting needs no bundled twin — its ``find_splits`` branches on the
+        Voting needs no bundled twin — its two halves branch on the
         per-call ``bundle`` tables."""
         if self.strategy == "data":
             if num_bundles:

@@ -250,6 +250,24 @@ def dp_step(v5e_chips):
     """``tree_learner=data`` over four devices, as the benchmark's four-chip
     cell runs it: (the step traced, as a jaxpr; the step compiled for the four
     described chips at ``DP_ROWS`` rows a device, as text)."""
+    return _dp_step(v5e_chips)
+
+
+@pytest.fixture(scope="module")
+def dp_step_blocked_tail(v5e_chips):
+    """The same step with the wave's tail in blocks of three slot pairs, as a
+    wide table runs it (``grower.scan_block_pairs``: the constant is moved to
+    put this 16-column table on the wide side)."""
+    from lightgbm_tpu import grower
+    was = grower._SCAN_BLOCK_BYTES
+    grower._SCAN_BLOCK_BYTES = 3 * 2 * 4 * 256 * 12      # a shard scans 4 columns
+    try:
+        return _dp_step(v5e_chips)
+    finally:
+        grower._SCAN_BLOCK_BYTES = was
+
+
+def _dp_step(v5e_chips):
     from jax.sharding import NamedSharding
     from lightgbm_tpu.parallel.comm import ParallelContext
     rng = np.random.RandomState(3)
@@ -367,3 +385,58 @@ def test_dp_compiled_step_exchanges_nothing_row_sized(dp_step):
     sorts = _row_sized(hlo, DP_ROWS, "sort")
     assert len(sorts) == 1 and _IN_COMPACT_ARM.search(sorts[0]), sorts
     assert not _row_sized(hlo, DP_ROWS, "scatter")
+
+
+def test_dp_blocked_tail_holds_no_collective(dp_step_blocked_tail):
+    """The wave's tail in blocks (a wide table): ONE loop under ``wave.split``
+    whose body is the cache's write-back and the scan of a few slot pairs, and
+    nothing that crosses devices inside it. The reduce-scatter of all the
+    wave's histograms and the candidates' all-gather keep their static shape
+    outside, so a shard whose wave holds fewer leaves waits for nobody in the
+    middle of a collective."""
+    jaxpr, hlo = dp_step_blocked_tail
+
+    def loops(jaxpr, under=()):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "while":
+                yield eqn, under
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list)) else (value,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from loops(sub, under + (eqn,))
+
+    tails = [eqn for eqn, under in loops(jaxpr.jaxpr)
+             if "wave.split" in str(eqn.source_info.name_stack).split("/")
+             and any(e.primitive.name == "while" for e in under)]
+    assert len(tails) == 1, [str(e.source_info.name_stack) for e in tails]
+    inside = [e.primitive.name for e, _ in _walk(tails[0].params["body_jaxpr"].jaxpr)]
+    assert "scatter" in inside and "cumsum" in inside      # write-back, scan
+    assert not set(inside) & set(COLLECTIVE_PRIMITIVES)
+    # the collectives are all still there, outside it
+    found = [str(eqn.source_info.name_stack) for eqn, _ in _walk(jaxpr.jaxpr)
+             if eqn.primitive.name in COLLECTIVE_PRIMITIVES]
+    for scope in COLLECTIVE_SCOPES:
+        assert any(scope in stack.split("/") for stack in found), scope
+
+    # and in the program compiled for the four chips: the tail's loop, and
+    # every computation it calls
+    comps = _computations(hlo)
+    heads = [ln for lines in comps.values() for ln in lines
+             if " while(" in ln
+             and re.search(r'op_name="[^"]*/while/body/wave\.split/while"', ln)]
+    assert len(heads) == 1, heads
+    todo = re.findall(r"(?:body|condition)=%([\w.\-]+)", heads[0])
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        assert not _collective_lines(comps[name]), name
+        for ln in comps[name]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", ln)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", ln):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    assert len(seen) > 2
+    assert len(_collective_lines(hlo.splitlines())) >= 3

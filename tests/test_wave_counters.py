@@ -109,15 +109,27 @@ def _replay(tree, Xb, real, L, frac):
                 scan_pending=scanned)
 
 
-@pytest.mark.parametrize("frac,min_leaf,width", [
-    (0.5, 5, NARROW), (1.0, 5, NARROW), (1e-9, 5, NARROW), (0.25, 400, NARROW),
-    (0.0, 5, NARROW), (0.0, 5, WIDE)],
+@pytest.mark.parametrize("frac,min_leaf,width,tail_pairs", [
+    (0.5, 5, NARROW, 0), (1.0, 5, NARROW, 0), (1e-9, 5, NARROW, 0),
+    (0.25, 400, NARROW, 0), (0.0, 5, NARROW, 0), (0.0, 5, WIDE, 0),
+    (0.5, 5, NARROW, 1), (0.25, 400, NARROW, 3), (0.0, 5, WIDE, 4)],
     ids=["mixed", "all-compact", "all-stream", "stops-on-gain",
-         "auto-narrow", "auto-wide"])
-def test_counters_equal_numpy_replay(clean_registry, frac, min_leaf, width):
+         "auto-narrow", "auto-wide", "mixed-tail-by-1",
+         "stops-on-gain-tail-by-3", "auto-wide-tail-by-4"])
+def test_counters_equal_numpy_replay(clean_registry, monkeypatch, frac,
+                                     min_leaf, width, tail_pairs):
     """``frac`` 0 is the default: the rule of ops/histogram.py sets the
     threshold from the table's width. A wide table streams its root and
-    nothing else; a narrow one keeps streaming its early waves."""
+    nothing else; a narrow one keeps streaming its early waves.
+    ``tail_pairs`` > 0 puts the table on the wide side of
+    ``grower.scan_block_pairs``: the wave's tail runs in blocks of that many
+    slot pairs and counts the slots it covered; 0 leaves these small tables
+    on the narrow side, the static tail, which counts nothing."""
+    from lightgbm_tpu import grower
+    if tail_pairs:
+        monkeypatch.setattr(
+            grower, "_SCAN_BLOCK_BYTES",
+            tail_pairs * 2 * width["f"] * (width["max_bin"] + 1) * 12)
     params = dict(BASE, tpu_compact_frac=frac, min_data_in_leaf=min_leaf,
                   max_bin=width["max_bin"])
     auto = frac == 0.0
@@ -144,6 +156,16 @@ def test_counters_equal_numpy_replay(clean_registry, frac, min_leaf, width):
         assert st.rows_split[:w].tolist() == want["rows_split"]
         assert st.compacted[:w].tolist() == want["compacted"]
         assert st.scan_pending[:w].tolist() == want["scan_pending"]
+        if tail_pairs:
+            # the loop's own count: whole blocks over the pending leaves
+            b = grower.scan_block_pairs(g.spec.hist_slots, g.Xb.shape[1],
+                                        g.spec.num_bins_padded)
+            assert 1 <= b < g.spec.hist_slots
+            pending = [max(1, held // 2) for held in want["scan_pending"]]
+            assert st.scan_slots[:w].tolist() == \
+                [2 * b * -(-k // b) for k in pending]
+        else:
+            assert st.scan_slots is None
         assert int(rec.num_leaves[0]) == int(tree.num_leaves)
         seen["compact"] += sum(want["compacted"])
         seen["stream"] += w - sum(want["compacted"])
