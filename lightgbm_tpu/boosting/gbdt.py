@@ -76,6 +76,22 @@ class ValidSet(MetadataDuckTyping):
 from ..analysis.contracts.registry import trace_entry
 
 
+class SampleStats(NamedTuple):
+    """What a step's row sample held, counted on the device where the sample
+    is drawn (int32 scalars; published per tree as ``sample.rows_top``,
+    ``sample.rows_other``, ``sample.rows_in``)."""
+    rows_top: jnp.ndarray      # rows kept for their gradient (GOSS's top set)
+    rows_other: jnp.ndarray    # rows kept by a uniform draw (GOSS's other
+                               # set; every row of a bag)
+    rows_in: jnp.ndarray       # rows the tree is grown on
+
+    @classmethod
+    def count(cls, is_top, is_other, mask) -> "SampleStats":
+        def rows(a):
+            return jnp.sum((a > 0).astype(jnp.int32))
+        return cls(rows(is_top), rows(is_other), rows(mask))
+
+
 @trace_entry("train_step.fused")
 class GrowRecord(NamedTuple):
     """What one iteration leaves on the device beside its trees, until the
@@ -87,6 +103,9 @@ class GrowRecord(NamedTuple):
     stats: Optional[WaveStats]        # leading axes [K, D]; None where the
                                       # loop did not run in this process's
                                       # step (checkpoint restore, streaming)
+    sample: Optional[SampleStats] = None  # None where the step draws no row
+                                      # sample: that program carries no
+                                      # counter
 
 
 def _abstract_signature(args) -> Dict[str, str]:
@@ -1210,11 +1229,22 @@ class GBDT:
         new_mask = bern.astype(jnp.float32) * self.pad_mask
         return jnp.where(resample, new_mask, prev_mask)
 
+    @property
+    def samples_rows(self) -> bool:
+        """Whether the step draws a row sample: static for a compiled step
+        (bagging is on; GOSS always). Such a step hands the grower its mask
+        as the histogram's row set (``grow_tree(sampled=True)``) and counts
+        the sample (``SampleStats``); any other step is the program it was."""
+        return self.bagging_on
+
     def _sampling(self, g, h, bag_mask, key, it):
-        """Row-sampling hook: returns (mask, g, h). Base = bagging; GOSS
-        overrides with gradient-based one-side sampling (goss.hpp:86-131)."""
+        """Row-sampling hook: returns (mask, g, h, SampleStats or None).
+        Base = bagging; GOSS overrides with gradient-based one-side sampling
+        (goss.hpp:86-131)."""
         mask = self._bag_mask_for_iter(key, it, bag_mask)
-        return mask, g, h
+        if not self.samples_rows:
+            return mask, g, h, None
+        return mask, g, h, SampleStats.count(jnp.zeros((), bool), mask, mask)
 
     def _tree_output_transform(self, tree):
         """Hook: RF converts leaf outputs via the objective (rf.hpp:160-167)."""
@@ -1340,6 +1370,8 @@ class GBDT:
         comm = self.comm
         linear_tree = self.linear_tree    # static per booster
 
+        sampled = self.samples_rows       # static: the mask is the
+                                          # histogram's row set
         bundle = self.bundle              # EFB: native arm scans/routes in
                                           # bundle space end-to-end; legacy
                                           # tpu_efb_unpack unpacks before
@@ -1347,7 +1379,7 @@ class GBDT:
 
         def grow_fn(X, g, h, inc, fok, iscat, nb, mc, db):
             return grow_tree(X, g, h, inc, fok, iscat, nb, mc, db, spec, comm,
-                             bundle=bundle)
+                             bundle=bundle, sampled=sampled)
 
         grow = self.pctx.shard_grow(grow_fn)
 
@@ -1432,7 +1464,7 @@ class GBDT:
                         g, h = clip_nonfinite(g), clip_nonfinite(h)
             bkey, fkey = jax.random.split(jax.random.fold_in(key, 0))
             with jax.named_scope("step.sampling"):
-                mask, g, h = self._sampling(g, h, bag_mask, bkey, it)
+                mask, g, h, sample = self._sampling(g, h, bag_mask, bkey, it)
             trees = []
             nleaves = []
             wave_stats = []
@@ -1476,7 +1508,7 @@ class GBDT:
             out_valid = tuple(tuple(v) for v in new_valid)
             record = GrowRecord(
                 jnp.stack(nleaves),
-                jax.tree.map(lambda *a: jnp.stack(a), *wave_stats))
+                jax.tree.map(lambda *a: jnp.stack(a), *wave_stats), sample)
             if nan_policy == "none":
                 return (out_score, out_valid, mask, tuple(trees),
                         record, it + 1)
@@ -1749,7 +1781,7 @@ class GBDT:
                         g, h = clip_nonfinite(g), clip_nonfinite(h)
                     bad = (bad_g, bad_h)
                 bkey, fkey = jax.random.split(jax.random.fold_in(key, 0))
-                mask, g, h = self._sampling(g, h, bag_mask, bkey, it)
+                mask, g, h, _ = self._sampling(g, h, bag_mask, bkey, it)
                 return (g, h, mask, fkey) + bad
             return pre_body
 
@@ -2453,7 +2485,9 @@ class GBDT:
                                    for sh in a.addressable_shards], axis=1)
         return [GrowRecord(np.asarray(r.num_leaves),
                            None if r.stats is None
-                           else jax.tree.map(local_rows, r.stats))
+                           else jax.tree.map(local_rows, r.stats),
+                           None if r.sample is None
+                           else jax.tree.map(np.asarray, r.sample))
                 for r in records]
 
     def _publish_grow_records(self, host_records: List[GrowRecord]) -> None:
@@ -2469,9 +2503,12 @@ class GBDT:
         wrote: every chunk folds into it once), ``grow.scan_slots`` and
         ``grow.scan_slots_pending``, and the counters ``rows.routed`` and
         ``hist.mxu_flops`` /
-        ``hist.floor_flops``. Under a row-sharded mesh every row count is
-        the pace-setting shard's (per-wave maximum over devices): compare
-        with the rows of ONE device."""
+        ``hist.floor_flops``; from a step that draws a row sample also
+        ``sample.rows_top``, ``sample.rows_other``, ``sample.rows_in`` (the
+        step's own count of its sample, ``SampleStats``). Under a
+        row-sharded mesh every ``grow.*`` row count is the pace-setting
+        shard's (per-wave maximum over devices): compare with the rows of
+        ONE device."""
         self._telemetry_iters_base = len(self.models)
         if not host_records:
             return
@@ -2481,6 +2518,12 @@ class GBDT:
         for rec in host_records:
             for leaves in np.asarray(rec.num_leaves).reshape(-1):
                 leaf_hist.observe(int(leaves))
+            # a sampling step's own count of its sample, one entry a tree
+            # (the K trees of a multiclass iteration share one sample)
+            if rec.sample is not None:
+                for name, rows in rec.sample._asdict().items():
+                    for _ in range(self.num_models):
+                        reg.summary("sample." + name).observe(int(rows))
         counted = [rec.stats for rec in host_records if rec.stats is not None]
         if not counted:
             return

@@ -173,8 +173,10 @@ class WaveStats(NamedTuple):
     (``wave_totals``)."""
     waves: jnp.ndarray          # i32 []  waves run for this tree
     rows_active: jnp.ndarray    # i32 [W] rows of the pending leaves, what
-                                # the histogram pass usefully reads (-1:
-                                # not counted, row_compact is off)
+                                # the histogram pass usefully reads; under
+                                # a row sample the INCLUDED rows of those
+                                # leaves (-1: not counted, row_compact is
+                                # off)
     compacted: jnp.ndarray      # bool [W] the pass was compacted: the
                                 # lax.cond's own predicate
     rows_split: jnp.ndarray     # i32 [W] rows of the leaves split this
@@ -848,6 +850,16 @@ def _route_rows(X: jnp.ndarray, lid: jnp.ndarray, table: RouteTable,
     return leaf_id, f_row, slot_row
 
 
+def _histogram_rows(slot_row: Optional[jnp.ndarray], included: jnp.ndarray,
+                    sampled: bool) -> Optional[jnp.ndarray]:
+    """Every row's pending slot as the HISTOGRAM pass sees it: under a row
+    sample an out-of-sample row is in no slot (-1), whatever its leaf. The
+    routing pass moved it all the same."""
+    if slot_row is None or not sampled:
+        return slot_row
+    return jnp.where(included > 0, slot_row, -1)
+
+
 def _rows_by_slot(slot_row: jnp.ndarray, num_slots: int) -> jnp.ndarray:
     """Row numbers grouped by pending slot, ascending within a slot, the
     rows of no pending leaf last: the index a compacted pass reads, from ONE
@@ -873,7 +885,8 @@ def _slot_grouped_rows(slot_row: jnp.ndarray, num_slots: int
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """What a compacted pass reads besides the packed rows: (the row index
     grouped by pending slot, the rows of each slot), from every row's slot
-    (-1: its leaf is not pending). The counts sum to the pending rows."""
+    (-1: its leaf is not pending, or the row is outside the step's row
+    sample). The counts sum to the pending rows."""
     row_idx = _rows_by_slot(slot_row, num_slots)
     counts = jnp.sum((slot_row[:, None]
                       == jnp.arange(num_slots, dtype=jnp.int32)[None, :])
@@ -895,9 +908,23 @@ def grow_tree(
     spec: GrowerSpec,
     comm=None,
     bundle: Optional[BundleDecode] = None,
+    sampled: bool = False,
 ) -> Tuple[TreeArrays, jnp.ndarray, WaveStats]:
     """Grow one tree; returns (tree arrays, final leaf_id per row, the
     loop's own per-wave counters with a leading device axis of 1).
+
+    ``sampled`` (static: the step draws a row sample, bagging or GOSS): the
+    included rows are the histogram's row set. A row counts as pending for
+    the HISTOGRAM only where ``included`` is set, so ``n_active``, the arm
+    of the wave's ``cond`` and the compacted passes' row index leave the
+    out-of-sample rows out (the reference hands its tree learner
+    ``bag_data_indices``, goss.hpp / gbdt.cpp ``SetBaggingData``). ROUTING
+    still moves every row every wave: ``leaf_id`` covers the out-of-sample
+    rows, which is how they get their score (the reference's out-of-bag
+    ``AddPredictionToScore``). A sampled tree also takes its leaf values
+    from the rows' own sums at the end (``_leaf_values_from_rows``). Without
+    it, and with ``spec.row_compact`` off, the program is the one it was:
+    every row of a pending leaf is pending.
 
     With a distributed ``comm`` (parallel/comm.py) this body runs inside
     shard_map: X/grad/hess/leaf_id may be row-local shards, the histogram
@@ -915,6 +942,9 @@ def grow_tree(
     if comm is None:
         from .parallel.comm import SerialComm
         comm = SerialComm(spec.num_features)
+    # without row compaction there is no row set to shrink: every pass
+    # streams, out-of-sample rows with weight 0, as before
+    sampled = sampled and spec.row_compact
     L = spec.num_leaves
     M = L - 1
     S = spec.hist_slots
@@ -985,8 +1015,11 @@ def grow_tree(
         parent_cache=jnp.full(L + 1, L, jnp.int32),
         num_leaves_cur=jnp.asarray(1, jnp.int32),
         done=jnp.asarray(False),
-        # the root is pending in slot 0, and every row is in it
-        slot_row=jnp.zeros(N, jnp.int32) if spec.row_compact else None,
+        # the root is pending in slot 0, and every row the histogram
+        # counts is in it
+        slot_row=_histogram_rows(
+            jnp.zeros(N, jnp.int32) if spec.row_compact else None,
+            included, sampled),
         stats=_empty_stats(L, scan_block_pairs(S, F_cache, B_cache) < S),
     )
 
@@ -1045,8 +1078,11 @@ def grow_tree(
             # where the two cost the same at this table's shape
             # (ops/histogram.compact_break_even; on the v5e 0.70 at 67
             # columns x 256 bins, 0.95 at 2,000, 0.26 at 28 columns and
-            # 0.35 at 10: PERF.md, PR 31). The root has every row pending,
-            # out-of-bag rows included, and streams; a later wave
+            # 0.35 at 10: PERF.md, PR 31). An unsampled root has every row
+            # pending and streams; a sampled one (bagging, GOSS) has the
+            # included rows pending and compacts where their share is
+            # under the threshold (0.30 < 0.70 at GOSS's documented rates
+            # on 67 columns); a later wave
             # histograms smaller children, under half of the rows, and
             # compacts unless the table is narrow. Under tree_learner=data
             # N is a shard's rows and each shard decides for itself, inside
@@ -1137,8 +1173,9 @@ def grow_tree(
                 scan_slots=(None if scan_slots is None else
                             st.scan_slots.at[st.waves].set(scan_slots)))
 
-        return state2._replace(leaf_id=leaf_id, slot_row=slot_row_next,
-                               stats=stats)
+        return state2._replace(
+            leaf_id=leaf_id, stats=stats,
+            slot_row=_histogram_rows(slot_row_next, included, sampled))
 
     def cond(state: GrowState):
         return ~state.done
@@ -1153,6 +1190,8 @@ def grow_tree(
     # with weight 0 — and 0 * Inf = NaN. Zero them so downstream score
     # updates stay exact; legitimate leaves are untouched.
     tr = final.tree
+    if sampled:
+        tr = _leaf_values_from_rows(tr, final.leaf_id, grad, hess, comm, spec)
     tr = tr._replace(
         leaf_value=tr.leaf_value.at[L].set(0.0),
         internal_value=tr.internal_value.at[M].set(0.0))
@@ -1161,6 +1200,42 @@ def grow_tree(
     # the counters leave with a leading device axis: under shard_map each
     # device's own record is one row of the global array (comm.shard_grow)
     return tr, final.leaf_id, jax.tree.map(lambda a: a[None], final.stats)
+
+
+@jax.named_scope("tree.leaf_sums")
+def _leaf_values_from_rows(tree: TreeArrays, leaf_id, grad, hess, comm,
+                           spec: GrowerSpec) -> TreeArrays:
+    """The leaves' values taken again from the rows' own g and h, where the
+    rows ended up. Growth takes a child's sums from its parent's histogram
+    (a prefix sum, or the parent's total less one: float32 differences of
+    sums as large as the table's), so a small leaf cut from a large node
+    carries the large node's rounding: 7.8e-3 of a leaf value for a leaf of
+    104 sampled rows (H = 32) under a node of H ~ 1e6, where rounding g and
+    h to bfloat16 costs 4e-3 (my chip run, PR 36). Under a row sample a
+    leaf's weight is a third of what its rows suggest at GOSS's rates, and
+    such leaves turn up; so a sampled tree sums its included rows per leaf
+    once more (``grad`` and ``hess`` are already masked and amplified), in
+    chunks, each sum accurate to ITS OWN size, and derives the values from
+    those. Splits, gains and counts stay the loop's."""
+    L, ch = spec.num_leaves, spec.chunk_rows
+    leaves = jnp.arange(L + 1, dtype=leaf_id.dtype)
+
+    def chunk(acc, i):
+        sl = jax.lax.dynamic_slice_in_dim
+        onehot = (sl(leaf_id, i * ch, ch)[:, None] == leaves[None, :])
+        gh = jnp.stack([sl(grad, i * ch, ch), sl(hess, i * ch, ch)], axis=1)
+        return acc + jax.lax.dot_general(
+            onehot.astype(jnp.float32), gh, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32), ()
+
+    sums, _ = jax.lax.scan(chunk, jnp.zeros((L + 1, 2), jnp.float32),
+                           jnp.arange(leaf_id.shape[0] // ch))
+    (sums,) = comm.reduce_scalars(sums)
+    value = leaf_output(sums[:, 0], sums[:, 1], spec.lambda_l1, spec.lambda_l2)
+    # a tree that never split keeps its empty root
+    live = (leaves < tree.num_leaves) & (tree.num_leaves > 1)
+    return tree._replace(leaf_value=jnp.where(live, value, tree.leaf_value))
 
 
 # the histograms' count channel, the leaves' running counts and the split

@@ -29,7 +29,6 @@ def test_dart_xgboost_mode():
     assert mean_squared_error(y, bst.predict(X)) < 0.6 * np.var(y)
 
 
-@pytest.mark.slow
 def test_goss():
     X, y = load_breast_cancer(return_X_y=True)
     params = {"objective": "binary", "boosting_type": "goss", "verbose": -1,

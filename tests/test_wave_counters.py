@@ -228,18 +228,21 @@ def test_wave_totals_takes_the_pace_setting_shard():
     assert t["hist_rows_touched"] == 100 + 20          # ceil(10/20) chunks
 
 
-def test_bagged_root_counts_every_row_and_streams(clean_registry):
-    """``n_active`` counts the rows of the pending leaves, out-of-bag rows
-    among them (they route, with zero weights): a bagged root holds all of
-    a device's rows and streams under any threshold up to 1."""
+def test_bagged_root_counts_its_bag_and_compacts(clean_registry):
+    """Under a row sample ``n_active`` counts the INCLUDED rows of the
+    pending leaves (the histogram's row set; out-of-bag rows route, in no
+    slot): a bagged root holds its bag, and compacts wherever the bag's
+    share is under the threshold. Until PR 36 it held every row and
+    streamed under any threshold."""
     params = dict(BASE, bagging_fraction=0.5, bagging_freq=1,
-                  tpu_compact_frac=1.0)
+                  tpu_compact_frac=0.9)
     g = _booster(params, rounds=3)._gbdt
-    N = int(g.num_data_padded)
+    N = int(g.num_data)
     for rec in jax.device_get(g._grow_records):
         st = jax.tree.map(lambda a: a[0, 0], rec.stats)
-        assert int(st.rows_active[0]) == N and not bool(st.compacted[0])
-        assert st.compacted[1:int(st.waves)].all()
+        assert int(st.rows_active[0]) == int(rec.sample.rows_in)
+        assert 0.4 * N < int(st.rows_active[0]) < 0.6 * N
+        assert st.compacted[:int(st.waves)].all()
 
 
 @pytest.mark.parametrize("learner,batch", [("serial", 1), ("serial", 4),
