@@ -639,7 +639,9 @@ class GBDT:
         # its own GPU bench config max_bin=63). The Pallas kernel's in-kernel
         # unpack handles plain byte layouts only — keep u8/u16 there.
         from ..ops.histogram import (code_mode_for, default_code_mode,
+                                     one_leaf_break_even, one_leaf_form,
                                      packed_row_bytes)
+        from ..ops.pallas_histogram import one_leaf_runs_on
         max_code = (bundle_plan.max_bundle_bins if bundle_plan is not None
                     else train_set.max_num_bin)
         _xb_dtype = Xb.dtype if Xb is not None else train_set.code_dtype
@@ -653,11 +655,25 @@ class GBDT:
         # width x bins, the packed row's bytes, the weight mode, rows a
         # device), a Python float resolved once, here
         _exact = bool(config.tpu_hist_f64)
+        _row_bytes = packed_row_bytes(_hist_cols, code_mode, _exact)
         compact_frac = resolve_compact_frac(
             config.tpu_compact_frac, hist_kernel,
             rows=Npad // Drow, features=_hist_cols, bins_padded=_kernel_bins,
-            row_bytes=packed_row_bytes(_hist_cols, code_mode, _exact),
-            num_slots=slots, exact=_exact)
+            row_bytes=_row_bytes, num_slots=slots, exact=_exact)
+        # the one-leaf form of the chunk matmul (a wave that holds one
+        # pending leaf: the root's pass, its smaller child's): where the
+        # build's shapes have one, beside the xla kernel's bf16 hi/lo mode
+        # of a resident pass, on the device its Mosaic kernel exists for.
+        # Its stream-or-compact threshold is its own break-even
+        _one_leaf = (one_leaf_form(_hist_cols, _kernel_bins, chunk)
+                     if (hist_kernel == "xla" and not _exact
+                         and self.residency != "stream"
+                         and one_leaf_runs_on(self.pctx.devices[0].platform))
+                     else None)
+        one_leaf_frac = 0.0 if _one_leaf is None else one_leaf_break_even(
+            Npad // Drow, _one_leaf, _row_bytes, slots)
+        self._hist_acc_bytes_one_leaf = (
+            0 if _one_leaf is None else _one_leaf.acc_bytes)
         wave = config.tpu_wave_size or slots
         self.spec = GrowerSpec(
             num_leaves=num_leaves,
@@ -679,6 +695,7 @@ class GBDT:
             row_compact=(config.tpu_row_compact
                          and self.residency != "stream"),
             compact_frac=compact_frac,
+            one_leaf_frac=one_leaf_frac,
             hist_kernel=hist_kernel,
             hist_f64=_exact,
             hist_bins=self._hist_bins,
@@ -905,6 +922,9 @@ class GBDT:
             * (4 if self.spec.hist_f64 else 2))
         reg.gauge("hist.acc_bytes").set(self._hist_acc_bytes)
         reg.gauge("hist.compact_frac").set(self.spec.compact_frac)
+        # 0 where no wave takes the one-leaf form
+        reg.gauge("hist.one_leaf_frac").set(self.spec.one_leaf_frac)
+        reg.gauge("hist.one_leaf_acc_bytes").set(self._hist_acc_bytes_one_leaf)
         # slot pairs a block of the wave's tail covers (the cache's
         # write-back and the split scan): hist_slots = the static form
         reg.gauge("scan.block_slots").set(scan_block_pairs(
@@ -2500,7 +2520,11 @@ class GBDT:
         ``grow.rows_split``, ``grow.compact_passes``, ``grow.stream_passes``,
         ``grow.hist_chunks`` (chunk matmuls the passes ran),
         ``grow.hist_acc_bytes`` (accumulator bytes the passes read and
-        wrote: every chunk folds into it once), ``grow.scan_slots`` and
+        wrote: every chunk folds into it once, a chunk of a one-leaf wave
+        into the one-leaf form's own), ``grow.one_leaf_passes`` and
+        ``grow.hist_rows_one_leaf`` (the waves that took the one-leaf form
+        of the chunk matmul and the rows they touched; only where the table
+        has that form), ``grow.scan_slots`` and
         ``grow.scan_slots_pending``, and the counters ``rows.routed`` and
         ``hist.mxu_flops`` /
         ``hist.floor_flops``; from a step that draws a row sample also
@@ -2548,15 +2572,19 @@ class GBDT:
                                 rows, chunk, spec.hist_slots)
                 for name in ("waves", "hist_rows_touched", "hist_rows_active",
                              "rows_split", "compact_passes", "stream_passes",
-                             "scan_slots", "scan_slots_pending"):
+                             "scan_slots", "scan_slots_pending",
+                             "one_leaf_passes", "hist_rows_one_leaf"):
                     if t[name] is not None:
                         reg.summary("grow." + name).observe(t[name])
                 # the Pallas kernel keeps its accumulator in VMEM: its
-                # passes are not counted here
+                # passes are not counted here. A chunk of a one-leaf wave
+                # folds into the one-leaf form's own accumulator
                 if spec.hist_kernel == "xla":
+                    one_leaf = t["hist_chunks_one_leaf"] or 0
                     reg.summary("grow.hist_chunks").observe(t["hist_chunks"])
                     reg.summary("grow.hist_acc_bytes").observe(
-                        t["hist_chunks"] * self._hist_acc_bytes * 2)
+                        2 * ((t["hist_chunks"] - one_leaf) * self._hist_acc_bytes
+                             + one_leaf * self._hist_acc_bytes_one_leaf))
                 if self._comm_bytes_per_wave:
                     moved = tree_collective_bytes(self._comm_bytes_per_wave,
                                                   t["waves"])
@@ -2568,8 +2596,13 @@ class GBDT:
                     reg.summary(f"grow.stream_passes.{d}").observe(streamed)
                     reg.summary(f"grow.compact_passes.{d}").observe(compacted)
                 reg.counter("rows.routed").inc(t["rows_routed"])
+                # a row of a one-leaf pass: each feature group's [G*bins_hi,
+                # 128] product, one MAC a cell of that form's f32 accumulator
+                one_leaf_rows = t["hist_rows_one_leaf"] or 0
                 reg.counter("hist.mxu_flops").inc(
-                    2 * t["hist_rows_touched"] * cells * spec.hist_slots * ch)
+                    2 * ((t["hist_rows_touched"] - one_leaf_rows)
+                         * cells * spec.hist_slots * ch
+                         + one_leaf_rows * (self._hist_acc_bytes_one_leaf // 4)))
                 reg.counter("hist.floor_flops").inc(
                     2 * t["hist_rows_touched"] * cells * 3)
 

@@ -43,7 +43,8 @@ import numpy as np
 
 from .analysis.contracts.registry import trace_entry
 from .ops.histogram import (PALLAS_COMPACT_FRAC_CAP, build_histograms,
-                            keyed_lookup, root_sums, sort_is_one_word)
+                            keyed_lookup, one_leaf_form, root_sums,
+                            sort_is_one_word)
 from .ops.split_finder import SplitCandidates, leaf_output
 from .robustness import allowed_host_sync
 
@@ -193,9 +194,17 @@ class WaveStats(NamedTuple):
                                 # (scan_block_pairs: a narrow table), 2 x
                                 # hist_slots every wave and nothing to
                                 # count: that program carries no counter
+    one_leaf: Optional[jnp.ndarray] = None  # bool [W] the wave's pending
+                                # leaves numbered ONE (the root's pass, its
+                                # smaller child's) and its histograms were
+                                # built in the one-leaf form of the chunk
+                                # matmul: the lax.switch's own predicate.
+                                # None where the table has no such form
+                                # (ops/histogram.one_leaf_form): that
+                                # program carries no counter
 
 
-def _empty_stats(L: int, blocked_tail: bool) -> WaveStats:
+def _empty_stats(L: int, blocked_tail: bool, one_leaf: bool) -> WaveStats:
     W = max(L - 1, 1)
     return WaveStats(waves=jnp.asarray(0, jnp.int32),
                      rows_active=jnp.zeros(W, jnp.int32),
@@ -203,7 +212,8 @@ def _empty_stats(L: int, blocked_tail: bool) -> WaveStats:
                      rows_split=jnp.zeros(W, jnp.int32),
                      scan_pending=jnp.zeros(W, jnp.int32),
                      scan_slots=(jnp.zeros(W, jnp.int32) if blocked_tail
-                                 else None))
+                                 else None),
+                     one_leaf=jnp.zeros(W, bool) if one_leaf else None)
 
 
 def wave_totals(stats, rows_per_device: int, chunk_rows: int,
@@ -222,7 +232,11 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int,
     ``hist_chunks`` is the chunks the passes ran (each folds into the
     accumulator once). ``scan_slots`` is the slots the waves' tails covered
     (the loop's own count: the blocks it ran, or 2 x ``hist_slots`` a wave
-    in the static form); ``scan_slots_pending`` of them held a leaf."""
+    in the static form); ``scan_slots_pending`` of them held a leaf.
+    ``one_leaf_passes`` is the waves that built their histograms in the
+    one-leaf form, ``hist_rows_one_leaf`` / ``hist_chunks_one_leaf`` the
+    touched rows and the chunks of those waves (None where the program has
+    no such form and carries no counter)."""
     waves = int(np.max(stats.waves))
 
     def per_wave(a, dtype):                              # -> [D, waves]
@@ -242,7 +256,17 @@ def wave_totals(stats, rows_per_device: int, chunk_rows: int,
     # a wave counts as streamed when any shard streams it: that shard sets
     # the wave's pace
     streamed = int((~compacted).any(axis=0).sum())
+    if stats.one_leaf is None:
+        one_leaf = one_leaf_rows = None
+    else:
+        # replicated across shards: the pending leaves are the tree's
+        one_leaf = per_wave(stats.one_leaf, bool).any(axis=0)
+        one_leaf_rows = int(touched.max(axis=0)[one_leaf].sum())
     return {"waves": waves,
+            "one_leaf_passes": None if one_leaf is None else int(one_leaf.sum()),
+            "hist_rows_one_leaf": one_leaf_rows,
+            "hist_chunks_one_leaf": (None if one_leaf is None
+                                     else one_leaf_rows // chunk_rows),
             # (streamed, compacted) passes of each shard, where there are
             # several: shards that took different arms in a wave are seen
             "shard_passes": ([(int(w), waves - int(w))
@@ -324,6 +348,17 @@ class GrowerSpec:
                                   # static int compare. 1.0 = stream a full
                                   # root only: every later wave histograms
                                   # smaller children, under half of the rows
+    one_leaf_frac: float = 0.0    # > 0: a wave whose pending leaves number
+                                  # ONE builds its histogram in the one-leaf
+                                  # form of the chunk matmul
+                                  # (ops/histogram.one_leaf_form of the
+                                  # build's shapes), compacted when n_active
+                                  # < int(N * one_leaf_frac): that form's
+                                  # own break-even, resolved where the spec
+                                  # is built (one_leaf_break_even). 0: the
+                                  # table, the weight mode, the kernel or
+                                  # the device has no such form and every
+                                  # wave takes the general one
     hist_bins: int = 0            # bin axis of the histogram BUILD (EFB bundle
                                   # space); 0 = num_bins_padded (unbundled)
     efb_unpack: bool = False      # LEGACY EFB scan arm (tpu_efb_unpack):
@@ -975,6 +1010,11 @@ def grow_tree(
     if bundle is not None and getattr(comm, "bundled_blocks", False):
         scan_bundle = comm.localize_bundle(bundle)
     B_hist = spec.hist_bins or B  # bundle-space bin axis (build side)
+    # the one-leaf form of the chunk matmul, where this build has one
+    form = (one_leaf_form(F_hist, B_hist, spec.chunk_rows)
+            if spec.one_leaf_frac > 0 else None)
+    assert form is None or (spec.hist_kernel == "xla" and not spec.hist_f64), \
+        "the one-leaf form stands beside the xla kernel's bf16 hi/lo mode"
     F_cache, B_cache = scan_hist_shape(spec, comm, F_hist, bundle is not None)
     bm = comm.block_meta(feature_ok, num_bins, missing_code, default_bin, is_cat)
 
@@ -1020,7 +1060,8 @@ def grow_tree(
         slot_row=_histogram_rows(
             jnp.zeros(N, jnp.int32) if spec.row_compact else None,
             included, sampled),
-        stats=_empty_stats(L, scan_block_pairs(S, F_cache, B_cache) < S),
+        stats=_empty_stats(L, scan_block_pairs(S, F_cache, B_cache) < S,
+                           form is not None),
     )
 
     leaf_iota = jnp.arange(L + 1, dtype=jnp.int32)
@@ -1039,7 +1080,8 @@ def grow_tree(
         # then the distributed reduction: psum_scatter for data-parallel
         # (reference data_parallel_tree_learner.cpp:148-163), identity
         # otherwise; output covers this device's feature block only.
-        def hist_pass(row_idx=None, n_active=None, slot_counts=None):
+        def hist_pass(row_idx=None, n_active=None, slot_counts=None,
+                      one_leaf=None):
             # "mixed": the XLA one-hot matmul for FULL streaming passes and
             # the Pallas VMEM-accumulator kernel for COMPACTED passes (which
             # kernel wins which pass type on today's chip: not measured,
@@ -1066,7 +1108,16 @@ def grow_tree(
                 num_slots=S, num_bins_padded=B_hist, chunk_rows=spec.chunk_rows,
                 row_idx=row_idx, n_active=n_active, exact=spec.hist_f64,
                 slot_counts=slot_counts, packed=packed_rows,
-                code_mode=spec.code_mode, compensated=spec.hist_f64)
+                code_mode=spec.code_mode, compensated=spec.hist_f64,
+                one_leaf=one_leaf)
+
+        # A wave that holds ONE pending leaf (the root's pass, its smaller
+        # child's) builds it in the one-leaf form where the build has one:
+        # the count is the tree's own (replicated across shards under
+        # tree_learner=data, so every chip takes the same form in the same
+        # wave); which ARM a shard takes stays its own rows' business
+        one_leaf = (jnp.sum(pending.astype(jnp.int32)) == 1
+                    if form is not None else None)
 
         if spec.row_compact:
             # Adaptive, the TPU analog of the reference histogramming only
@@ -1118,12 +1169,42 @@ def grow_tree(
                 with jax.named_scope("wave.hist.stream"):
                     return hist_pass()
 
-            new_hist = jax.lax.cond(compacted, compact_arm, stream_arm)
+            if form is None:
+                new_hist = jax.lax.cond(compacted, compact_arm, stream_arm)
+            else:
+                # the one-leaf form has its own stream-or-compact threshold
+                # (its matmul is cheaper, its gather and sort are not), and
+                # its compacted pass reads the leaf's rows as the first
+                # n_active entries of the sorted index: no per-slot counts
+                compacted = jnp.where(
+                    one_leaf, n_active < int(N * spec.one_leaf_frac),
+                    compacted)
+
+                def one_leaf_compact_arm():
+                    with jax.named_scope("wave.hist.compact"):
+                        with jax.named_scope("wave.partition"):
+                            row_idx = _rows_by_slot(slot_row, S)
+                        return hist_pass(row_idx, n_active, one_leaf=form)
+
+                def one_leaf_stream_arm():
+                    with jax.named_scope("wave.hist.stream"):
+                        return hist_pass(one_leaf=form)
+
+                new_hist = jax.lax.switch(
+                    compacted.astype(jnp.int32)
+                    + 2 * one_leaf.astype(jnp.int32),
+                    (stream_arm, compact_arm,
+                     one_leaf_stream_arm, one_leaf_compact_arm))
         else:
             n_active = jnp.asarray(-1, jnp.int32)   # not counted in this arm
             compacted = jnp.asarray(False)
             with jax.named_scope("wave.hist.stream"):
-                new_hist = hist_pass()
+                if form is None:
+                    new_hist = hist_pass()
+                else:
+                    new_hist = jax.lax.cond(
+                        one_leaf, lambda: hist_pass(one_leaf=form),
+                        lambda: hist_pass())
         with jax.named_scope("wave.hist.reduce"):
             if unbundle_early:
                 # this shard's leaf totals: any bundled column's bins partition
@@ -1171,7 +1252,9 @@ def grow_tree(
                 rows_split=st.rows_split.at[st.waves].set(rows_split),
                 scan_pending=st.scan_pending.at[st.waves].set(scan_pending),
                 scan_slots=(None if scan_slots is None else
-                            st.scan_slots.at[st.waves].set(scan_slots)))
+                            st.scan_slots.at[st.waves].set(scan_slots)),
+                one_leaf=(None if one_leaf is None else
+                          st.one_leaf.at[st.waves].set(one_leaf)))
 
         return state2._replace(
             leaf_id=leaf_id, stats=stats,

@@ -24,7 +24,7 @@ accuracy deltas: docs/GPU-Performance.rst:131-133).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +149,102 @@ def resolve_compact_frac(requested: float, hist_kernel: str, **shape) -> float:
     return frac
 
 
+# ---- the one-leaf form of the chunk matmul ---------------------------------
+# A wave whose pending leaves number ONE (the root's pass, its smaller
+# child's) pays the 125 columns of the general form for 5 live ones: rhs is
+# [R, S*ch] and every row of the wave is in slot 0. The one-leaf form splits
+# the bin code instead, code = hi << 3 | lo, and contracts two NARROW
+# one-hots over the rows, G features side by side:
+#     hist[f, hi, lo, c] = sum_r [hi_rf == hi] * ([lo_rf == lo] * w[r, c])
+# lhs G x bins_hi wide (96 at 256 bins), rhs G x 5 x 8 = 120 columns: ONE
+# 128 x 128 MXU tile pass per G = 3 features and 128 rows where the general
+# form streams 256 one-hot columns a feature; the off-diagonal feature blocks
+# of the [G*bins_hi, 128] product are discarded. Same five bf16 hi/lo
+# channels, 0/1 one-hots, f32 accumulation: every product exact, only the
+# order of the f32 additions inside a chunk differs. Both one-hots are built
+# in VMEM by a Pallas kernel (ops/pallas_histogram.hist_one_leaf_chunk):
+# every XLA form of the split materialises the weight operand or lowers the
+# batched dot to a dilated convolution, and ran 1.6 to 5 times SLOWER than
+# the general form (PERF.md, PR 37). Read on the v5e, one pass alone, ns a
+# row streamed / compacted (gather included), slices, weight rows and the
+# per-chunk transposes included:
+#     14,680,064 x 67:   4.00 / 14.17   (the general form 25.42 / 34.94)
+#     401,408 x 2,000:   113.7 / 166.1  (707.5 / 764.2)
+# = 2.2e-4 ns a PADDED cell (72 x 256, 2,016 x 256). (With its loop over
+# the feature groups unrolled whole the kernel read 3.70 / 13.86 and 103.0
+# / 155.1, and cost every process 16 s of tracing: pallas_histogram.py.)
+_ONE_LEAF_LO_BITS = 3
+_ONE_LEAF_NS_A_CELL = 2.2e-4
+_ONE_LEAF_ROW_TILES = (2048, 1024, 512, 256, 128)
+ONE_LEAF_TRIP_GROUPS = 4            # feature groups a trip of the kernel's
+                                    # loop over a grid step's block of groups
+_ONE_LEAF_MAX_BLOCK_GROUPS = 32     # feature groups a grid step
+
+
+class OneLeafForm(NamedTuple):
+    """The one-leaf form's static shapes for one table."""
+    bins_lo: int          # 1 << _ONE_LEAF_LO_BITS
+    bins_hi: int          # ceil(bins / bins_lo), padded to 16 sublanes
+    group: int            # G: features side by side in one matmul
+    groups: int           # feature groups, padded to whole blocks
+    block_groups: int     # groups a grid step of the kernel covers
+    row_tile: int         # rows a grid step
+
+    @property
+    def features_padded(self) -> int:
+        return self.groups * self.group
+
+    @property
+    def acc_shape(self) -> Tuple[int, int, int]:
+        return (self.groups, self.group * self.bins_hi, 128)
+
+    @property
+    def acc_bytes(self) -> int:
+        g, m, n = self.acc_shape
+        return g * m * n * 4
+
+
+def one_leaf_form(features: int, bins_padded: int, chunk_rows: int
+                  ) -> Optional[OneLeafForm]:
+    """The one-leaf form for a histogram build of ``features`` x
+    ``bins_padded`` in chunks of ``chunk_rows``, or None where it has none:
+    a static function of the shapes. A bin count the split does not divide
+    and a feature count G does not divide are padded (the padding's cells
+    are computed and dropped); a chunk no row tile divides and more than
+    1,024 bins (a hi one-hot wider than one MXU tile) keep the general
+    form."""
+    bins_lo = 1 << _ONE_LEAF_LO_BITS
+    bins_hi = -(-(-(-int(bins_padded) // bins_lo)) // 16) * 16
+    row_tile = next((t for t in _ONE_LEAF_ROW_TILES
+                     if int(chunk_rows) % t == 0), None)
+    if row_tile is None or bins_hi > 128 or features < 1:
+        return None
+    group = max(1, min(128 // (bins_lo * NUM_CHANNELS), 128 // bins_hi))
+    # a block is whole trips of the kernel's loop over its groups
+    n_groups = -(-int(features) // group)
+    block = min(-(-n_groups // ONE_LEAF_TRIP_GROUPS) * ONE_LEAF_TRIP_GROUPS,
+                _ONE_LEAF_MAX_BLOCK_GROUPS)
+    return OneLeafForm(bins_lo=bins_lo, bins_hi=bins_hi, group=group,
+                       groups=-(-n_groups // block) * block,
+                       block_groups=block, row_tile=row_tile)
+
+
+def one_leaf_break_even(rows: int, form: OneLeafForm, row_bytes: int,
+                        num_slots: int) -> float:
+    """``compact_break_even`` for a ONE-LEAF wave: the share of a device's
+    rows below which its compacted pass is cheaper than its streamed one,
+    with the form's own cost a row in place of the general matmul's. The
+    streamed form pays nothing beside the matmul (no slot lookup), so a
+    cheap matmul leaves the gather and the sort to decide: 0.20 at 67
+    columns (the root's smaller child, 0.3-0.5 of the rows, STREAMS; so does
+    a GOSS root at 0.30), 0.74 at 2,000."""
+    matmul = (_ONE_LEAF_NS_A_CELL * form.features_padded
+              * form.bins_hi * form.bins_lo)
+    sort = _SORT_NS_A_ROW[sort_is_one_word(int(rows), int(num_slots))]
+    frac = (matmul - sort) / (matmul + _gather_ns(int(row_bytes)))
+    return float(min(1.0, max(_MIN_COMPACT_FRAC, frac)))
+
+
 def weight_channels(grad, hess, included, exact: bool):
     """[N, ch] weight channels for the one-hot matmul (dtype by mode)."""
     if exact:
@@ -185,6 +281,16 @@ def _split_hi_lo(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     x = x.astype(jnp.float32)
     hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
     return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+
+
+def _weight_rows(grad, hess, included, live) -> jnp.ndarray:
+    """[8, R] f32: the five weight channels of ``weight_channels`` as ROWS
+    (rows of the table along the lanes, as the one-leaf kernel reads them),
+    zero where ``live`` is unset, three rows of padding. Every value is
+    exactly bf16-representable, so the kernel's cast to bf16 is exact."""
+    w = weight_channels(grad, hess, included, exact=False)          # [R, 5]
+    rows = jnp.where(live[None, :], w.astype(jnp.float32).T, 0.0)
+    return jnp.pad(rows, ((0, 8 - NUM_CHANNELS), (0, 0)))
 
 
 # ---- packed-row form for the compacted gather -------------------------------
@@ -437,6 +543,11 @@ def build_histograms(
                                    # instead of the finalized histogram —
                                    # streaming callers finalize once per wave
                                    # via finalize_histograms
+    one_leaf: Optional[OneLeafForm] = None,  # the wave's pending leaves number
+                                   # ONE (the caller's promise: one leaf, in
+                                   # slot 0): build in the one-leaf form of
+                                   # the chunk matmul (one_leaf_form of the
+                                   # shapes); bf16 hi/lo weights only
 ) -> jnp.ndarray:
     """Returns hist [num_slots, F, num_bins_padded, 3] f32 (sum_g, sum_h, count).
 
@@ -458,6 +569,13 @@ def build_histograms(
     n_chunks = n_rows // chunk_rows
     ch = num_channels(exact)
     compact = row_idx is not None
+    if one_leaf is not None:
+        assert not (exact or compensated or raw_output) and acc_init is None, \
+            "the one-leaf form builds bf16 hi/lo histograms of a resident pass"
+        return _build_one_leaf(
+            X, grad, hess, included, leaf_id, slot_of_leaf, one_leaf,
+            num_slots, num_bins_padded, chunk_rows, row_idx, n_active, packed,
+            code_mode)
     iota_bins = jnp.arange(num_bins_padded, dtype=jnp.int32)[None, None, :]
     iota_slots = jnp.arange(num_slots, dtype=jnp.int32)[None, :]
     iota_chunk = jnp.arange(chunk_rows, dtype=jnp.int32)
@@ -555,6 +673,84 @@ def build_histograms(
     if raw_output:
         return acc, comp
     return finalize_histograms(acc, num_slots, exact)
+
+
+def _build_one_leaf(X, grad, hess, included, leaf_id, slot_of_leaf,
+                    form: OneLeafForm, num_slots: int, num_bins_padded: int,
+                    chunk_rows: int, row_idx, n_active, packed, code_mode
+                    ) -> jnp.ndarray:
+    """``build_histograms`` for a wave that holds ONE pending leaf, in slot
+    0: the same chunking, packed-row gather and scopes, its own chunk body
+    (the one-leaf kernel on codes and weights laid feature-major) and its
+    own ``form.acc_shape`` f32 accumulator, finalized into slot 0 of the
+    [S, F, B, 3] result. Streamed: no slot lookup and no slot one-hot, a
+    row is live where its leaf is the pending one (padding and out-of-sample
+    rows carry weight 0). Compacted: the first ``n_active`` entries of
+    ``row_idx`` are the leaf's rows, no ``slot_from_position``."""
+    from .pallas_histogram import hist_one_leaf_chunk
+    n_rows, num_features = X.shape
+    n_chunks = n_rows // chunk_rows
+    compact = row_idx is not None
+    pad_f = form.features_padded - num_features
+    iota_chunk = jnp.arange(chunk_rows, dtype=jnp.int32)
+    sl = jax.lax.dynamic_slice_in_dim
+    if compact:
+        assert packed is not None, "a compacted pass reads the packed rows"
+        if code_mode is None:
+            code_mode = default_code_mode(X.dtype)
+        ncb = code_bytes_total(num_features, code_mode)
+    else:
+        leaf = jnp.argmax(slot_of_leaf == 0).astype(leaf_id.dtype)
+
+    def chunk_part(i):
+        if compact:
+            with jax.named_scope("wave.hist.compact.gather"):
+                valid = i * chunk_rows + iota_chunk < n_active
+                idx = sl(row_idx, i * chunk_rows, chunk_rows)
+                pk = jnp.take(packed, idx, axis=0)                    # [R, Wb] u8
+                xc = unpack_codes(pk[:, :ncb], num_features, code_mode)
+                w = unpack_weights(pk[:, ncb:], NUM_CHANNELS)          # [R, 5]
+                w = jnp.where(valid[:, None], w.astype(jnp.float32), 0.0)
+            wt = jnp.pad(w, ((0, 0), (0, 8 - NUM_CHANNELS))).T         # [8, R]
+        else:
+            xc = sl(X, i * chunk_rows, chunk_rows).astype(jnp.int32)
+            wt = _weight_rows(sl(grad, i * chunk_rows, chunk_rows),
+                              sl(hess, i * chunk_rows, chunk_rows),
+                              sl(included, i * chunk_rows, chunk_rows),
+                              sl(leaf_id, i * chunk_rows, chunk_rows) == leaf)
+        xt = jnp.pad(xc, ((0, 0), (0, pad_f))).T                      # [Fp, R]
+        return hist_one_leaf_chunk(xt, wt, form)
+
+    acc0 = jnp.zeros(form.acc_shape, jnp.float32)
+    with jax.named_scope("hist.kernel"):
+        if compact:
+            n_chunks_active = jnp.minimum(
+                (n_active + chunk_rows - 1) // chunk_rows, n_chunks)
+            _, acc = jax.lax.while_loop(
+                lambda c: c[0] < n_chunks_active,
+                lambda c: (c[0] + 1, c[1] + chunk_part(c[0])),
+                (jnp.asarray(0, n_chunks_active.dtype), acc0))
+        else:
+            acc, _ = jax.lax.scan(lambda a, i: (a + chunk_part(i), ()), acc0,
+                                  jnp.arange(n_chunks))
+    return finalize_one_leaf(acc, form, num_features, num_bins_padded,
+                             num_slots)
+
+
+def finalize_one_leaf(acc: jnp.ndarray, form: OneLeafForm, num_features: int,
+                      num_bins_padded: int, num_slots: int) -> jnp.ndarray:
+    """The one-leaf accumulator [groups, G*bins_hi, 128] -> [S, F, B, 3]
+    with the leaf's histogram in slot 0 and zeros elsewhere: the diagonal
+    feature blocks of each group's product, its columns ordered (feature of
+    the group, channel, lo)."""
+    G, Bh, Bl = form.group, form.bins_hi, form.bins_lo
+    a = acc[:, :, :G * NUM_CHANNELS * Bl].reshape(
+        form.groups, G, Bh, G, NUM_CHANNELS, Bl)
+    d = jnp.stack([a[:, g, :, g] for g in range(G)], axis=1)  # [ng,G,Bh,ch,Bl]
+    d = jnp.transpose(d, (0, 1, 2, 4, 3)).reshape(
+        form.features_padded, Bh * Bl, NUM_CHANNELS)
+    hist = combine_channels(d[:num_features, :num_bins_padded], exact=False)
+    return jnp.zeros((num_slots,) + hist.shape, jnp.float32).at[0].set(hist)
 
 
 def finalize_histograms(acc: jnp.ndarray, num_slots: int, exact: bool

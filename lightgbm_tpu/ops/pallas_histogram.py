@@ -41,8 +41,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .histogram import (NUM_CHANNELS, code_bytes, combine_channels,
-                        slot_from_position, table_lookup, unpack_weights)
+from .histogram import (NUM_CHANNELS, ONE_LEAF_TRIP_GROUPS, OneLeafForm,
+                        code_bytes, combine_channels, slot_from_position,
+                        table_lookup, unpack_weights)
 
 _INTERPRET = False   # flipped by tests on CPU
 
@@ -259,3 +260,109 @@ def build_histograms_pallas(
                        num_features=F, cb=cb,
                        chunk_rows=min(chunk_rows, n_rows),
                        n_active=n_active)
+
+
+# ---- the one-leaf form (ops/histogram.one_leaf_form) ------------------------
+# rows of a grid step's tile the kernel turns into one-hots at a time: the
+# [bins_hi x G, 512] and [128, 512] operands of one matmul stay in vregs
+_ONE_LEAF_SUB_ROWS = 512
+
+
+def one_leaf_runs_on(platform: str) -> bool:
+    """The one-leaf kernel is a Mosaic kernel: it exists for a TPU (and,
+    interpreted, wherever the tests flip ``_INTERPRET``)."""
+    return platform == "tpu" or _INTERPRET
+
+
+def _one_leaf_kernel(x_ref,           # [block_groups*G, Rt] i32 bin codes,
+                                      # feature-major: rows along the lanes
+                     w_ref,           # [8, Rt] f32 weight channels as rows
+                                      # (g_hi, g_lo, h_hi, h_lo, count, 0..)
+                     out_ref,         # [block_groups, G*bins_hi, 128] f32:
+                                      # the VMEM accumulator of this block of
+                                      # feature groups over the row tiles
+                     *, form: OneLeafForm):
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    G, Bh, Bl = form.group, form.bins_hi, form.bins_lo
+    lo_bits = Bl.bit_length() - 1
+    cols = G * NUM_CHANNELS * Bl
+    sub = min(_ONE_LEAF_SUB_ROWS, form.row_tile)
+    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (Bl, sub), 0)
+    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (Bh, sub), 0)
+
+    def group_part(g, rows):
+        """One feature group's [G*bins_hi, 128] product over ``sub`` rows."""
+        w = w_ref[:, rows]                                     # [8, sub]
+        lhs, rhs = [], []
+        for j in range(G):
+            # one feature's codes, broadcast down the sublanes: every
+            # compare and select below is one op on a natural (8, 128)
+            # f32 tile, no lane shuffle
+            x = x_ref[pl.ds(g * G + j, 1), rows]               # [1, sub]
+            lhs.append(jnp.where((x >> lo_bits) == iota_hi, 1.0, 0.0))
+            is_lo = (x & (Bl - 1)) == iota_lo                   # [Bl, sub]
+            rhs += [jnp.where(is_lo, w[c:c + 1, :], 0.0)
+                    for c in range(NUM_CHANNELS)]
+        if cols < 128:
+            rhs.append(jnp.zeros((128 - cols, sub), jnp.float32))
+        # rows on the lanes of BOTH operands: the q . k^T contraction
+        return jax.lax.dot_general(
+            jnp.concatenate(lhs, axis=0).astype(jnp.bfloat16),
+            jnp.concatenate(rhs, axis=0).astype(jnp.bfloat16),
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                # [G*Bh, 128]
+
+    # ONE_LEAF_TRIP_GROUPS groups a trip of a real loop: a Python loop over
+    # all 23 groups x 4 row slices traced 8,000 operations an arm, 16 s of
+    # every process's first dispatch on the chip's host (PERF.md, PR 37)
+    trip = ONE_LEAF_TRIP_GROUPS
+    assert form.block_groups % trip == 0, form
+
+    def groups_body(t, carry):
+        for u in range(trip):
+            g = t * trip + u
+            part = group_part(g, pl.ds(0, sub))
+            for s in range(1, form.row_tile // sub):
+                part += group_part(g, pl.ds(s * sub, sub))
+            out_ref[g] += part
+        return carry
+
+    jax.lax.fori_loop(0, form.block_groups // trip, groups_body, 0)
+
+
+def hist_one_leaf_chunk(xt: jnp.ndarray,     # [form.features_padded, R] i32
+                        wt: jnp.ndarray,     # [8, R] f32, bf16-representable
+                        form: OneLeafForm) -> jnp.ndarray:
+    """One chunk of a ONE-LEAF wave's histogram pass: [form.groups,
+    G*bins_hi, 128] f32 with, for feature group ``g``,
+    ``out[g, j*bins_hi + hi, j'*40 + c*8 + lo]`` = the sum of channel ``c``
+    over the chunk's rows whose feature ``g*G + j`` has the hi code and
+    whose feature ``g*G + j'`` has the lo code; ``j == j'`` is the
+    histogram (``ops/histogram.finalize_one_leaf`` takes it). Both one-hots
+    are built in VMEM; the grid walks blocks of feature groups, and inside a
+    block the chunk's row tiles, accumulating in the resident output block."""
+    assert (xt.shape[0] == form.features_padded
+            and xt.shape[1] % form.row_tile == 0), (xt.shape, form)
+    return _one_leaf_call(xt, wt, form, _INTERPRET)
+
+
+# jitted, so the kernel's body is traced once a process and not once for
+# every arm and every loop's fixpoint that holds a call
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _one_leaf_call(xt, wt, form: OneLeafForm, interpret: bool):
+    block = form.block_groups * form.group
+    return pl.pallas_call(
+        functools.partial(_one_leaf_kernel, form=form),
+        name="hist_one_leaf",
+        grid=(form.groups // form.block_groups,
+              xt.shape[1] // form.row_tile),
+        in_specs=[pl.BlockSpec((block, form.row_tile), lambda j, i: (j, i)),
+                  pl.BlockSpec((8, form.row_tile), lambda j, i: (0, i))],
+        out_specs=pl.BlockSpec((form.block_groups,) + form.acc_shape[1:],
+                               lambda j, i: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(form.acc_shape, jnp.float32),
+        interpret=interpret,
+    )(xt, wt)

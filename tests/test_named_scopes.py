@@ -192,6 +192,117 @@ def test_v5e_step_sorts_rows_once_in_the_compacted_arm(v5e_hlo):
     assert not _row_sized(v5e_hlo, 2560, "scatter")
 
 
+# ---- the one-leaf form of the chunk matmul (ops/histogram.one_leaf_form) ----
+
+def _with_the_form(monkeypatch, on: bool):
+    """The one-leaf kernel's device switch, as a test steers it: the booster
+    is built on the CPU, where the Mosaic kernel does not exist."""
+    from lightgbm_tpu.ops import pallas_histogram
+    monkeypatch.setattr(pallas_histogram, "one_leaf_runs_on",
+                        lambda platform: on)
+
+
+@pytest.fixture(scope="module")
+def v5e_hlo_one_leaf(one_chip):
+    """The default step WITH the one-leaf form, compiled for the described
+    chip, as text."""
+    mp = pytest.MonkeyPatch()
+    try:
+        _with_the_form(mp, True)
+        fn, args = _step_and_args(tpu_hist_chunk=512)
+    finally:
+        mp.undo()
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip), args)
+    return _compiled_text(fn.lower(*shapes))
+
+
+def test_v5e_hist_kernel_encloses_the_one_leaf_matmul_in_both_arms(
+        v5e_hlo_one_leaf):
+    """The one-leaf form's matmul is a Mosaic kernel (``hist_one_leaf``): in
+    the step compiled for the chip it sits under ``hist.kernel`` in the
+    streamed and in the compacted arm of the wave's switch, beside the
+    general form's fusions, which are still there."""
+    ops = [ln for ln in v5e_hlo_one_leaf.splitlines() if "metadata={" in ln]
+    kernels = [ln for ln in ops if "tpu_custom_call" in ln
+               and "hist_one_leaf" in ln]
+    assert kernels and all("hist.kernel" in ln for ln in kernels)
+    assert any("wave.hist.stream" in ln for ln in kernels)
+    assert any("wave.hist.compact" in ln for ln in kernels)
+    dots = [ln for ln in ops if re.search(r"kind=kOutput|convolution\(", ln)
+            and "hist.kernel" in ln]
+    assert any("wave.hist.stream" in ln for ln in dots)
+    assert any("wave.hist.compact" in ln for ln in dots)
+    # each compacted arm sorts the rows once, and no other row-sized sort
+    sorts = _row_sized(v5e_hlo_one_leaf, 2560, "sort")
+    assert len(sorts) == 2 and all(
+        "wave.hist.compact/wave.partition" in ln for ln in sorts), sorts
+
+
+@pytest.mark.parametrize("extra", [
+    dict(tpu_hist_f64=True), dict(tpu_hist_kernel="pallas"),
+    dict(tpu_row_compact=False, tpu_hist_f64=True)],
+    ids=["f64", "pallas", "f64-no-row-compact"])
+def test_other_weight_modes_and_kernels_lower_to_the_same_text(
+        monkeypatch, extra):
+    """Paths that keep the general form by construction: their lowered step
+    is the same text with the one-leaf kernel's device there or not (the
+    parent's text: PERF.md, PR 37, compared by hand)."""
+    from lightgbm_tpu.ops import pallas_histogram
+    monkeypatch.setattr(pallas_histogram, "_INTERPRET",
+                        extra.get("tpu_hist_kernel") == "pallas")
+    texts = []
+    for on in (False, True):
+        _with_the_form(monkeypatch, on)
+        fn, args = _step_and_args(**extra)
+        texts.append(fn.lower(*args).as_text())
+    assert texts[0] == texts[1] and "hist_one_leaf" not in texts[0]
+
+
+def test_the_streamed_growers_shard_leg_lowers_to_the_same_text(monkeypatch):
+    """``StreamedGrower.shard_body`` threads the general form's accumulator
+    through its legs (``acc_init`` / ``raw_output``): the one-leaf form is
+    not there, whatever the device."""
+    texts = []
+    for on in (False, True):
+        _with_the_form(monkeypatch, on)
+        rng = np.random.RandomState(2)
+        X = rng.rand(2500, 8).astype(np.float32)
+        y = (X[:, 0] + X[:, 1] * X[:, 2] > 0.8).astype(np.float32)
+        params = dict(PARAMS, bagging_fraction=1.0, bagging_freq=0,
+                      tpu_residency="stream", tpu_stream_shard_rows=256)
+        bst = lgb.Booster(params=params,
+                          train_set=lgb.Dataset(X, label=y, params=params))
+        g = bst._gbdt
+        assert g.spec.one_leaf_frac == 0
+        grower, leg = g._streamed_grower, {}
+        jitted = grower.shard_fn
+
+        def spy(*a, **kw):
+            leg.setdefault("text", jitted.lower(*a, **kw).as_text())
+            return jitted(*a, **kw)
+        grower.shard_fn = spy
+        bst.update()
+        texts.append(leg["text"])
+    assert texts[0] == texts[1] and "hist_one_leaf" not in texts[0]
+
+
+def test_the_default_step_takes_the_form_where_its_kernel_exists(monkeypatch):
+    """The same booster with the kernel's device there: the wave's ``cond``
+    becomes a four-armed switch and the loop carries ``one_leaf``."""
+    _with_the_form(monkeypatch, False)
+    fn, args = _step_and_args()
+    without = str(fn.trace(*args).jaxpr)
+    _with_the_form(monkeypatch, True)
+    fn, args = _step_and_args()
+    jaxpr = str(fn.trace(*args).jaxpr)
+    assert "hist_one_leaf" in jaxpr and "hist_one_leaf" not in without
+    # one call in the streamed arm and one in the compacted arm
+    assert jaxpr.count("name=_one_leaf_call") == 2
+    assert "pallas_call" in jaxpr and "pallas_call" not in without
+
+
 def _route_widths(hlo: str, rows: int):
     """Minor widths ``w`` of the row-sized 2-D values ``[rows, w]`` the
     compiled program computes under ``wave.route``: a one-hot over T keys is

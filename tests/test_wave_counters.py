@@ -221,7 +221,10 @@ def test_wave_totals_takes_the_pace_setting_shard():
                  "hist_rows_touched": 200, "hist_chunks": 10,
                  "hist_rows_active": 130,
                  "rows_routed": 200, "rows_split": 170,
-                 "scan_slots": 16, "scan_slots_pending": 3}
+                 "scan_slots": 16, "scan_slots_pending": 3,
+                 # this program has no one-leaf form and carries no counter
+                 "one_leaf_passes": None, "hist_rows_one_leaf": None,
+                 "hist_chunks_one_leaf": None}
     one = jax.tree.map(lambda a: a[:1], st)
     t = wave_totals(one, rows_per_device=100, chunk_rows=20, hist_slots=4)
     assert (t["stream_passes"], t["compact_passes"]) == (1, 1)
@@ -441,3 +444,115 @@ def test_step_traces_counts_a_forced_retrace(clean_registry):
     assert "float32[]" in changed["[7]"] and "bfloat16[]" in changed["[7]"]
     assert any(v.startswith("float32[1, ") for v in
                traces[-1]["args"]["signature"].values())    # the score
+
+
+# ------------------------------------------------- the one-leaf form's counters
+
+@pytest.fixture
+def one_leaf_form_on(monkeypatch):
+    """The one-leaf kernel interpreted: on the CPU the form exists only so."""
+    from lightgbm_tpu.ops import pallas_histogram
+    monkeypatch.setattr(pallas_histogram, "_INTERPRET", True)
+
+
+@pytest.mark.parametrize("frac,width", [(0.5, NARROW), (1.0, NARROW),
+                                        (0.0, WIDE)],
+                         ids=["mixed", "all-compact", "auto-wide"])
+def test_one_leaf_waves_are_the_waves_with_one_pending_leaf(
+        clean_registry, one_leaf_form_on, frac, width):
+    """``WaveStats.one_leaf`` against the replay: true for the root's wave
+    and its smaller child's, and later only where the wave before split ONE
+    node; such a wave takes its own stream-or-compact threshold
+    (``spec.one_leaf_frac``). The published totals against a hand count."""
+    params = dict(BASE, max_bin=width["max_bin"])
+    if frac:
+        params["tpu_compact_frac"] = frac
+    bst = _booster(params, rounds=3, f=width["f"])
+    g = bst._gbdt
+    assert 0 < g.spec.one_leaf_frac <= g.spec.compact_frac
+    L, N, chunk = g.spec.num_leaves, int(g.num_data_padded), g.spec.chunk_rows
+    Xb, real = np.asarray(g.Xb), np.asarray(g.pad_mask) > 0
+    records = jax.device_get(g._grow_records)
+    by_hand = []
+    for rec, (tree,) in zip(records, jax.device_get(g.models)):
+        st = jax.tree.map(lambda a: a[0, 0], rec.stats)
+        want = _replay(tree, Xb, real, L, g.spec.compact_frac)
+        w = int(st.waves)
+        # pending leaves of a wave: the root, then one per node split in the
+        # wave before (scan_pending counts them and their siblings)
+        one_leaf = [held <= 2 for held in want["scan_pending"]]
+        assert w == want["waves"] >= 3 and one_leaf[:2] == [True, True]
+        assert not all(one_leaf)
+        assert st.one_leaf[:w].tolist() == one_leaf
+        compacted = [a < int(N * g.spec.one_leaf_frac) if ol else c
+                     for a, c, ol in zip(want["rows_active"],
+                                         want["compacted"], one_leaf)]
+        assert st.compacted[:w].tolist() == compacted
+        touched = [(-(-a // chunk) * chunk) if c else N
+                   for a, c in zip(want["rows_active"], compacted)]
+        by_hand.append((sum(one_leaf), sum(t for t, ol in zip(touched, one_leaf)
+                                           if ol), sum(touched)))
+    bst._ensure_finalized()
+    reg = obs.get_registry()
+    assert reg.summary("grow.one_leaf_passes").values() == \
+        [float(b[0]) for b in by_hand]
+    assert reg.summary("grow.hist_rows_one_leaf").values() == \
+        [float(b[1]) for b in by_hand]
+    assert reg.summary("grow.hist_rows_touched").values() == \
+        [float(b[2]) for b in by_hand]
+    # a chunk of a one-leaf wave folds into the form's own accumulator
+    gauges = obs.snapshot()["gauges"]
+    acc, acc_one = gauges["hist.acc_bytes"], gauges["hist.one_leaf_acc_bytes"]
+    # (smaller than the general one wherever the table is not tiny)
+    assert acc_one > 0 and (acc_one < acc or width is NARROW)
+    assert reg.summary("grow.hist_acc_bytes").values() == [
+        2.0 * ((b[2] - b[1]) // chunk * acc + b[1] // chunk * acc_one)
+        for b in by_hand]
+
+
+def test_one_leaf_is_the_same_on_every_shard(clean_registry, one_leaf_form_on):
+    """Under ``tree_learner=data`` the pending leaves are the TREE's, so all
+    four shards record the same ``one_leaf`` in every wave, whatever arm each
+    took for its own rows."""
+    params = dict(BASE, tree_learner="data", num_machines=4)
+    g = _booster(params, rounds=2)._gbdt
+    assert g.spec.one_leaf_frac > 0
+    for rec in jax.device_get(g._grow_records):
+        one_leaf = np.asarray(rec.stats.one_leaf)[0]          # [D, W]
+        w = int(np.asarray(rec.stats.waves).max())
+        assert one_leaf.shape[0] == 4 and w >= 3
+        assert (one_leaf == one_leaf[:1]).all()
+        assert one_leaf[0, :2].all() and not one_leaf[0, :w].all()
+
+
+def test_wave_totals_counts_the_one_leaf_waves():
+    """Two devices: a one-leaf wave's touched rows are the pace-setting
+    shard's, streamed or compacted; the others' are left out."""
+    st = WaveStats(waves=np.array([3, 3]),
+                   rows_active=np.array([[100, 30, 50], [100, 45, 50]]),
+                   compacted=np.array([[False, True, True],
+                                       [False, True, True]]),
+                   rows_split=np.array([[100, 40, 0], [100, 70, 0]]),
+                   scan_pending=np.array([[1, 2, 4], [1, 2, 4]]),
+                   one_leaf=np.array([[True, True, False],
+                                      [True, True, False]]))
+    t = wave_totals(st, rows_per_device=100, chunk_rows=20, hist_slots=4)
+    assert t["one_leaf_passes"] == 2
+    assert t["hist_rows_one_leaf"] == 100 + 60            # ceil(45/20) chunks
+    assert t["hist_chunks_one_leaf"] == 8
+    assert t["hist_rows_touched"] == 100 + 60 + 60
+    assert t["hist_chunks"] == 11
+
+
+def test_a_program_without_the_form_publishes_no_one_leaf_count(clean_registry):
+    """On the CPU the Mosaic kernel does not exist: the spec says so, the
+    loop carries no ``one_leaf`` and nothing is published."""
+    bst = _booster(BASE, rounds=2)
+    g = bst._gbdt
+    assert g.spec.one_leaf_frac == 0
+    assert all(r.stats.one_leaf is None for r in g._grow_records)
+    bst._ensure_finalized()
+    reg = obs.get_registry()
+    assert reg.summary("grow.one_leaf_passes").count == 0
+    assert reg.summary("grow.hist_rows_one_leaf").count == 0
+    assert reg.summary("grow.waves").count == 2
