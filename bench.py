@@ -104,7 +104,7 @@ _PARTIAL = {}
 
 def _timed_update_phase(name, bst, warmup, timed, timings, tree_batch=1):
     """Warm up + time one booster's training loop with the attributable
-    per-phase breakdown (utils/timer.PhaseBreakdown): compile/warm-up
+    per-phase breakdown (observability.PhaseBreakdown): compile/warm-up
     wall-clock vs steady-state wall-clock vs host-sync + recompile counts
     from a record-only RecompileGuard. The breakdown lands in
     ``timings[name]`` (emitted as ``phase_timings`` in the BENCH json).
@@ -461,7 +461,7 @@ def run_bench(deadline, platform):
     if slots:
         params["tpu_hist_slots"] = slots
 
-    # attributable per-phase timing (utils/timer.PhaseBreakdown): every
+    # attributable per-phase timing (observability.PhaseBreakdown): every
     # timed phase records compile_s / steady_s / host_syncs / recompiles
     # here; emitted as "phase_timings" in the JSON (docs/TPU-Performance.md)
     timings = {}

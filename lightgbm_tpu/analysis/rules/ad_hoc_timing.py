@@ -5,16 +5,18 @@
 (or feed a local variable) and never reach the metrics registry, the
 span trace, or the BENCH json. The observability subsystem exists so
 every timing lands in ONE place — use ``observability.span(...)`` for
-wall-clock sections, ``PhaseBreakdown`` for compile/steady attribution,
-or a registry gauge for one-off durations. Worse, a naive ``perf_counter``
+wall-clock sections, ``observability.timed_span(...)`` where the seconds
+have to reach the registry with tracing off, ``PhaseBreakdown`` for
+compile/steady attribution, or a registry gauge for one-off durations. Worse, a naive ``perf_counter``
 pair around a jax dispatch measures *dispatch* time, not device time
 (execution is asynchronous) — the exact confusion the span docs call out.
 
 Scope: files under ``lightgbm_tpu/`` EXCEPT ``observability/`` itself
-(the subsystem is the one legitimate home of the primitive). Intentional
-sites elsewhere — the legacy TIMETAG accumulator in ``utils/timer.py`` —
-are baseline-exempt (``tpu_lint_baseline.json``), not rewritten: the
-baseline records the audit, and any NEW ad-hoc timer fails the lint.
+(the subsystem is the one legitimate home of the primitive). No site
+elsewhere is exempt: the TIMETAG accumulator that the baseline once
+carried (``utils/timer.py``) is gone, its summary a view of the registry
+(``observability.time_tag_summary``), and any NEW ad-hoc timer fails the
+lint.
 
 Both the dotted form (``time.perf_counter()``) and names imported via
 ``from time import perf_counter`` are caught; ``time.monotonic`` deadline
@@ -73,5 +75,4 @@ class AdHocTimingRule:
                     f"`{name}()` is ad-hoc wall-clock timing — route it "
                     f"through observability (span()/PhaseBreakdown/a "
                     f"registry gauge) so the measurement is findable in "
-                    f"the trace and snapshot; audited legacy sites belong "
-                    f"in tpu_lint_baseline.json")
+                    f"the trace and snapshot")

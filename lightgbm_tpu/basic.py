@@ -8,6 +8,7 @@ from typing import Any, Dict, List, Optional, Union
 
 import numpy as np
 
+from . import observability as obs
 from .config import Config
 from .dataset import ConstructedDataset, Metadata, construct_dataset
 from .tree import Tree
@@ -55,7 +56,6 @@ def _to_2d_float(data):
         # values it looks at (the bin-finding sample, a column, a chunk),
         # and f32 -> f64 is exact, so bins, codes and trees are the float64
         # copy's. Everything else becomes float64, as the reference reads it
-        from . import observability as obs
         with obs.setup_span("dataset.to_float"):
             arr = np.asarray(arr, dtype=np.float64)
     if arr.ndim == 1:
@@ -133,10 +133,20 @@ class Dataset:
     def construct(self, config: Optional[Config] = None) -> "Dataset":
         if self._constructed is not None or self._binned_aligned is not None:
             return self
+        # the training set's is one of set-up's two wholes (the other:
+        # ``booster.init``); a valid set's is a boundary of its own, so that
+        # one attached after ``booster.init`` leaves ``setup.*`` the
+        # training set's
+        with obs.setup_span("dataset.construct" if self.reference is None
+                            else "dataset.construct_valid"):
+            self._construct(config)
+        return self
+
+    def _construct(self, config: Optional[Config]) -> None:
         if self._binary_path is not None:
             self._constructed = ConstructedDataset.load_binary(self._binary_path)
             self.label = self._constructed.metadata.label
-            return self
+            return
         if self._stream_path is not None:
             from .io.file_io import stream_construct_dataset
             cfg = config or Config.from_params(self.params)
@@ -146,7 +156,7 @@ class Dataset:
                 else self.feature_name,
                 categorical_features=self.categorical_feature)
             self.label = self._constructed.metadata.label
-            return self
+            return
         if self.reference is not None:
             ref = self.reference
             ref.construct(config)
@@ -160,17 +170,14 @@ class Dataset:
             self._metadata = meta
         else:
             cfg = config or Config.from_params(self.params)
-            from .utils.timer import TIMERS
-            with TIMERS("dataset_construct"):
-                self._constructed = construct_dataset(
-                    self.raw_data, self.label, cfg,
-                    weight=self.weight, group=self.group,
-                    init_score=self.init_score,
-                    feature_names=self.feature_name,
-                    categorical_features=self.categorical_feature)
+            self._constructed = construct_dataset(
+                self.raw_data, self.label, cfg,
+                weight=self.weight, group=self.group,
+                init_score=self.init_score,
+                feature_names=self.feature_name,
+                categorical_features=self.categorical_feature)
         if self.free_raw_data:
             self.raw_data = None
-        return self
 
     @property
     def constructed(self) -> ConstructedDataset:
@@ -381,9 +388,6 @@ class Booster:
                  silent: bool = False):
         self.params = dict(params or {})
         self.config = Config.from_params(self.params)
-        if self.config.tpu_time_tag:
-            from .utils.timer import TIMERS
-            TIMERS.enabled = True
         self._gbdt = None
         self.trees: List[Tree] = []          # flattened tree list (iter-major)
         self._forest_rev = 0                 # bumped whenever trees change
@@ -419,7 +423,9 @@ class Booster:
         train_set.params.update(self.params)
         train_set.construct(self.config)
         cd = train_set.constructed
-        self._gbdt = create_boosting(self.config, cd)
+        # set-up's other whole; GBDT's constructor tiles it with its stages
+        with obs.setup_span("booster.init"):
+            self._gbdt = create_boosting(self.config, cd)
         # the booster may normalize config fields to their EFFECTIVE values
         # during construction (tpu_residency=stream forces
         # tpu_row_compact=false) — adopt them so the checkpoint fingerprint

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import observability as obs
 from ..config import Config
 from ..ops.predict import leaves_from_binned
 from ..utils.log import Log
@@ -84,6 +85,8 @@ class DART(GBDT):
                     drop.append(i)
         return drop
 
+    # the drop-set arithmetic is inside step.host_s and in none of its parts
+    @obs.step_call()
     def train_one_iter(self) -> None:
         cfg = self.config
         lr = cfg.learning_rate

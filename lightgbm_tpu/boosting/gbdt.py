@@ -40,7 +40,6 @@ from ..ops.histogram import (hist_pass_shape, num_channels,
 from ..parallel.comm import make_parallel_context, tree_collective_bytes
 from ..metrics import Metric, create_metrics
 from ..robustness import allowed_host_sync
-from ..utils.timer import TIMERS
 from ..objectives import Objective, create_objective
 from ..ops.predict import leaves_from_binned
 from ..tree import Tree, tree_from_device_arrays
@@ -134,8 +133,17 @@ class GBDT:
 
     def __init__(self, config: Config, train_set: ConstructedDataset,
                  objective: Optional[Objective] = None):
+        # one set-up span after another, no second unnamed: each
+        # ``stage(name)`` in ``_build`` ends the stage before it
+        # (docs/Observability.md, the tiled set-up tree)
+        with obs.setup_stages() as stage:
+            self._build(config, train_set, objective, stage)
+
+    def _build(self, config: Config, train_set: ConstructedDataset,
+               objective: Optional[Objective], stage) -> None:
         self.config = config
         self.train_set = train_set
+        stage("booster.mesh")
         # multi-host wiring FIRST — jax.distributed.initialize must run
         # before anything touches the XLA backend (mirrors the reference's
         # Network::Init-before-LoadData ordering, application.cpp:167-178)
@@ -213,9 +221,11 @@ class GBDT:
                      N, len(blocks), self._block_counts)
         self._meta_global = meta_global
 
+        stage("booster.objective")
         if self.objective is not None:
             self.objective.init(meta_global, N)
 
+        stage("booster.shapes")
         F = train_set.num_features
         # feature padding: block-partitioned strategies need F % devices == 0
         F_pad = self.pctx.pad_features_to(max(F, 1))
@@ -253,6 +263,7 @@ class GBDT:
         #        plans bundles from the same distributed sample it bins from,
         #        dataset_loader.cpp:820-899), then materializes its local
         #        shard against the common plan. ----
+        stage("booster.efb")
         self.bundle = None
         bundle_plan = None
         # legacy unpack arm (tpu_efb_unpack). The one unsupported native
@@ -361,6 +372,7 @@ class GBDT:
 
         # ---- histogram kernel shape (needs the FINAL column/bin layout,
         #      hence after EFB planning) ----
+        stage("booster.layout")
         # auto slots: 25 x 5 bf16 channels = 125 matmul columns — one full
         # MXU tile (128) — while quartering the wave count at 255 leaves.
         # User-set slot counts clamp to the leaf budget: the wave loop's
@@ -497,6 +509,7 @@ class GBDT:
                         "stream residency" if self.residency == "stream"
                         else "pre-partitioned/multi-process layout")
                 Xb = train_set.X_binned
+        stage("booster.fingerprint")
         # dataset fingerprint for checkpoint/resume: the config fingerprint
         # deliberately excludes data PATHS, so a resumed run pointed at a
         # different dataset of the same shape must be caught here — a strided
@@ -522,6 +535,7 @@ class GBDT:
         _fp.update(np.asarray(meta_global.label, np.float32).tobytes())
         self._data_fingerprint = _fp.hexdigest()
 
+        stage("booster.place_codes")
         # device placement of the (possibly bundled) code matrix: rows padded
         # to Npad (equal per-process blocks under pre-partition, where only
         # the LOCAL shard exists on this host), columns to the strategy pad.
@@ -610,6 +624,7 @@ class GBDT:
             self.Xb = train_set.device_put_cached(
                 ("Xb", Npad, cols_pad, str(_code_dtype), bundle_sig,
                  self.pctx.residency_key()), _build)
+        stage("booster.place")
         self.label = self._put(self._row_layout(meta_global.label, Npad), "rows")
         w = meta_global.weight
         self.weight = None if w is None else self._put(
@@ -633,6 +648,7 @@ class GBDT:
         ok = np.arange(F_pad) < F                           # padding features off
         self.feature_ok_base = self._put(ok)
 
+        stage("booster.spec")
         # packed-row code layout for the compacted gather: nibble-pack two
         # codes/byte at <=16 bins, 6-bit-pack four codes/3 bytes at <=64
         # (the reference's Dense4bitsBin analog, dense_nbits_bin.hpp:37, and
@@ -796,6 +812,7 @@ class GBDT:
         self.valid_sets: List[ValidSet] = []
 
         # ---- initial scores -------------------------------------------------
+        stage("booster.init_score")
         self.init_score_value = 0.0
         # meta_global, not train_set.metadata: under pre-partition the local
         # shard only holds its own init_score slice
@@ -817,6 +834,7 @@ class GBDT:
                               for k in range(K)])
         self.score = self._put(base, "rows1")
 
+        stage("booster.state")
         self.models: List[List] = []        # per iteration: list of K device TreeArrays
         self._grow_records: List[GrowRecord] = []   # per iteration, on device
         self._step_traces = 0               # times jax traced a step body
@@ -1594,7 +1612,8 @@ class GBDT:
         return consts, valid_Xb, valid_scores
 
     def _dispatch(self, site: str, fn, args):
-        """The jitted call itself, under the ``step.dispatch`` span: an
+        """The jitted call itself, under the ``step.dispatch`` span (the
+        ``launch`` part of the call's always-on record): an
         enqueue in steady state; trace and/or compile (or cache load) on a
         call whose signature jit has not seen. Two counters tell which:
         ``compile.step_traces`` moves when jax ran the step's Python body
@@ -1605,26 +1624,46 @@ class GBDT:
         reuse the trace and still pay the compile. Either way the instant
         event ``compile.step_trace`` records the arguments' abstract
         signature and which of its leaves differ from this site's previous
-        one: the answer to "what recompiled, and why"."""
-        before = (self._step_traces, fn._cache_size())
-        with obs.span("step.dispatch", site=site):
-            outs = fn(*args)
-        after = (self._step_traces, fn._cache_size())
-        if after != before:
-            obs.inc("compile.step_executables", after[1] - before[1])
-            sig = _abstract_signature(args)
-            last = self._trace_signatures.get(site, sig)
-            changed = {k: f"{last.get(k)} -> {v}" for k, v in sig.items()
-                       if last.get(k) != v}
-            self._trace_signatures[site] = sig
-            obs.event("compile.step_trace", site=site, traces=after[0],
-                      retraced=after[0] != before[0],
-                      executables=after[1], changed=changed, signature=sig)
-            if changed:
-                Log.info("%s: a new executable (number %d of this step; "
-                         "retraced: %s) because %s", site, after[1],
-                         after[0] != before[0], changed)
+        one: the answer to "what recompiled, and why"; and the call's own
+        seconds go to ``compile.step_first_call_s``, split by the durations
+        ``jax.monitoring`` reported inside it into ``compile.step_trace_s``,
+        ``compile.step_lower_s``, ``compile.step_backend_s`` (compile or
+        load) and ``compile.step_cache_load_s`` (the load alone)."""
+        with obs.step_part("launch", site=site) as t0:
+            before = (self._step_traces, fn._cache_size())
+            with obs.compile_watch() as fired:
+                outs = fn(*args)
+            after = (self._step_traces, fn._cache_size())
+            if after != before:
+                self._record_new_executable(site, args, before, after,
+                                            obs.clock() - t0, fired)
         return outs
+
+    def _record_new_executable(self, site: str, args, before, after,
+                               first_call_s: float, fired) -> None:
+        """``_dispatch``'s record of a call that traced and/or compiled:
+        the counters, the ``compile.step_trace`` event, the INFO line."""
+        split = obs.compile_split(fired)
+        reg = obs.get_registry()
+        reg.counter("compile.step_first_call_s").inc(first_call_s)
+        for kind, seconds in split.items():
+            reg.counter(f"compile.step_{kind}_s").inc(seconds)
+        obs.inc("compile.step_executables", after[1] - before[1])
+        sig = _abstract_signature(args)
+        last = self._trace_signatures.get(site, sig)
+        changed = {k: f"{last.get(k)} -> {v}" for k, v in sig.items()
+                   if last.get(k) != v}
+        self._trace_signatures[site] = sig
+        obs.event("compile.step_trace", site=site, traces=after[0],
+                  retraced=after[0] != before[0],
+                  executables=after[1], changed=changed, signature=sig,
+                  first_call_s=round(first_call_s, 6),
+                  **{f"{kind}_s": round(seconds, 6)
+                     for kind, seconds in split.items()})
+        if changed:
+            Log.info("%s: a new executable (number %d of this step; "
+                     "retraced: %s) because %s", site, after[1],
+                     after[0] != before[0], changed)
 
     def _capture_step_cost(self, site: str, fn, args, batch: int) -> None:
         """Cost-report leg of the dispatch protocol (observability/costs.py,
@@ -1651,7 +1690,7 @@ class GBDT:
         """Dispatch one compiled step against current state; returns new score
         and per-valid score tuples (device)."""
         site = "train_step.k1" + (".custom" if custom_gh is not None else "")
-        with obs.span("step.prep"):
+        with obs.step_part("prep"):
             if custom_gh is None:
                 if self._step_fn is None:
                     self._step_fn = self._make_step()
@@ -1671,7 +1710,7 @@ class GBDT:
                 # dispatch then hits
                 self._capture_step_cost(site, fn, args, 1)
         outs = self._dispatch(site, fn, args)
-        with obs.span("step.post"):
+        with obs.step_part("post"):
             nf = None
             if self.nan_policy != "none":
                 (score, out_valid, self.bag_mask, trees, record,
@@ -1751,7 +1790,7 @@ class GBDT:
         # span nesting mirrors the fused path: one dispatch ("tree_batch",
         # k=1) holding one iteration — host-side bookkeeping only, no device
         # value is read (the recompile-free steady state is preserved)
-        with TIMERS("train_step"), obs.span("tree_batch", k=1), \
+        with obs.step_call(), obs.span("tree_batch", k=1), \
                 obs.span("iteration", iteration=self.iter_):
             if self.residency == "stream":
                 score, out_valid = self._run_streamed_step(
@@ -1863,62 +1902,67 @@ class GBDT:
         StreamedGrower.grow over the shard prefetcher -> shrink) -> apply,
         with the SAME host bookkeeping contract as ``_run_step`` (models
         appended, counters advanced, then the nan policy fetch)."""
-        if self._stream_fns is None:
-            self._stream_fns = self._make_stream_fns()
-        fns = self._stream_fns
-        self._place_step_scalars(shrinkage)
-        valid_scores = tuple(tuple(vs.score[k] for k in range(self.num_models))
-                             for vs in self.valid_sets)
-        valid_Xb = tuple(vs.Xb for vs in self.valid_sets)
-        if custom_gh is not None:
-            outs = fns["pre_custom"](self.score, self.bag_mask,
-                                     self._rng_key, self._iter_dev,
-                                     *custom_gh)
-        else:
-            outs = fns["pre"](self.score, self.bag_mask, self._rng_key,
-                              self._iter_dev)
-        if self.nan_policy != "none":
-            g, h, mask, fkey, bad_g, bad_h = outs
-        else:
-            g, h, mask, fkey = outs
-            bad_g = bad_h = None
-        trees, leaf_ids, bad_leafs = [], [], []
-        for k in range(self.num_models):
-            gk, hk, fmask = fns["prep"](g, h, mask, fkey, np.int32(k))
-            tree_raw, lid = self._streamed_grower.grow(
-                self._stream, gk, hk, mask, fmask)
-            tree, bl = fns["shrink"](tree_raw, self._shrink_cache[1])
-            if bl is not None:
-                bad_leafs.append(bl)
-            trees.append(tree)
-            leaf_ids.append(lid)
-        flags = ((bad_g, bad_h, tuple(bad_leafs))
-                 if self.nan_policy != "none" else None)
-        outs = fns["apply"](self.score, valid_scores, valid_Xb,
-                            self.bag_mask, mask, tuple(trees),
-                            tuple(leaf_ids), self._iter_dev, flags)
-        nf = None
-        if self.nan_policy != "none":
-            score, out_valid, self.bag_mask, nl, self._iter_dev, nf = outs
-        else:
-            score, out_valid, self.bag_mask, nl, self._iter_dev = outs
-        self.models.append(list(trees))
-        # the host drives a streamed tree's waves: no loop on the device,
-        # so no record of one
-        self._grow_records.append(GrowRecord(nl, None))
-        self.iter_ += 1
-        self.mutations_ = getattr(self, "mutations_", 0) + 1
-        if nf is not None:
-            try:
-                self._apply_nan_policy(nf)
-            except Exception:
-                # the pre-step score/valid buffers were DONATED to apply —
-                # rebind the (gated, bit-identical) outputs before
-                # propagating, exactly like the resident path
-                self.score = score
-                for vi, vs in enumerate(self.valid_sets):
-                    vs.score = jnp.stack(out_valid[vi])
-                raise
+        # the same three parts as the resident step; the host drives the
+        # shard loop, so every leg from ``pre`` to ``apply`` is the launch
+        with obs.step_part("prep", streamed=True):
+            if self._stream_fns is None:
+                self._stream_fns = self._make_stream_fns()
+            fns = self._stream_fns
+            self._place_step_scalars(shrinkage)
+            valid_scores = tuple(tuple(vs.score[k] for k in range(self.num_models))
+                                 for vs in self.valid_sets)
+            valid_Xb = tuple(vs.Xb for vs in self.valid_sets)
+        with obs.step_part("launch", site="train_step.stream"):
+            if custom_gh is not None:
+                outs = fns["pre_custom"](self.score, self.bag_mask,
+                                         self._rng_key, self._iter_dev,
+                                         *custom_gh)
+            else:
+                outs = fns["pre"](self.score, self.bag_mask, self._rng_key,
+                                  self._iter_dev)
+            if self.nan_policy != "none":
+                g, h, mask, fkey, bad_g, bad_h = outs
+            else:
+                g, h, mask, fkey = outs
+                bad_g = bad_h = None
+            trees, leaf_ids, bad_leafs = [], [], []
+            for k in range(self.num_models):
+                gk, hk, fmask = fns["prep"](g, h, mask, fkey, np.int32(k))
+                tree_raw, lid = self._streamed_grower.grow(
+                    self._stream, gk, hk, mask, fmask)
+                tree, bl = fns["shrink"](tree_raw, self._shrink_cache[1])
+                if bl is not None:
+                    bad_leafs.append(bl)
+                trees.append(tree)
+                leaf_ids.append(lid)
+            flags = ((bad_g, bad_h, tuple(bad_leafs))
+                     if self.nan_policy != "none" else None)
+            outs = fns["apply"](self.score, valid_scores, valid_Xb,
+                                self.bag_mask, mask, tuple(trees),
+                                tuple(leaf_ids), self._iter_dev, flags)
+        with obs.step_part("post"):
+            nf = None
+            if self.nan_policy != "none":
+                score, out_valid, self.bag_mask, nl, self._iter_dev, nf = outs
+            else:
+                score, out_valid, self.bag_mask, nl, self._iter_dev = outs
+            self.models.append(list(trees))
+            # the host drives a streamed tree's waves: no loop on the device,
+            # so no record of one
+            self._grow_records.append(GrowRecord(nl, None))
+            self.iter_ += 1
+            self.mutations_ = getattr(self, "mutations_", 0) + 1
+            if nf is not None:
+                try:
+                    self._apply_nan_policy(nf)
+                except Exception:
+                    # the pre-step score/valid buffers were DONATED to apply —
+                    # rebind the (gated, bit-identical) outputs before
+                    # propagating, exactly like the resident path
+                    self.score = score
+                    for vi, vs in enumerate(self.valid_sets):
+                        vs.score = jnp.stack(out_valid[vi])
+                    raise
         return score, out_valid
 
     # --------------------------------------------- fused multi-tree dispatch
@@ -1943,13 +1987,13 @@ class GBDT:
         # the fused scan is ONE dispatch: there is no host boundary between
         # its iterations, so no per-iteration span (the device trace's
         # ``step.*`` scopes show them)
-        with TIMERS("train_step"), \
+        with obs.step_call(), \
                 obs.span("tree_batch", k=n, iteration=self.iter_):
             self._run_fused_batch(n)
 
     def _run_fused_batch(self, n: int) -> None:
         site = f"train_step.k{n}"
-        with obs.span("step.prep"):
+        with obs.step_part("prep"):
             fn = self._batch_step_fns.get(n)
             if fn is None:
                 fn = self._make_step(batch=n)
@@ -1962,7 +2006,7 @@ class GBDT:
             if obs_costs.enabled():
                 self._capture_step_cost(site, fn, args, n)
         outs = self._dispatch(site, fn, args)
-        with obs.span("step.post"):
+        with obs.step_part("post"):
             nf = None
             if self.nan_policy != "none":
                 (score, out_valid, self.bag_mask, trees, records,
@@ -2073,15 +2117,18 @@ class GBDT:
             Log.fatal("custom objectives are not supported with "
                       "is_pre_partition (host gradients need the full score "
                       "vector on every process)")
-        with obs.span("tree_batch", k=1, custom_fobj=True), \
+        with obs.step_call(), obs.span("tree_batch", k=1, custom_fobj=True), \
                 obs.span("iteration", iteration=self.iter_):
-            preds = self._fetch(self.score)[:, :N].reshape(-1)
-            grad, hess = fobj(preds, self.train_set)
-            g = np.zeros((K, Npad), np.float32)
-            h = np.zeros((K, Npad), np.float32)
-            g[:, :N] = np.asarray(grad, np.float32).reshape(K, N)
-            h[:, :N] = np.asarray(hess, np.float32).reshape(K, N)
-            custom_gh = (self._put(g, "rows1"), self._put(h, "rows1"))
+            # the score's fetch, the user's objective and the gradients'
+            # upload are this call's preparation too
+            with obs.step_part("prep", custom_fobj=True):
+                preds = self._fetch(self.score)[:, :N].reshape(-1)
+                grad, hess = fobj(preds, self.train_set)
+                g = np.zeros((K, Npad), np.float32)
+                h = np.zeros((K, Npad), np.float32)
+                g[:, :N] = np.asarray(grad, np.float32).reshape(K, N)
+                h[:, :N] = np.asarray(hess, np.float32).reshape(K, N)
+                custom_gh = (self._put(g, "rows1"), self._put(h, "rows1"))
             if self.residency == "stream":
                 score, out_valid = self._run_streamed_step(
                     self.config.learning_rate, custom_gh=custom_gh)
@@ -2263,7 +2310,10 @@ class GBDT:
                  ) -> List[Tuple[str, str, float, bool]]:
         """only=<dataset name>: evaluate just that dataset (single-dataset
         entry points must not pay for every attached valid set)."""
-        with TIMERS("metric_eval"), obs.span("eval", only=only):
+        # always on: host seconds of each evaluation, in ``eval.host_s``
+        with obs.timed_span(
+                "eval", obs.get_registry().summary("eval.host_s").observe,
+                only=only):
             return self._eval_all(force_training, only)
 
     def _eval_all(self, force_training=False, only=None
@@ -2628,7 +2678,7 @@ class GBDT:
         are published here: wherever the trees come to the host, the wave
         loop's counters come with them, and nowhere else."""
         base = min(self._telemetry_iters_base, len(self.models))
-        with TIMERS("finalize_fetch"), obs.setup_span("finalize.fetch"):
+        with obs.setup_span("finalize.fetch"):
             if self.pctx.multi_process:
                 host = jax.device_get(self.models)
                 records = self._fetch_records(self._grow_records[base:])

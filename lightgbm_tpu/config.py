@@ -409,8 +409,9 @@ class Config:
     # OpenCL histogram256.cl analog) | "mixed" (pallas for compacted passes
     # only); chip_smoke.py compiles and checks "mixed" on the chip every run
     tpu_hist_kernel: str = "auto"
-    # per-phase wall-clock accumulators (reference TIMETAG) printed after
-    # training; tpu_profile_dir wraps training in a jax.profiler trace
+    # the reference's TIMETAG summary, printed after training from the
+    # registry's always-on records (observability.time_tag_summary; env
+    # LGBM_TPU_TIMETAG); tpu_profile_dir wraps training in a jax.profiler trace
     tpu_time_tag: bool = False
     tpu_profile_dir: str = ""
     # jax.profiler capture WINDOW "start:stop" over boosting iterations

@@ -459,26 +459,28 @@ def construct_dataset(
     (dataset_loader.cpp:748-903): sample -> FindBin per feature -> drop
     trivial features -> materialize bin codes.
     """
-    sparse = hasattr(data, "tocsc")
-    if sparse:
-        data = data.tocsc()            # columnwise access for binning
-    else:
-        data = np.ascontiguousarray(data)
-    if data.ndim != 2:
-        Log.fatal("Training data must be 2-dimensional")
-    num_data, num_total_features = data.shape
-    if feature_names is None:
-        feature_names = [f"Column_{i}" for i in range(num_total_features)]
-
-    # resolve categorical / ignored columns
-    cat_set = set()
-    if categorical_features is not None:
-        for c in categorical_features:
-            cat_set.add(feature_names.index(c) if isinstance(c, str) else int(c))
-    cat_set.update(_parse_column_spec(config.categorical_column, feature_names))
-    ignore_set = set(_parse_column_spec(config.ignore_column, feature_names))
-
     from . import observability as obs
+    # the table as binning reads it, and which columns are what
+    with obs.setup_span("dataset.columns"):
+        sparse = hasattr(data, "tocsc")
+        if sparse:
+            data = data.tocsc()            # columnwise access for binning
+        else:
+            data = np.ascontiguousarray(data)
+        if data.ndim != 2:
+            Log.fatal("Training data must be 2-dimensional")
+        num_data, num_total_features = data.shape
+        if feature_names is None:
+            feature_names = [f"Column_{i}" for i in range(num_total_features)]
+
+        # resolve categorical / ignored columns
+        cat_set = set()
+        if categorical_features is not None:
+            for c in categorical_features:
+                cat_set.add(feature_names.index(c) if isinstance(c, str) else int(c))
+        cat_set.update(_parse_column_spec(config.categorical_column, feature_names))
+        ignore_set = set(_parse_column_spec(config.ignore_column, feature_names))
+
     # bin finding: the row sample, then the quantiles of each feature
     with obs.setup_span("dataset.find_bins"):
         # sampling (dataset_loader.cpp:688-746)
@@ -506,51 +508,57 @@ def construct_dataset(
 
     dtype = np.uint8 if all(f.mapper.num_bin <= 256 for f in features) else np.uint16
 
-    deferred = _maybe_defer(data, features, config, dtype, num_data, sparse)
-    if deferred is not None:
-        X_binned = None
-    elif sparse:
-        X_binned = np.zeros((num_data, max(len(features), 1)), dtype=dtype)
+    # whether the device can bin these rows (for float64 input that is
+    # the round-trip check, ``dataset.lossless_check``, a child of this)
+    with obs.setup_span("dataset.ingest_check"):
+        deferred = _maybe_defer(data, features, config, dtype, num_data, sparse)
+    # host binning: nothing where binning was deferred to the device
+    with obs.setup_span("dataset.bin_host"):
+        if deferred is not None:
+            X_binned = None
+        elif sparse:
+            X_binned = np.zeros((num_data, max(len(features), 1)), dtype=dtype)
 
-        def _bin_column(inner_f):
-            # bin the implicit zeros once, scatter only the stored values
-            # (the float matrix is never densified; the dense uint8 bin
-            # matrix IS the design's storage — dataset.py:6-14); the zero
-            # bin is default_bin (asserted at mapper construction), and
-            # the fancy-index assignment casts to the output dtype in one
-            # pass
-            inner, f = inner_f
-            rows, vals = _csc_column(data, f.real_index)
-            X_binned[:, inner] = dtype(f.mapper.default_bin)
-            if len(rows):
-                X_binned[rows, inner] = f.mapper.value_to_bin(vals)
+            def _bin_column(inner_f):
+                # bin the implicit zeros once, scatter only the stored values
+                # (the float matrix is never densified; the dense uint8 bin
+                # matrix IS the design's storage — dataset.py:6-14); the zero
+                # bin is default_bin (asserted at mapper construction), and
+                # the fancy-index assignment casts to the output dtype in one
+                # pass
+                inner, f = inner_f
+                rows, vals = _csc_column(data, f.real_index)
+                X_binned[:, inner] = dtype(f.mapper.default_bin)
+                if len(rows):
+                    X_binned[rows, inner] = f.mapper.value_to_bin(vals)
 
-        if num_data * max(len(features), 1) > 8_000_000 and len(features) > 1:
-            from concurrent.futures import ThreadPoolExecutor
-            workers = min(16, os.cpu_count() or 1, len(features))
-            with ThreadPoolExecutor(workers) as pool:
-                list(pool.map(_bin_column, enumerate(features)))
+            if num_data * max(len(features), 1) > 8_000_000 and len(features) > 1:
+                from concurrent.futures import ThreadPoolExecutor
+                workers = min(16, os.cpu_count() or 1, len(features))
+                with ThreadPoolExecutor(workers) as pool:
+                    list(pool.map(_bin_column, enumerate(features)))
+            else:
+                for item in enumerate(features):
+                    _bin_column(item)
         else:
-            for item in enumerate(features):
-                _bin_column(item)
-    else:
-        X_binned = bin_dense_host(
-            data, [f.mapper for f in features],
-            np.array([f.real_index for f in features], np.int64),
-            dtype, num_data)
+            X_binned = bin_dense_host(
+                data, [f.mapper for f in features],
+                np.array([f.real_index for f in features], np.int64),
+                dtype, num_data)
 
-    metadata = Metadata(num_data)
-    if label is not None:
-        metadata.set_label(label)
-    metadata.set_weight(weight)
-    metadata.set_group(group)
-    metadata.set_init_score(init_score)
+    with obs.setup_span("dataset.metadata"):
+        metadata = Metadata(num_data)
+        if label is not None:
+            metadata.set_label(label)
+        metadata.set_weight(weight)
+        metadata.set_group(group)
+        metadata.set_init_score(init_score)
 
-    ds = ConstructedDataset(X_binned, features, num_total_features, metadata,
-                            feature_names, config, deferred=deferred)
-    if getattr(config, "linear_tree", False):
-        ds.X_raw = extract_raw_slice(
-            data, [f.real_index for f in features], num_data)
+        ds = ConstructedDataset(X_binned, features, num_total_features, metadata,
+                                feature_names, config, deferred=deferred)
+        if getattr(config, "linear_tree", False):
+            ds.X_raw = extract_raw_slice(
+                data, [f.real_index for f in features], num_data)
     return ds
 
 

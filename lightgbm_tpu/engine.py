@@ -276,16 +276,13 @@ def train(params: Dict[str, Any], train_set: Dataset,
                     tree_batch)
         tree_batch = 1
     metric_freq = max(config.metric_freq, 1)
-    from .utils.timer import TIMERS, maybe_xla_trace
-    if config.tpu_time_tag:
-        TIMERS.enabled = True
     # ---- telemetry (lightgbm_tpu/observability, docs/Observability.md) -----
     # span recording turned on above when a telemetry dir is configured
     # (param or LGBM_TPU_TELEMETRY_DIR); the metrics registry is always
     # live. The optional jax.profiler window (tpu_profile_iters) captures a
     # bounded iteration range at batch boundaries; it supersedes the
     # whole-run tpu_profile_dir trace (double-tracing is a jax error).
-    from .observability.profiler import ProfileWindow
+    from .observability.profiler import ProfileWindow, maybe_xla_trace
     if config.telemetry_dir:
         obs.configure(telemetry_dir=config.telemetry_dir)
     _profile_out = config.tpu_profile_dir or (
@@ -446,7 +443,10 @@ def train(params: Dict[str, Any], train_set: Dataset,
             obs_costs.configure(enabled=False)
 
     booster._finalize()
-    TIMERS.dump()       # reference TIMETAG destructor dump (gbdt.cpp)
+    if config.tpu_time_tag or os.environ.get("LGBM_TPU_TIMETAG"):
+        # the reference's TIMETAG destructor dump (gbdt.cpp), as a view of
+        # the registry's always-on records
+        Log.info("%s", obs.time_tag_summary())
     if best_iteration:
         # best_iteration indexes the FULL forest (prev + new): predict()
         # slices self.trees from the front

@@ -30,7 +30,9 @@ from typing import Dict, Optional
 
 
 class Counter:
-    """Monotonic event count (e.g. ``comm.retries``)."""
+    """Monotonic event count (e.g. ``comm.retries``), or a monotonic sum of
+    seconds where ``inc`` is given a float
+    (``compile.step_first_call_s``)."""
     __slots__ = ("name", "_lock", "value")
 
     def __init__(self, name: str, lock: threading.Lock):
@@ -40,7 +42,7 @@ class Counter:
 
     def inc(self, n: int = 1) -> None:
         with self._lock:
-            self.value += int(n)
+            self.value += n if isinstance(n, float) else int(n)
 
 
 class Gauge:

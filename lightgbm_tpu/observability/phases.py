@@ -1,13 +1,11 @@
 """Attributable per-phase device timing (``PhaseBreakdown``).
 
-Moved here from ``utils/timer.py`` so the bench's ``phase_timings`` are a
-CONSUMER of the observability subsystem instead of a parallel
-implementation: ``to_dict()`` output is byte-compatible with the historical
+The bench's ``phase_timings`` are a CONSUMER of the observability
+subsystem, not a parallel implementation: ``to_dict()`` output is byte-compatible with the historical
 BENCH json schema (the BENCH_r* trajectory scripts parse it), and every
 breakdown also lands in the process-wide metrics registry as
 ``phase.<name>.*`` gauges so a live snapshot sees the same numbers the
-bench prints. ``utils.timer.PhaseBreakdown`` remains as a re-export for
-existing imports.
+bench prints.
 
     pb = PhaseBreakdown("headline")
     with pb.compile_window():      # warm-up: compiles allowed

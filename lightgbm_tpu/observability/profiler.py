@@ -3,7 +3,7 @@
 ``tpu_profile_iters=start:stop`` captures a device-level profile (XProf /
 TensorBoard / Perfetto) of exactly the iterations ``[start, stop)`` instead
 of the whole run (``tpu_profile_dir`` alone wraps the full training loop in
-one trace — utils/timer.maybe_xla_trace). The window is the deep-profiling
+one trace — ``maybe_xla_trace`` below). The window is the deep-profiling
 leg of the telemetry contract: host-side spans (tracer.py) attribute
 dispatch boundaries; the profiler window attributes the device program
 (histogram / split / partition) for the chosen iterations only, keeping
@@ -20,9 +20,22 @@ jax-free environments (the lint CLI imports the observability package).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
 from ..utils.log import Log
+
+
+@contextlib.contextmanager
+def maybe_xla_trace(profile_dir: str):
+    """jax.profiler trace wrapper — the deep-profiling hook (XProf), gated
+    on a non-empty directory (config tpu_profile_dir)."""
+    if not profile_dir:
+        yield
+        return
+    import jax
+    with jax.profiler.trace(profile_dir):
+        yield
 
 
 def parse_profile_iters(spec: str) -> Optional[Tuple[int, int]]:

@@ -12,7 +12,13 @@ modelled):
                                 + cache load on a first call
           step.post             bookkeeping, nan policy
       eval | comm | checkpoint  real host-side operations
-    dataset.* | ingest | ingest.compile | finalize.fetch   set-up boundaries
+    dataset.construct > dataset.* | booster.init > booster.* > ingest >
+    ingest.compile | finalize.fetch          set-up, tiled
+                                             (docs/Observability.md)
+
+Some of these boundaries are timed ALWAYS, tracer on or off
+(``observability.timed_span``: set-up's, the step's three, ``eval``): the
+tracer decides only whether the span is kept.
 
 What happens INSIDE a dispatch has no host boundary: the wave loop runs on
 the device. Its phases are ``jax.named_scope`` names in the compiled

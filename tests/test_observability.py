@@ -157,9 +157,17 @@ def test_phase_breakdown_schema_and_registry(clean_registry):
     assert gauges["phase.unit.steady_iters"] == 4
 
 
-def test_phase_breakdown_reexported_from_utils_timer():
-    from lightgbm_tpu.utils.timer import PhaseBreakdown as FromTimer
-    assert FromTimer is PhaseBreakdown
+def test_utils_timer_is_gone_and_its_two_survivors_have_one_home():
+    """``utils/timer.py`` went with ``TIMERS``: ``PhaseBreakdown`` is
+    observability's (bench.py imports it from there), ``maybe_xla_trace``
+    sits beside ``ProfileWindow``."""
+    import importlib
+    with pytest.raises(ImportError):
+        importlib.import_module("lightgbm_tpu.utils.timer")
+    assert obs.PhaseBreakdown is PhaseBreakdown
+    from lightgbm_tpu.observability.profiler import maybe_xla_trace
+    with maybe_xla_trace(""):        # no directory: no trace, no jax
+        pass
 
 
 def test_recompile_guard_publishes_to_registry(clean_registry):
