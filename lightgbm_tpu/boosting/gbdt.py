@@ -279,20 +279,24 @@ class GBDT:
                 and bool(meta["is_categorical"].any())):
             self._efb_unpack = True
             _efb_unpack_forced = True
-        if config.enable_bundle != "false" and F >= 2:
-            from ..efb import (_SAMPLE_ROWS, plan_bundles,
-                               sample_row_indices, sample_rows)
-            efb_sample = None
-            efb_ndata = None
-            X_for_plan = None
+        from ..efb import (_SAMPLE_ROWS, no_pair_fits, plan_bundles,
+                           sample_row_indices, sample_rows)
+        efb_num_bins = meta["num_bins"].astype(np.int64)
+        efb_default_bin = meta["default_bin"].astype(np.int64)
+        # the bin rule first: where no two features can share a group by
+        # their bins alone (fewer than two features included) there is no
+        # plan, so no sample is drawn, binned or exchanged. It reads the
+        # global bin mappers only, identical on every rank, so under
+        # pre-partition every rank skips the host_allgather below together.
+        efb_sample = None
+        if (config.enable_bundle != "false"
+                and not no_pair_fits(efb_num_bins, efb_default_bin)):
             if self._block_counts is not None:
                 from ..parallel.comm import host_allgather
                 per_rank = max(1, _SAMPLE_ROWS // len(self._block_counts))
                 parts = host_allgather(
                     sample_rows(train_set.X_binned, per_rank), "efb_sample")
                 efb_sample = np.concatenate(parts, axis=0)
-                efb_ndata = N
-                X_for_plan = train_set.X_binned
             elif train_set.deferred:
                 # deferred device ingest: plan from a host-binned row
                 # SAMPLE (the plan is a pure function of the sample, and
@@ -300,13 +304,17 @@ class GBDT:
                 # full host bin matrix is only materialized below if the
                 # plan actually wins
                 efb_sample = train_set.bin_rows(sample_row_indices(N))
-                efb_ndata = N
             else:
-                X_for_plan = train_set.X_binned
-            plan = plan_bundles(X_for_plan,
-                                meta["num_bins"].astype(np.int64),
-                                meta["default_bin"].astype(np.int64), config,
-                                sample=efb_sample, num_data=efb_ndata)
+                efb_sample = sample_rows(train_set.X_binned)
+        # rows of the planning sample binned or read; 0 where no sample was
+        # drawn (the bin rule decided, or bundling is off)
+        obs.get_registry().gauge("efb.sample_rows").set(
+            0 if efb_sample is None else efb_sample.shape[0])
+        if efb_sample is not None:
+            plan = plan_bundles(
+                None if train_set.deferred else train_set.X_binned,
+                efb_num_bins, efb_default_bin, config,
+                sample=efb_sample, num_data=N)
             if plan is not None:
                 Bb_pad = max(8, _round_up(plan.max_bundle_bins, 8))
                 # the BundlePlan win ratio: bundling wins when it shrinks

@@ -132,6 +132,29 @@ def build_code_feat(plan: "BundlePlan", cols_pad: int, bins_pad: int,
     return cf
 
 
+def _nbins_eff(num_bins: np.ndarray, default_bin: np.ndarray) -> np.ndarray:
+    """Bundle codes a feature takes in a group: its bins, less the default
+    bin's code where that bin is 0 (the FeatureGroup encoding above)."""
+    return (np.asarray(num_bins, np.int64)
+            - (np.asarray(default_bin) == 0).astype(np.int64))
+
+
+def no_pair_fits(num_bins: np.ndarray, default_bin: np.ndarray,
+                 max_group_bins: int = 256) -> bool:
+    """True when no two features can share a group by their bins alone.
+
+    A group opens at ``1 + nbins_eff[f]`` codes and admits ``g`` only where
+    the sum stays within ``max_group_bins`` (:func:`_find_groups`), so if
+    the two smallest ``nbins_eff`` already overflow, every feature is its own
+    group and :func:`plan_bundles` returns None whatever the sample holds:
+    the answer is known before a single sample row is binned (a table of
+    255-bin columns: 1 + 254 + 254 > 256)."""
+    nbins_eff = _nbins_eff(num_bins, default_bin)
+    if nbins_eff.shape[0] < 2:
+        return True
+    return 1 + int(np.partition(nbins_eff, 1)[:2].sum()) > max_group_bins
+
+
 def sample_row_indices(num_data: int, max_rows: int = _SAMPLE_ROWS,
                        rng_seed: int = 1) -> np.ndarray:
     """The sorted row indices :func:`sample_rows` would draw — exposed so
@@ -187,8 +210,8 @@ def plan_bundles(X_binned: Optional[np.ndarray], num_bins: np.ndarray,
         N, F = int(num_data), sample.shape[1]
     else:
         N, F = X_binned.shape
-    if F < 2:
-        return None
+    if no_pair_fits(num_bins, default_bin, max_group_bins):
+        return None                      # every feature its own group
     # conflict estimation on a row sample (the reference uses its
     # bin-construction sample; we sample the materialized bin matrix)
     if sample is None:
@@ -199,7 +222,7 @@ def plan_bundles(X_binned: Optional[np.ndarray], num_bins: np.ndarray,
 
     masks = sample != default_bin[None, :]                   # non-default mask
     counts = np.count_nonzero(masks, axis=0)
-    nbins_eff = num_bins - (default_bin == 0).astype(np.int64)
+    nbins_eff = _nbins_eff(num_bins, default_bin)
 
     max_error_cnt = int(S * getattr(config, "max_conflict_rate", 0.0))
     filter_cnt = (0.95 * getattr(config, "min_data_in_leaf", 20)
