@@ -221,7 +221,7 @@ class GBDT:
                      N, len(blocks), self._block_counts)
         self._meta_global = meta_global
 
-        stage("booster.objective")
+        stage("booster.objective_init")
         if self.objective is not None:
             self.objective.init(meta_global, N)
 
@@ -1092,7 +1092,9 @@ class GBDT:
                      and config.bagging_fraction < 1.0),
             tree_batch=max(1, config.tree_batch),
             linear_max_features=(config.linear_max_features
-                                 if config.linear_tree else 0))
+                                 if config.linear_tree else 0),
+            objective_bytes=(self.objective.device_bytes()
+                             if self.objective is not None else 0))
         if est["total_bytes"] <= budget:
             return "device"
         gb = 1 << 30
@@ -1405,6 +1407,10 @@ class GBDT:
         consts["valid_raw"] = tuple((vs.Xraw, vs.Xmiss)
                                     for vs in self.valid_sets) \
             if self.linear_tree else None
+        # the objective's own device arrays (lambdarank's query layout):
+        # closed over, they would be baked into the executable as constants
+        consts["objective"] = (self.objective.device_arrays
+                               if self.objective is not None else None)
         return consts, tuple(vs.Xb for vs in self.valid_sets)
 
     def _make_step(self, custom_grads: bool = False, batch: int = 1,
@@ -1443,8 +1449,12 @@ class GBDT:
             saved = {a: getattr(self, a) for a in self._STEP_CONSTS}
             saved_vXb = [vs.Xb for vs in self.valid_sets]
             saved_vraw = [(vs.Xraw, vs.Xmiss) for vs in self.valid_sets]
+            objective = self.objective
+            saved_objective = objective.device_arrays if objective else None
             for a in self._STEP_CONSTS:
                 setattr(self, a, consts[a])
+            if objective is not None:
+                objective.device_arrays = consts["objective"]
             for vs, xb in zip(self.valid_sets, valid_Xb):
                 vs.Xb = xb
             if linear_tree:     # static: self.linear_tree, fixed per booster
@@ -1464,6 +1474,8 @@ class GBDT:
                     vs.Xb = xb
                 for vs, (xr, xm) in zip(self.valid_sets, saved_vraw):
                     vs.Xraw, vs.Xmiss = xr, xm
+                if objective is not None:
+                    objective.device_arrays = saved_objective
 
         def batch_body(score, valid_scores, bag_mask, key, it, shrinkage):
             # tree_batch fusion: `batch` whole iterations under ONE lax.scan

@@ -40,11 +40,20 @@ class Objective:
     num_models = 1
     is_constant_hessian = False
 
+    # device arrays ``gradients`` reads besides its arguments; the training
+    # step takes them as an argument (GBDT._step_consts), never as constants
+    device_arrays = None
+
     def __init__(self, config: Config):
         self.config = config
 
     def init(self, metadata: Metadata, num_data: int) -> None:
         self.num_data = num_data
+
+    def device_bytes(self) -> int:
+        """HBM the objective adds to a training step (the pre-flight's
+        ``objective`` term): none for an elementwise objective."""
+        return 0
 
     def gradients(self, score: jnp.ndarray, label: jnp.ndarray,
                   weight: Optional[jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -388,15 +397,32 @@ def default_label_gain() -> List[float]:
 class LambdarankNDCG(Objective):
     """lambdarank (rank_objective.hpp:19-208).
 
-    TPU formulation: queries are padded to power-of-two bucket lengths and
-    processed as batched [Qchunk, M, M] pairwise matrices — the reference's
-    per-query double loop (rank_objective.hpp:113-160) with the sigmoid lookup
-    table replaced by direct computation. A host-precomputed permutation maps
-    bucket layout back to row order with gathers only (no TPU scatters).
+    TPU formulation: each query is padded to a power-of-two length class
+    (8, 16, ..., the first at or above the longest query) and a class runs
+    as ``[chunk, M, M]`` pair blocks under ``lax.map`` (scope
+    ``rank.pairwise``; ``chunk`` = queries a block, ``QUERY_CHUNK_BUDGET``
+    pair slots or one query): the reference's per-query double loop
+    (rank_objective.hpp:113-160) with the sigmoid lookup table replaced by
+    direct computation. Inside a block each query is sorted by score (one
+    stable argsort: tied scores keep doc order, where the reference's
+    ``std::sort`` leaves them unspecified) and its grad/hess are put back in
+    doc-slot order by a SCATTER (``.at[order].set``); a host-built row map
+    then gathers every row's value out of the classes' concatenated slots.
+
+    The class layout (per class the doc index, its mask and the inverse max
+    DCGs, already padded to whole blocks; the row map) is built on the host
+    at ``init`` and kept on the device in ``device_arrays``, which the
+    training step takes as an ARGUMENT (``GBDT._step_consts``): closed over,
+    a table of millions of docs would be baked into the executable as
+    constants. ``init`` publishes what one step evaluates: the counters
+    ``rank.pair_slots`` (slots of the pair blocks) and ``rank.pairs`` (pairs
+    of one query whose labels differ, the slots that carry a lambda), and
+    the gauges ``rank.queries`` and ``rank.length_classes``.
     """
     name = "lambdarank"
 
     QUERY_CHUNK_BUDGET = 1 << 22  # pairwise f32 elements per chunk (~16MB)
+    MIN_CLASS = 8                 # the shortest padded query length
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -414,54 +440,86 @@ class LambdarankNDCG(Objective):
         label = metadata.label.astype(np.int64)
         if label.min() < 0 or label.max() >= len(self.label_gain):
             Log.fatal("Label (%d) excceed the max label gain size", int(label.max()))
-        self.num_queries = len(qb) - 1
+        self.num_queries = nq = len(qb) - 1
         sizes = np.diff(qb)
-        # inverse max DCG at k per query (dcg_calculator.cpp semantics)
-        inv_max_dcg = np.zeros(self.num_queries, dtype=np.float64)
-        gains = self.label_gain
-        for q in range(self.num_queries):
-            ls = np.sort(label[qb[q]:qb[q + 1]])[::-1][: self.optimize_pos_at]
-            dcg = float((gains[ls] / np.log2(np.arange(len(ls)) + 2.0)).sum())
-            inv_max_dcg[q] = 1.0 / dcg if dcg > 0.0 else 0.0
+        qid = np.repeat(np.arange(nq), sizes)
+        offset = np.arange(num_data) - qb[qid]          # a doc's place in its query
+        G = len(self.label_gain)
+        per_label = np.bincount(qid * G + label, minlength=nq * G).reshape(nq, G)
+        # inverse max DCG at k per query (dcg_calculator.cpp semantics): with
+        # a query's labels sorted down, label l holds the places
+        # [start, end), and the first k places are discounted
+        end = np.cumsum(per_label[:, ::-1], axis=1)[:, ::-1]
+        start = end - per_label
+        k = self.optimize_pos_at
+        disc = np.concatenate([[0.0], np.cumsum(1.0 / np.log2(np.arange(k) + 2.0))])
+        dcg = (self.label_gain * (disc[np.minimum(end, k)]
+                                  - disc[np.minimum(start, k)])).sum(axis=1)
+        inv_max_dcg = np.where(dcg > 0.0, 1.0 / np.where(dcg > 0.0, dcg, 1.0), 0.0)
 
-        # bucket queries by padded length
-        max_m = int(sizes.max()) if len(sizes) else 1
-        self.buckets = []
+        # the length classes, each padded to whole blocks of queries
+        max_m = int(sizes.max()) if nq else 1
+        lengths = [self.MIN_CLASS]
+        while lengths[-1] < max_m:
+            lengths.append(lengths[-1] * 2)
+        q_len = np.maximum(self.MIN_CLASS,
+                           1 << np.ceil(np.log2(np.maximum(sizes, 1))).astype(np.int64))
+        doc_len = q_len[qid]
+        self.classes = []
+        classes_dev = []
         pos_of_row = np.zeros(num_data, dtype=np.int64)
-        base = 0
-        m = 1
-        while m < 8:
-            m *= 2
-        bucket_lengths = []
-        while True:
-            bucket_lengths.append(m)
-            if m >= max_m:
-                break
-            m *= 2
-        for m in bucket_lengths:
-            qsel = np.nonzero((sizes <= m) & (sizes > (m // 2 if m > bucket_lengths[0] else 0)))[0]
+        base = slots = 0
+        for m in lengths:
+            qsel = np.flatnonzero(q_len == m)
             if len(qsel) == 0:
                 continue
-            doc_idx = np.full((len(qsel), m), num_data, dtype=np.int64)  # sentinel
-            for r, q in enumerate(qsel):
-                n = int(sizes[q])
-                doc_idx[r, :n] = np.arange(qb[q], qb[q + 1])
-                pos_of_row[qb[q]:qb[q + 1]] = base + r * m + np.arange(n)
-            self.buckets.append({
-                "doc_idx": jnp.asarray(doc_idx, jnp.int32),
-                "mask": jnp.asarray(doc_idx < num_data),
-                "inv_max_dcg": jnp.asarray(inv_max_dcg[qsel], jnp.float32),
-                "m": m,
-                "base": base,
-            })
-            base += doc_idx.size
+            chunk = max(1, self.QUERY_CHUNK_BUDGET // (m * m))
+            rows = len(qsel) + (-len(qsel)) % chunk     # whole blocks
+            place = np.zeros(nq, np.int64)
+            place[qsel] = np.arange(len(qsel))
+            docs = np.flatnonzero(doc_len == m)
+            at = place[qid[docs]] * m + offset[docs]
+            doc_idx = np.full(rows * m, num_data, dtype=np.int64)   # sentinel
+            doc_idx[at] = docs
+            pos_of_row[docs] = base + at
+            imd = np.zeros(rows)
+            imd[: len(qsel)] = inv_max_dcg[qsel]
+            self.classes.append({"m": m, "chunk": chunk, "queries": len(qsel)})
+            classes_dev.append({
+                "doc_idx": jnp.asarray(doc_idx.reshape(rows, m).astype(np.int32)),
+                "mask": jnp.asarray((doc_idx < num_data).reshape(rows, m)),
+                "inv_max_dcg": jnp.asarray(imd.astype(np.float32))})
+            base += rows * m
+            slots += rows * m * m
         self.total_slots = base
         self._pos_of_row_np = pos_of_row
-        self.pos_of_row = jnp.asarray(pos_of_row, jnp.int32)
-        self.label_gain_dev = jnp.asarray(self.label_gain, jnp.float32)
-        # set by gbdt.set_row_layout under pre-partitioned block layouts
-        self._row_positions_dev = None
-        self._slot_of_device_row = None
+        self.device_arrays = {
+            "classes": classes_dev,
+            "pos_of_row": jnp.asarray(pos_of_row.astype(np.int32)),
+            "label_gain": jnp.asarray(self.label_gain.astype(np.float32)),
+            # set by set_row_layout under pre-partitioned block layouts
+            "row_positions": None, "slot_of_device_row": None}
+        # ordered pairs (high, low) of one query with high's label above
+        # low's: the slots of the pair blocks that carry a lambda
+        same = int(np.sum(per_label.astype(np.int64) ** 2))
+        self.pairs = (int(np.sum(sizes * sizes)) - same) // 2
+        self.pair_slots = slots
+        from . import observability as obs
+        reg = obs.get_registry()
+        reg.counter("rank.pair_slots").inc(slots)
+        reg.counter("rank.pairs").inc(self.pairs)
+        reg.gauge("rank.queries").set(nq)
+        reg.gauge("rank.length_classes").set(len(self.classes))
+
+    def device_bytes(self) -> int:
+        """HBM the objective holds and its step's gradients pass uses: the
+        resident class layout and row map, the classes' grad/hess (8 B a
+        slot) and one f32 pair block of the largest class. (The objective
+        alone at a 7.3M-doc layout of 11.4M slots, compiled for a described
+        v5e, holds 161 MB of temporaries: those two terms.)"""
+        resident = sum(int(a.nbytes) for a in jax.tree.leaves(self.device_arrays))
+        block = max((c["chunk"] * c["m"] * c["m"] for c in self.classes), default=0)
+        return resident + 8 * self.total_slots + 4 * block
 
     def set_row_layout(self, positions: np.ndarray, npad: int) -> None:
         """Pre-partitioned device layout hook (boosting/gbdt.py): global row
@@ -471,12 +529,14 @@ class LambdarankNDCG(Objective):
         the reference analog is Metadata::CheckOrPartition re-indexing
         queries onto the local used-row set (src/io/metadata.cpp:97-127)."""
         positions = np.asarray(positions, np.int64)
-        self._row_positions_dev = jnp.asarray(positions, jnp.int32)
         slot = np.full(npad, self.total_slots, dtype=np.int64)  # -> zero slot
         slot[positions] = self._pos_of_row_np
-        self._slot_of_device_row = jnp.asarray(slot, jnp.int32)
+        self.device_arrays = dict(
+            self.device_arrays,
+            row_positions=jnp.asarray(positions.astype(np.int32)),
+            slot_of_device_row=jnp.asarray(slot.astype(np.int32)))
 
-    def _query_grads(self, s, l, mask, inv_max_dcg):
+    def _query_grads(self, s, l, mask, inv_max_dcg, label_gain):
         """One padded query: s,l,mask [M]; returns (g, h) [M] in doc order."""
         M = s.shape[0]
         sig = self.config.sigmoid
@@ -486,7 +546,7 @@ class LambdarankNDCG(Objective):
         s_s = s_m[order]
         l_s = jnp.where(mask[order], l[order], 0).astype(jnp.int32)
         valid_s = mask[order]
-        gain = self.label_gain_dev[l_s]
+        gain = label_gain[l_s]
         disc = 1.0 / jnp.log2(jnp.arange(M, dtype=jnp.float32) + 2.0)
         n_valid = jnp.sum(valid_s.astype(jnp.int32))
         best = s_s[0]
@@ -514,51 +574,47 @@ class LambdarankNDCG(Objective):
         # scores may arrive padded to a chunk multiple (boosting/gbdt.py);
         # the query structure only covers the first num_data rows (or, under
         # a pre-partitioned block layout, the positions set_row_layout gave)
+        dev = self.device_arrays
         n = self.num_data
-        if self._row_positions_dev is not None:
-            s_flat = score[0][self._row_positions_dev]
-            l_flat = label[self._row_positions_dev]
+        if dev["row_positions"] is not None:
+            s_flat = score[0][dev["row_positions"]]
+            l_flat = label[dev["row_positions"]]
         else:
             s_flat = score[0, :n]
             l_flat = label[:n]
         s_ext = jnp.concatenate([s_flat, jnp.zeros(1, s_flat.dtype)])
         l_ext = jnp.concatenate([l_flat, jnp.zeros(1, label.dtype)])
         parts = []
-        for b in self.buckets:
-            m = b["m"]
-            chunk_q = max(1, self.QUERY_CHUNK_BUDGET // (m * m))
-            di, mask, imd = b["doc_idx"], b["mask"], b["inv_max_dcg"]
-            nq = di.shape[0]
-            pad_q = (-nq) % chunk_q
-            if pad_q:
-                di = jnp.concatenate([di, jnp.full((pad_q, m), n, jnp.int32)])
-                mask = jnp.concatenate([mask, jnp.zeros((pad_q, m), bool)])
-                imd = jnp.concatenate([imd, jnp.zeros(pad_q, jnp.float32)])
+        for c, arrays in zip(self.classes, dev["classes"]):
+            m, chunk = c["m"], c["chunk"]
+            di, mask, imd = arrays["doc_idx"], arrays["mask"], arrays["inv_max_dcg"]
             sq = s_ext[di]
             lq = l_ext[di]
 
             def batch(args):
                 sqc, lqc, maskc, imdc = args
-                return jax.vmap(self._query_grads)(sqc, lqc, maskc, imdc)
+                return jax.vmap(self._query_grads, in_axes=(0, 0, 0, 0, None))(
+                    sqc, lqc, maskc, imdc, dev["label_gain"])
 
-            gq, hq = jax.lax.map(
-                batch,
-                (sq.reshape(-1, chunk_q, m), lq.reshape(-1, chunk_q, m),
-                 mask.reshape(-1, chunk_q, m), imd.reshape(-1, chunk_q)))
-            parts.append((gq.reshape(-1)[: nq * m], hq.reshape(-1)[: nq * m]))
+            with jax.named_scope("rank.pairwise"):
+                gq, hq = jax.lax.map(
+                    batch,
+                    (sq.reshape(-1, chunk, m), lq.reshape(-1, chunk, m),
+                     mask.reshape(-1, chunk, m), imd.reshape(-1, chunk)))
+            parts.append((gq.reshape(-1), hq.reshape(-1)))
         g_cat = jnp.concatenate([p[0] for p in parts])
         h_cat = jnp.concatenate([p[1] for p in parts])
-        if self._slot_of_device_row is not None:
+        if dev["slot_of_device_row"] is not None:
             # one gather lands grad/hess at their device positions; padding
             # rows point at the appended zero slot
             gx = jnp.concatenate([g_cat, jnp.zeros(1, g_cat.dtype)])
             hx = jnp.concatenate([h_cat, jnp.zeros(1, h_cat.dtype)])
-            g = gx[self._slot_of_device_row]
-            h = hx[self._slot_of_device_row]
-            pad = score.shape[1] - self._slot_of_device_row.shape[0]
+            g = gx[dev["slot_of_device_row"]]
+            h = hx[dev["slot_of_device_row"]]
+            pad = score.shape[1] - dev["slot_of_device_row"].shape[0]
         else:
-            g = g_cat[self.pos_of_row]
-            h = h_cat[self.pos_of_row]
+            g = g_cat[dev["pos_of_row"]]
+            h = h_cat[dev["pos_of_row"]]
             pad = score.shape[1] - n
         if pad:
             g = jnp.concatenate([g, jnp.zeros(pad, g.dtype)])

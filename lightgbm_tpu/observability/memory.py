@@ -116,7 +116,8 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
                             compensated: bool = False,
                             valid_bytes: int = 0,
                             stream_shard_bytes: int = 0,
-                            linear_max_features: int = 0) -> Dict:
+                            linear_max_features: int = 0,
+                            objective_bytes: int = 0) -> Dict:
     """Per-device HBM residency of one training step, by component (bytes).
 
     ``rows``/``cols`` are the PADDED per-device dims the step actually
@@ -149,6 +150,11 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
                   ([N, F] x 5 B), the per-leaf moment buffers
                   ([L+1, K+1, K+1] + [L+1, K+1] f32), and the chunked
                   one-hot gather intermediate of the fit leg
+    - objective:  what the objective holds and its gradients pass uses
+                  (``Objective.device_bytes``): 0 for an elementwise
+                  objective; lambdarank's per-class doc index and mask, its
+                  row map, the classes' gathered slots and the largest pair
+                  block's temporaries
     """
     f32 = 4
     comp = {}
@@ -176,6 +182,7 @@ def estimate_wave_residency(*, rows: int, cols: int, code_itemsize: int,
             + (num_leaves + 1) * (K1 * K1 + K1 + 1) * f32   # moments
             + lin_chunk * linear_max_features * cols * f32  # one-hot gather
             + lin_chunk * (K1 * K1 + K1 + 1) * f32)         # channel matrix
+    comp["objective"] = objective_bytes
     total = int(sum(comp.values()))
     return {"components": {k: int(v) for k, v in comp.items()},
             "total_bytes": total,
@@ -254,7 +261,9 @@ def hbm_preflight(gbdt) -> Dict:
                 stream_shard_bytes=stream_shard_bytes,
                 linear_max_features=(
                     int(getattr(gbdt.config, "linear_max_features", 0))
-                    if getattr(gbdt, "linear_tree", False) else 0))
+                    if getattr(gbdt, "linear_tree", False) else 0),
+                objective_bytes=(gbdt.objective.device_bytes()
+                                 if gbdt.objective is not None else 0))
     est = estimate_wave_residency(**dims)
     est["dims"] = dims
     est["residency"] = residency

@@ -160,7 +160,7 @@ def test_unnamed_is_the_wholes_minus_their_children(registry):
                "setup.dataset_ingest_check_s",
                "setup.dataset_bin_host_s", "setup.dataset_metadata_s")
     booster = [f"setup.booster_{s}_s" for s in (
-        "mesh", "objective", "shapes", "efb", "layout", "fingerprint",
+        "mesh", "objective_init", "shapes", "efb", "layout", "fingerprint",
         "place_codes", "place", "spec", "init_score", "state")]
     assert all(g.get(name) is not None for name in (*dataset, *booster)), g
     wholes = g["setup.dataset_construct_s"] + g["setup.booster_init_s"]
